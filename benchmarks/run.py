@@ -92,4 +92,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == '__main__':
+    from repro.utils import runtime
+    runtime.configure()
     main()

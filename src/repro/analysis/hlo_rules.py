@@ -12,7 +12,9 @@ Backend note: on CPU the Pallas kernels run in interpret mode, whose
 emulation lowers ``pl.load`` to real HLO gathers -- so
 :class:`NoGatherOnFusedPath` is a TPU/GPU contract and self-skips
 elsewhere (raw-text subjects have no backend and always check, which is
-what the fixture tests use). :class:`NoDenseScoreMatrix` is
+what the fixture tests use). A program compiled ahead of time for a
+described TPU topology reports that topology's platform, so the contract
+is checked on TPU-compiled HLO from a CPU-only host too. :class:`NoDenseScoreMatrix` is
 backend-independent: interpret mode preserves blocking, so the forbidden
 shapes stay absent even on CPU (asserted since PR 5).
 """
@@ -62,6 +64,17 @@ def donated_params(hlo_text: str) -> frozenset:
     return frozenset(int(e) for e in _ALIAS_ENTRY_RE.findall(seg))
 
 
+def _platform(compiled) -> str:
+    """The platform ``compiled`` was built for: its input devices' (an AOT
+    compile for a described topology is not the host's backend), else
+    the default backend."""
+    import jax
+    for sharding in jax.tree.leaves(getattr(compiled, "input_shardings",
+                                            ())):
+        return next(iter(sharding.device_set)).platform
+    return jax.default_backend()
+
+
 class HLOProgram:
     """One compiled program as the HLO rules see it: post-opt text, the
     normalized cost dict, parsed trip/byte stats, the defined-buffer
@@ -85,14 +98,13 @@ class HLOProgram:
             return cls(subject, label=label)
         if hasattr(subject, "compile") and not hasattr(subject, "as_text"):
             subject = subject.compile()
-        import jax
         cost = {}
         try:
             cost = hlo_analysis.normalize_cost(subject.cost_analysis())
         except Exception:   # cost analysis is best-effort on some backends
             pass
         return cls(subject.as_text(), cost=cost,
-                   backend=jax.default_backend(), label=label)
+                   backend=_platform(subject), label=label)
 
     @property
     def buffer_shapes(self):

@@ -4,9 +4,9 @@ These encode hygiene rules the protocols were built to make possible:
 the search path dispatches on protocol methods, never ``isinstance`` over
 scorer/index classes (PR 1's whole point); jit-traced functions never
 host-sync (``.item()`` / ``np.*`` on traced values forces a blocking
-device->host copy per call); ``jax.debug.*`` never ships; version-
-sensitive jax APIs route through ``utils/jax_compat.py`` so one shim
-owns the 0.4-vs-0.6 differences.
+device->host copy per call); ``jax.debug.*`` never ships; the mesh and
+shard_map APIs route through ``utils/jax_compat.py`` so one module
+chooses their settings.
 
 Each rule walks pre-parsed ASTs from a shared :class:`SourceTree`.
 A violation can be waived for a specific line with a trailing
@@ -207,9 +207,9 @@ class NoHostSyncInJit(_SourceRule):
 
 
 class NoRawCompatAPIs(_SourceRule):
-    """Version-sensitive jax APIs (mesh construction, shard_map) are
-    used only through ``utils/jax_compat.py`` -- one module owns the
-    jax 0.4/0.6 differences."""
+    """The mesh and shard_map APIs are used only through
+    ``utils/jax_compat.py`` -- one module chooses their settings (Auto
+    axis types, no replication check)."""
 
     name = "NoRawCompatAPIs"
     tag = "raw-compat"

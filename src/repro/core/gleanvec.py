@@ -24,7 +24,7 @@ from repro.core import linalg, spherical_kmeans
 from repro.core.leanvec_sphering import SpheringModel
 
 __all__ = ["GleanVecModel", "fit", "fit_from_moments", "assign_tags",
-           "encode_database", "sort_by_tag", "inverse_permutation",
+           "encode_rows", "encode_database", "sort_by_tag", "inverse_permutation",
            "project_queries_eager", "inner_products_lazy",
            "inner_products_eager", "per_cluster_moments"]
 
@@ -59,11 +59,21 @@ class GleanVecModel(NamedTuple):
 def per_cluster_moments(x: jax.Array, tags: jax.Array, c: int) -> jax.Array:
     """K_X^c = sum_{x in X_c} x x^T for each cluster: (C, D, D).
 
-    One einsum; shards over rows of ``x`` under pjit (psum on output).
+    One einsum per row slice (the one-hot contraction's (rows, C, D)
+    temporary stays O(slice) at any n); shards over rows of ``x`` under
+    pjit (psum on output).
     """
-    onehot = jax.nn.one_hot(tags, c, dtype=jnp.float32)
-    return jnp.einsum("nc,nd,ne->cde", onehot, x.astype(jnp.float32),
-                      x.astype(jnp.float32))
+    x = jnp.asarray(x, jnp.float32)
+    d_full = x.shape[1]
+
+    def add(acc, xb, tb, fresh):
+        onehot = jax.nn.one_hot(tb, c, dtype=jnp.float32) * fresh[:, None]
+        return acc + jnp.einsum("nc,nd,ne->cde", onehot, xb, xb,
+                                precision=jax.lax.Precision.HIGHEST)
+
+    return linalg.fold_row_blocks(
+        add, jnp.zeros((c, d_full, d_full), jnp.float32), [x, tags],
+        linalg.block_rows(c * d_full * 4))
 
 
 def fit_from_moments(centers: jax.Array, k_q: jax.Array,
@@ -98,22 +108,39 @@ def fit(key: jax.Array, queries: jax.Array, database: jax.Array, c: int,
 def assign_tags(model: GleanVecModel, database: jax.Array) -> jax.Array:
     """Eq. (19) cluster assignment under the model's fixed landmarks (the
     tag half of :func:`encode_database`; streaming inserts use it alone to
-    route rank-1 moment updates)."""
-    x_unit = spherical_kmeans.normalize_rows(
-        jnp.asarray(database, jnp.float32))
-    return spherical_kmeans.assign(x_unit, model.centers)
+    route rank-1 moment updates). Row-sliced: no (n, D) temporary."""
+    def tag(x):
+        return spherical_kmeans.assign(
+            spherical_kmeans.normalize_rows(x.astype(jnp.float32)),
+            model.centers)
+
+    database = jnp.asarray(database)
+    return linalg.map_row_blocks(tag, [database],
+                                 linalg.block_rows(database.shape[1] * 8))
+
+
+def encode_rows(model: GleanVecModel, tags: jax.Array,
+                database: jax.Array) -> jax.Array:
+    """``x_low_i = B_{tags_i} x_i`` for a slice of rows: gather the (d, D)
+    blocks then contract (f32 throughout, HIGHEST matmul precision)."""
+    return jnp.einsum("ndk,nk->nd", model.b[tags],
+                      database.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def encode_database(model: GleanVecModel, database: jax.Array):
     """Eq. (14)-(15): tags ``c_i`` and reduced vectors ``x_i_low = B_{c_i} x_i``.
 
     Returns ``(tags: (n,) int32, x_low: (n, d))``. The pair is what a
-    deployment stores contiguously per vector.
+    deployment stores contiguously per vector. Row-sliced, so the gathered
+    (rows, d, D) projections never exceed one slice.
     """
     database = jnp.asarray(database, jnp.float32)
     tags = assign_tags(model, database)
-    # x_low_i = B_{tags_i} x_i: gather the (d, D) block then contract.
-    x_low = jnp.einsum("ndk,nk->nd", model.b[tags], database)
+    b = model.b
+    x_low = linalg.map_row_blocks(
+        lambda t, x: encode_rows(model, t, x), [tags, database],
+        linalg.block_rows(b.shape[1] * b.shape[2] * 4))
     return tags, x_low
 
 

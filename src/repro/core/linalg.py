@@ -18,6 +18,9 @@ __all__ = [
     "orthonormalize_rows",
     "polar",
     "safe_inv_sqrt_spectrum",
+    "block_rows",
+    "map_row_blocks",
+    "fold_row_blocks",
 ]
 
 
@@ -89,3 +92,56 @@ def polar(a: jax.Array) -> jax.Array:
     spectral-norm ball (convex hull of the Stiefel manifold)."""
     u, _, vt = jnp.linalg.svd(a, full_matrices=False)
     return u @ vt
+
+
+def block_rows(bytes_per_row: int, budget: int = 1 << 28) -> int:
+    """Rows per slice so that a ``bytes_per_row`` temporary stays within
+    ``budget`` bytes (256 MiB by default)."""
+    return max(256, budget // max(1, bytes_per_row))
+
+
+def _slices(xs, n: int, block: int, b):
+    """Slice ``b`` of every array in ``xs``: rows ``[start, start+block)``
+    with the last slice clamped to end at ``n``."""
+    start = jnp.minimum(b * block, n - block)
+    return start, [jax.lax.dynamic_slice_in_dim(x, start, block) for x in xs]
+
+
+def map_row_blocks(fn, xs, block: int):
+    """``fn(*slices)`` over consecutive ``block``-row slices of the arrays
+    ``xs`` (one leading length ``n``), written back in place, so that
+    ``fn``'s temporaries are O(block) whatever ``n`` is. The last slice is
+    clamped to end at ``n``; the rows it shares with the previous one are
+    recomputed identically. ``n <= block`` is one plain call."""
+    n = xs[0].shape[0]
+    if n <= block:
+        return fn(*xs)
+    shapes = jax.eval_shape(fn, *(x[:block] for x in xs))
+    out = jax.tree.map(lambda s: jnp.zeros((n,) + s.shape[1:], s.dtype),
+                       shapes)
+
+    def body(b, out):
+        start, sl = _slices(xs, n, block, b)
+        return jax.tree.map(
+            lambda o, r: jax.lax.dynamic_update_slice_in_dim(o, r, start, 0),
+            out, fn(*sl))
+
+    return jax.lax.fori_loop(0, -(-n // block), body, out)
+
+
+def fold_row_blocks(fn, init, xs, block: int):
+    """Accumulate ``carry = fn(carry, *slices, fresh)`` over consecutive
+    ``block``-row slices of ``xs``. ``fresh (rows,) bool`` marks the rows
+    no earlier slice covered (the clamped last slice overlaps its
+    predecessor), so sums count every row once. ``n <= block`` is one
+    call over all rows."""
+    n = xs[0].shape[0]
+    if n <= block:
+        return fn(init, *xs, jnp.ones((n,), bool))
+
+    def body(b, carry):
+        start, sl = _slices(xs, n, block, b)
+        fresh = start + jnp.arange(block) >= b * block
+        return fn(carry, *sl, fresh)
+
+    return jax.lax.fori_loop(0, -(-n // block), body, init)

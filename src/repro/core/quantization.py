@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["SQDatabase", "ClusteredSQDatabase", "quantize",
-           "quantize_per_cluster", "dequantize", "quantized_inner_products"]
+           "quantize_per_cluster", "cluster_ranges", "cluster_steps",
+           "quantize_rows", "dequantize", "quantized_inner_products"]
 
 
 class SQDatabase(NamedTuple):
@@ -63,6 +64,38 @@ class ClusteredSQDatabase(NamedTuple):
     delta: jax.Array   # (C, d) per-cluster per-dimension step
 
 
+def cluster_ranges(x: jax.Array, tags: jax.Array, n_clusters: int,
+                   valid: jax.Array = None):
+    """Raw per-cluster per-dimension (min, max) of ``x``; empty clusters
+    hold the +-inf identities, so ranges of row slices combine with a plain
+    ``minimum`` / ``maximum``. ``valid`` ((n,) bool, optional) excludes rows
+    from the fit."""
+    x = x.astype(jnp.float32)
+    x_lo, x_hi = x, x
+    if valid is not None:
+        x_lo = jnp.where(valid[:, None], x, jnp.inf)
+        x_hi = jnp.where(valid[:, None], x, -jnp.inf)
+    return (jax.ops.segment_min(x_lo, tags, num_segments=n_clusters),
+            jax.ops.segment_max(x_hi, tags, num_segments=n_clusters))
+
+
+def cluster_steps(lo: jax.Array, hi: jax.Array, bits: int = 8):
+    """(lo, delta) of :func:`cluster_ranges` output: an empty cluster (the
+    +-inf sentinels) falls back to the zero range."""
+    empty = ~jnp.isfinite(lo)
+    lo = jnp.where(empty, 0.0, lo)
+    hi = jnp.where(~jnp.isfinite(hi), 0.0, hi)
+    return lo, jnp.maximum(hi - lo, 1e-12) / ((1 << bits) - 1)
+
+
+def quantize_rows(x: jax.Array, tags: jax.Array, lo: jax.Array,
+                  delta: jax.Array, bits: int = 8) -> jax.Array:
+    """u8 codes of rows ``x`` under their clusters' (lo, delta)."""
+    levels = (1 << bits) - 1
+    return jnp.clip(jnp.round((x.astype(jnp.float32) - lo[tags])
+                              / delta[tags]), 0, levels).astype(jnp.uint8)
+
+
 def quantize_per_cluster(x: jax.Array, tags: jax.Array, n_clusters: int,
                          bits: int = 8,
                          valid: jax.Array = None) -> ClusteredSQDatabase:
@@ -76,21 +109,10 @@ def quantize_per_cluster(x: jax.Array, tags: jax.Array, n_clusters: int,
     range fit (dead / padding rows of streaming stores); their codes are
     still produced (clipped). A cluster with no valid rows falls into the
     existing empty-cluster guard."""
-    levels = (1 << bits) - 1
-    x = x.astype(jnp.float32)
-    x_lo, x_hi = x, x
-    if valid is not None:
-        x_lo = jnp.where(valid[:, None], x, jnp.inf)
-        x_hi = jnp.where(valid[:, None], x, -jnp.inf)
-    lo = jax.ops.segment_min(x_lo, tags, num_segments=n_clusters)
-    hi = jax.ops.segment_max(x_hi, tags, num_segments=n_clusters)
-    empty = ~jnp.isfinite(lo)          # empty cluster -> +-inf sentinels
-    lo = jnp.where(empty, 0.0, lo)
-    hi = jnp.where(~jnp.isfinite(hi), 0.0, hi)
-    delta = jnp.maximum(hi - lo, 1e-12) / levels
-    codes = jnp.clip(jnp.round((x - lo[tags]) / delta[tags]), 0,
-                     levels).astype(jnp.uint8)
-    return ClusteredSQDatabase(codes=codes, lo=lo, delta=delta)
+    lo, delta = cluster_steps(*cluster_ranges(x, tags, n_clusters, valid),
+                              bits)
+    return ClusteredSQDatabase(codes=quantize_rows(x, tags, lo, delta, bits),
+                               lo=lo, delta=delta)
 
 
 def dequantize(db: SQDatabase) -> jax.Array:

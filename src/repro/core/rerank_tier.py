@@ -287,9 +287,5 @@ def supports_pinned_host() -> bool:
     TPU/GPU runtimes generally do; CPU backends report only
     ``unpinned_host`` -- their device memory IS host memory, so the
     two-stage pipeline's ``device_put`` is already a no-copy move."""
-    try:
-        dev = jax.devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-    except Exception:       # very old jax: no memories API at all
-        return False
+    kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
     return "pinned_host" in kinds

@@ -80,6 +80,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import gleanvec as gv
+from repro.core import linalg
 from repro.core import quantization as quant
 from repro.core.quantization import ClusteredSQDatabase
 
@@ -1068,12 +1069,35 @@ def quantized_scorer(model, database: jax.Array,
                            a=model.a)
 
 
+def _encode_quantized(model, database: jax.Array, bits: int):
+    """Tags + per-cluster SQ codes of ``database`` in two passes over row
+    slices -- ranges first, then codes -- so the f32 reduced vectors never
+    exist for all rows at once (at 10M rows they would take as much device
+    memory as the full-precision store). Same codes and scales as
+    ``quantize_per_cluster(encode_database(...))``."""
+    database = jnp.asarray(database, jnp.float32)
+    tags = gv.assign_tags(model, database)
+    c, d, d_full = model.b.shape
+    block = linalg.block_rows(d * d_full * 4)
+
+    def ranges(acc, t, x, fresh):
+        lo, hi = quant.cluster_ranges(gv.encode_rows(model, t, x), t, c)
+        return jnp.minimum(acc[0], lo), jnp.maximum(acc[1], hi)
+
+    init = (jnp.full((c, d), jnp.inf), jnp.full((c, d), -jnp.inf))
+    lo, delta = quant.cluster_steps(
+        *linalg.fold_row_blocks(ranges, init, [tags, database], block), bits)
+    codes = linalg.map_row_blocks(
+        lambda t, x: quant.quantize_rows(gv.encode_rows(model, t, x), t, lo,
+                                         delta, bits),
+        [tags, database], block)
+    return tags, ClusteredSQDatabase(codes=codes, lo=lo, delta=delta)
+
+
 def gleanvec_quantized_scorer(model, database: jax.Array,
                               bits: int = 8) -> GleanVecQuantizedScorer:
     """GleanVec + per-cluster int8 SQ of the reduced vectors."""
-    tags, x_low = gv.encode_database(model, database)
-    db: ClusteredSQDatabase = quant.quantize_per_cluster(
-        x_low, tags, model.n_clusters, bits)
+    tags, db = _encode_quantized(model, database, bits)
     return GleanVecQuantizedScorer(codes=db.codes, tags=tags, lo=db.lo,
                                    delta=db.delta, a=model.a)
 
@@ -1101,12 +1125,10 @@ def sorted_gleanvec_quantized_scorer(
     """GleanVec + per-cluster int8 SQ in the tag-sorted layout: the SAME
     codes/scales as :func:`gleanvec_quantized_scorer` (quantize first, then
     sort), so scores match the unsorted scorer exactly."""
-    tags, x_low = gv.encode_database(model, database)
-    db: ClusteredSQDatabase = quant.quantize_per_cluster(
-        x_low, tags, model.n_clusters, bits)
+    tags, db = _encode_quantized(model, database, bits)
     cs, block_tags, perm, _ = gv.sort_by_tag(tags, db.codes, block=block,
                                              slack_blocks=slack_blocks)
-    inv = gv.inverse_permutation(perm, x_low.shape[0])
+    inv = gv.inverse_permutation(perm, tags.shape[0])
     return SortedGleanVecQuantizedScorer(
         codes=cs, block_tags=block_tags, perm=perm.astype(jnp.int32),
         inv_perm=inv, lo=db.lo, delta=db.delta, a=model.a,

@@ -31,7 +31,8 @@ def normalize_rows(x: jax.Array, eps: float = 1e-12) -> jax.Array:
 
 def assign(x_unit: jax.Array, centers: jax.Array) -> jax.Array:
     """Cluster tags via Eq. (14)/(23): argmax_c <x_i, mu_c>. (n,) int32."""
-    return jnp.argmax(x_unit @ centers.T, axis=-1).astype(jnp.int32)
+    sims = jnp.dot(x_unit, centers.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.argmax(sims, axis=-1).astype(jnp.int32)
 
 
 def kmeanspp_init(key: jax.Array, x_unit: jax.Array, c: int) -> jax.Array:

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import spherical_kmeans
+from repro.core import gleanvec as gv, spherical_kmeans
 from repro.core.scorer import LinearScorer
 from repro.index.protocol import (_offset_ids, register_index_pytree,
                                   replace, stacked_specs)
@@ -205,9 +205,7 @@ def build_aligned(model, database, nprobe: int = 8) -> IVFIndex:
     by ``candidates``). The packed lists are kept ONLY for streaming
     ``insert_ids`` / ``remove_ids`` and for non-sorted scorers; the fused
     serving path never reads them."""
-    x_unit = spherical_kmeans.normalize_rows(
-        jnp.asarray(database, jnp.float32))
-    tags = np.asarray(spherical_kmeans.assign(x_unit, model.centers))
+    tags = np.asarray(gv.assign_tags(model, database))
     return IVFIndex(centers=jnp.asarray(model.centers, jnp.float32),
                     lists=jnp.asarray(_pack_lists(tags, model.n_clusters)),
                     nprobe=min(nprobe, model.n_clusters),
@@ -224,8 +222,7 @@ def build_aligned_sharded(model, database, n_shards: int,
     if n % n_shards:
         raise ValueError(f"n={n} not divisible by n_shards={n_shards}")
     per = n // n_shards
-    x_unit = spherical_kmeans.normalize_rows(X)
-    tags = np.asarray(spherical_kmeans.assign(x_unit, model.centers))
+    tags = np.asarray(gv.assign_tags(model, X))
     packed = [_pack_lists(tags[s * per:(s + 1) * per], model.n_clusters)
               for s in range(n_shards)]
     max_len = max(p.shape[1] for p in packed)

@@ -43,54 +43,75 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -3.4e38  # python scalar: safe to close over inside the kernel
+from repro.kernels._mosaic import NEG_INF, fold_topk, lanes, to_f32
 
 
-def _tile_scores(qs, qlo, tags, x, *, c: int, sorted_layout: bool):
-    """(TM, TN) score tile. ``qs (TM, C, d)``, ``qlo (TM, C)``, ``x (TN, d)``
-    codes (any dtype, cast on load), ``tags``: (TN,) row tags, or (1,) tile
-    tag when ``sorted_layout``."""
-    x = x.astype(jnp.float32)
-    if sorted_layout:
-        tag = tags[0]
-        q = jax.lax.dynamic_index_in_dim(qs, tag, axis=1,
-                                         keepdims=False)       # (TM, d)
-        lo = jax.lax.dynamic_index_in_dim(qlo, tag, axis=1,
-                                          keepdims=False)      # (TM,)
-        s = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        return s + lo[:, None]
+def _sorted_scores(qs_ref, qlo_ref, tag, x):
+    """(TM, TN) single-tag tile: ``qs_ref (C, TM, d)`` / ``qlo_ref
+    (C, TM, 1)`` are tag-major, so the tile's tag picks a leading-axis
+    slice (no in-register gather)."""
+    s = jax.lax.dot_general(qs_ref[tag], x, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    return s + qlo_ref[tag]
 
-    tm = qs.shape[0]
-    onehot = (tags[:, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (tags.shape[0], c), 1)
-              ).astype(jnp.float32)                            # (TN, C)
+
+def _row_scores(qs_ref, qlo_ref, tags, x, *, c: int):
+    """(TM, TN) tile with per-row ``tags (1, TN)``: per query, the (C, TN)
+    product of every cluster view with the tile is masked by the one-hot
+    ``(C, TN)`` tag matrix and summed over C -- the tag select rides the
+    MXU, and every selected score is an exact copy of its product entry.
+    ``qs_ref (TM, C, d)``, ``qlo_ref (TM, C, 1)``."""
+    tm = qs_ref.shape[0]
+    onehot = tags == jax.lax.broadcasted_iota(jnp.int32, (c, x.shape[0]), 0)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tm, x.shape[0]), 0)
 
     def per_query(mi, acc):
-        q_sel = jax.lax.dot_general(
-            onehot, qs[mi], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (TN, d)
-        lo_sel = jax.lax.dot_general(
-            onehot, qlo[mi][:, None], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (TN, 1)
-        s = jnp.sum(q_sel * x, axis=1) + lo_sel[:, 0]
-        return jax.lax.dynamic_update_index_in_dim(acc, s, mi, 0)
+        g = jax.lax.dot_general(qs_ref[mi], x, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+        s = jnp.sum(jnp.where(onehot, g + qlo_ref[mi], 0.0), axis=0,
+                    keepdims=True)                             # (1, TN)
+        return jnp.where(rows == mi, s, acc)
 
     init = jnp.zeros((tm, x.shape[0]), jnp.float32)
     return jax.lax.fori_loop(0, tm, per_query, init)
 
 
-def _dense_kernel(qs_ref, qlo_ref, tags_ref, x_ref, out_ref, *, c: int,
-                  sorted_layout: bool):
-    out_ref[...] = _tile_scores(qs_ref[...].astype(jnp.float32),
-                                qlo_ref[...].astype(jnp.float32),
-                                tags_ref[...], x_ref[...], c=c,
-                                sorted_layout=sorted_layout)
+def _tile_scores(tags_ref, qs_ref, qlo_ref, tagrow_ref, x_ref, *, c: int,
+                 bpt: int):
+    """Score tile of grid step (i, j). Sorted layout (``tags_ref`` is the
+    scalar-prefetched per-block tag array): one tag per tile, ``bpt``
+    tiles per layout block. Gathered layout: per-row tags in
+    ``tagrow_ref (1, 1, TN)``."""
+    x = to_f32(x_ref[...])                                     # (TN, d)
+    if tags_ref is not None:
+        tag = tags_ref[pl.program_id(1) // bpt]
+        return _sorted_scores(qs_ref, qlo_ref, tag, x)
+    return _row_scores(qs_ref, qlo_ref, tagrow_ref[0], x, c=c)
 
 
-def _topk_kernel(qs_ref, qlo_ref, tags_ref, rid_ref, x_ref, vals_ref,
-                 ids_ref, *, c: int, k: int, sorted_layout: bool):
+def _dense_kernel(*refs, c: int, bpt: int, sorted_layout: bool):
+    if sorted_layout:
+        tags_ref, qs_ref, qlo_ref, x_ref, out_ref = refs
+        tagrow_ref = None
+    else:
+        qs_ref, qlo_ref, tagrow_ref, x_ref, out_ref = refs
+        tags_ref = None
+    out_ref[...] = _tile_scores(tags_ref, qs_ref, qlo_ref, tagrow_ref, x_ref,
+                                c=c, bpt=bpt)
+
+
+def _topk_kernel(*refs, c: int, k: int, bpt: int, sorted_layout: bool):
+    if sorted_layout:
+        tags_ref, qs_ref, qlo_ref, rid_ref, x_ref, vals_ref, ids_ref = refs
+        tagrow_ref = None
+    else:
+        (qs_ref, qlo_ref, tagrow_ref, rid_ref, x_ref, vals_ref,
+         ids_ref) = refs
+        tags_ref = None
     nj = pl.program_id(1)
 
     @pl.when(nj == 0)
@@ -98,36 +119,14 @@ def _topk_kernel(qs_ref, qlo_ref, tags_ref, rid_ref, x_ref, vals_ref,
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         ids_ref[...] = jnp.full_like(ids_ref, -1)
 
-    scores = _tile_scores(qs_ref[...].astype(jnp.float32),
-                          qlo_ref[...].astype(jnp.float32),
-                          tags_ref[...], x_ref[...], c=c,
-                          sorted_layout=sorted_layout)
-    col_ids = jnp.broadcast_to(rid_ref[...][None, :], scores.shape)
+    scores = _tile_scores(tags_ref, qs_ref, qlo_ref, tagrow_ref, x_ref,
+                          c=c, bpt=bpt)
+    col_ids = jnp.broadcast_to(rid_ref[0], scores.shape)
     scores = jnp.where(col_ids >= 0, scores, NEG_INF)
-
     # fold the tile into the running top-k: k rounds of max/mask over the
-    # concatenated (TM, TN + k) candidates (same scheme as ip_topk).
-    cat_v = jnp.concatenate([vals_ref[...], scores], axis=1)
-    cat_i = jnp.concatenate([ids_ref[...], col_ids], axis=1)
-
-    def fold(j, carry):
-        cat_v, cat_i, out_v, out_i = carry
-        best = jnp.max(cat_v, axis=1)                          # (TM,)
-        arg = jnp.argmax(cat_v, axis=1)                        # (TM,)
-        bid = jnp.take_along_axis(cat_i, arg[:, None], axis=1)[:, 0]
-        out_v = jax.lax.dynamic_update_index_in_dim(out_v, best, j, 1)
-        out_i = jax.lax.dynamic_update_index_in_dim(out_i, bid, j, 1)
-        hit = (jax.lax.broadcasted_iota(jnp.int32, cat_v.shape, 1)
-               == arg[:, None])
-        cat_v = jnp.where(hit, NEG_INF, cat_v)
-        return cat_v, cat_i, out_v, out_i
-
-    out_v = jnp.zeros_like(vals_ref)
-    out_i = jnp.zeros_like(ids_ref)
-    _, _, out_v, out_i = jax.lax.fori_loop(
-        0, k, fold, (cat_v, cat_i, out_v, out_i))
-    vals_ref[...] = out_v
-    ids_ref[...] = out_i
+    # running (TM, k) and the tile's (TM, TN) candidates (as ip_topk).
+    vals_ref[...], ids_ref[...] = fold_topk(vals_ref[...], ids_ref[...],
+                                            scores, col_ids, k)
 
 
 def _pad0(x, pad, fill=0):
@@ -137,13 +136,58 @@ def _pad0(x, pad, fill=0):
     return jnp.pad(x, widths, constant_values=fill)
 
 
-def _tag_spec(tn: int, layout_block: int, sorted_layout: bool):
-    """BlockSpec of the tags input: per-row tags for gathered tiles, one tag
-    per tile (layout_block // tn tiles share a block tag) when sorted."""
-    if not sorted_layout:
-        return pl.BlockSpec((tn,), lambda i, j: (j,))
-    bpt = layout_block // tn                   # tiles per layout block
-    return pl.BlockSpec((1,), lambda i, j: (j // bpt,))
+def _call(kernel, q_scaled, q_lo, tags, codes, row_ids, *, layout_block: int,
+          tm: int, tn: int, out_specs, out_shape, interpret: bool):
+    """Shared pallas_call plumbing of the dense and top-k variants: pads M
+    (and N on the gathered layout), lays the operands out for Mosaic and
+    picks the grid spec of the layout."""
+    m, c, d = q_scaled.shape
+    n = codes.shape[0]
+    srt = layout_block > 0
+    if srt:
+        assert n % layout_block == 0 and layout_block % tn == 0, \
+            (n, layout_block, tn)
+    tm = min(tm, max(1, m))
+    m_pad = (-m) % tm
+    n_pad = 0 if srt else (-n) % tn
+    q_scaled = _pad0(q_scaled.astype(jnp.float32), m_pad)
+    q_lo = _pad0(q_lo.astype(jnp.float32), m_pad)[..., None]  # (M, C, 1)
+    codes = _pad0(codes, n_pad)
+    rows = (m + m_pad, n + n_pad)
+    grid = (rows[0] // tm, rows[1] // tn)
+    # per-row operands as (N // TN, 1, TN): the block's last two dims then
+    # equal the array's, whatever TN is
+    row_ops = [] if row_ids is None else [
+        _pad0(row_ids.astype(jnp.int32), n_pad, fill=-1)
+        .reshape(grid[1], 1, tn)]
+    row_spec = pl.BlockSpec((1, 1, tn), lambda i, j, *_: (j, 0, 0))
+    x_spec = pl.BlockSpec((tn, d), lambda i, j, *_: (j, 0))
+    if srt:
+        # tag-major query views; the per-block tags ride in SMEM
+        bpt = layout_block // tn
+        in_specs = [pl.BlockSpec((c, tm, d), lambda i, j, t: (0, i, 0)),
+                    pl.BlockSpec((c, tm, 1), lambda i, j, t: (0, i, 0))]
+        in_specs += [row_spec] * len(row_ops) + [x_spec]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs)
+        args = (tags.astype(jnp.int32), q_scaled.transpose(1, 0, 2),
+                q_lo.transpose(1, 0, 2), *row_ops, codes)
+    else:
+        bpt = 1
+        in_specs = [pl.BlockSpec((tm, c, d), lambda i, j: (i, 0, 0)),
+                    pl.BlockSpec((tm, c, 1), lambda i, j: (i, 0, 0)),
+                    row_spec]
+        in_specs += [row_spec] * len(row_ops) + [x_spec]
+        grid_spec = pl.GridSpec(grid=grid, in_specs=in_specs,
+                                out_specs=out_specs)
+        tag_rows = _pad0(tags.astype(jnp.int32), n_pad).reshape(
+            grid[1], 1, tn)
+        args = (q_scaled, q_lo, tag_rows, *row_ops, codes)
+    return pl.pallas_call(
+        functools.partial(kernel, c=c, bpt=bpt, sorted_layout=srt),
+        grid_spec=grid_spec, out_shape=out_shape(rows),
+        interpret=interpret)(*args)
 
 
 @functools.partial(jax.jit, static_argnames=("layout_block", "tm", "tn",
@@ -159,35 +203,14 @@ def gleanvec_sq(q_scaled: jax.Array, q_lo: jax.Array, tags: jax.Array,
     ``layout_block > 0``: tag-sorted layout, ``tags (N // layout_block,)``
     per-block; requires ``layout_block % tn == 0``.
     """
-    m, c, d = q_scaled.shape
-    n = codes.shape[0]
-    srt = layout_block > 0
-    if srt:
-        assert n % layout_block == 0 and layout_block % tn == 0, \
-            (n, layout_block, tn)
-    tm = min(tm, max(1, m))
-    m_pad = (-m) % tm
-    n_pad = 0 if srt else (-n) % tn
-    q_scaled = _pad0(q_scaled, m_pad)
-    q_lo = _pad0(q_lo, m_pad)
-    codes = _pad0(codes, n_pad)
-    if not srt:
-        tags = _pad0(tags, n_pad)
-    grid = ((m + m_pad) // tm, (n + n_pad) // tn)
-
-    out = pl.pallas_call(
-        functools.partial(_dense_kernel, c=c, sorted_layout=srt),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, c, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((tm, c), lambda i, j: (i, 0)),
-            _tag_spec(tn, layout_block, srt),
-            pl.BlockSpec((tn, d), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((tm, tn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m + m_pad, n + n_pad), jnp.float32),
-        interpret=interpret,
-    )(q_scaled, q_lo, tags, codes)
+    m, n = q_scaled.shape[0], codes.shape[0]
+    tm_ = min(tm, max(1, m))
+    out = _call(_dense_kernel, q_scaled, q_lo, tags, codes, None,
+                layout_block=layout_block, tm=tm, tn=tn,
+                out_specs=pl.BlockSpec((tm_, tn), lambda i, j, *_: (i, j)),
+                out_shape=lambda rows: jax.ShapeDtypeStruct(rows,
+                                                            jnp.float32),
+                interpret=interpret)
     return out[:m, :n]
 
 
@@ -204,43 +227,18 @@ def gleanvec_sq_topk(q_scaled: jax.Array, q_lo: jax.Array, tags: jax.Array,
     never win); defaults to ``arange(N)``. Sorted layouts pass their sort
     permutation here so the kernel emits ORIGINAL database ids.
     """
-    m, c, d = q_scaled.shape
-    n = codes.shape[0]
-    srt = layout_block > 0
-    if srt:
-        assert n % layout_block == 0 and layout_block % tn == 0, \
-            (n, layout_block, tn)
+    m, n = q_scaled.shape[0], codes.shape[0]
     if row_ids is None:
         row_ids = jnp.arange(n, dtype=jnp.int32)
-    tm = min(tm, max(1, m))
-    m_pad = (-m) % tm
-    n_pad = 0 if srt else (-n) % tn
-    q_scaled = _pad0(q_scaled, m_pad)
-    q_lo = _pad0(q_lo, m_pad)
-    codes = _pad0(codes, n_pad)
-    row_ids = _pad0(row_ids.astype(jnp.int32), n_pad, fill=-1)
-    if not srt:
-        tags = _pad0(tags, n_pad)
-    grid = ((m + m_pad) // tm, (n + n_pad) // tn)
-
-    vals, ids = pl.pallas_call(
-        functools.partial(_topk_kernel, c=c, k=k, sorted_layout=srt),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, c, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((tm, c), lambda i, j: (i, 0)),
-            _tag_spec(tn, layout_block, srt),
-            pl.BlockSpec((tn,), lambda i, j: (j,)),
-            pl.BlockSpec((tn, d), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((tm, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m + m_pad, k), jnp.float32),
-            jax.ShapeDtypeStruct((m + m_pad, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q_scaled, q_lo, tags, row_ids, codes)
-    return vals[:m], ids[:m]
+    tm_ = min(tm, max(1, m))
+    kp = lanes(k)                  # running top-k block, lane-aligned
+    spec = pl.BlockSpec((tm_, kp), lambda i, j, *_: (i, 0))
+    vals, ids = _call(
+        functools.partial(_topk_kernel, k=k), q_scaled, q_lo, tags, codes,
+        row_ids, layout_block=layout_block, tm=tm, tn=tn,
+        out_specs=[spec, spec],
+        out_shape=lambda rows: [
+            jax.ShapeDtypeStruct((rows[0], kp), jnp.float32),
+            jax.ShapeDtypeStruct((rows[0], kp), jnp.int32)],
+        interpret=interpret)
+    return vals[:m, :k], ids[:m, :k]

@@ -26,8 +26,9 @@ slab is DMAed ONCE; inside VMEM the kernel fuses
 Nothing shaped ``(batch, expand*R)`` in f32 -- neither gathered rows nor a
 score matrix -- ever exists in HBM; only the int32 schedule / neighbor-row
 arrays (4 bytes per candidate) ride along as scalar prefetch. HBM traffic
-per fresh slab: TN * d bytes of codes + TN * 4 of ids + 4 of tag; per
-query: C * d * 4 + C * 4 of prepared views plus the (beam) state in/out.
+per fresh slab: TN * d bytes of codes + TN * 4 of ids; per query: C * d *
+4 + C * 4 of prepared views plus the (beam) state in/out; per call: the
+block tags (4 bytes per layout block) into SMEM with the schedules.
 """
 from __future__ import annotations
 
@@ -38,18 +39,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -3.4e38  # python scalar: safe to close over inside the kernel
+from repro.kernels._mosaic import (_INT_MIN, NEG_INF, pick, query_chunks,
+                                   to_f32)
 
 
-def _beam_step_kernel(sched_ref, fill_ref, qs_ref, qlo_ref, nbr_ref,
-                      tag_ref, rid_ref, x_ref, bvals_ref, bids_ref,
-                      vals_ref, ids_ref, *, tn: int):
+def _beam_step_kernel(sched_ref, fill_ref, tags_ref, qs_ref, qlo_ref,
+                      nbr_ref, rid_ref, x_ref, bvals_ref, bids_ref,
+                      vals_ref, ids_ref, *, tn: int, bpt: int):
     """One ``tn``-row slab of one query's hop schedule, folded into its
     running (1, beam) top-k. ``sched_ref`` holds the slab schedule (a
     negative entry marks a padding / repeated-slab slot that must not
     fold); ``fill_ref`` is its forward-filled twin the BlockSpec index
     maps read, so a padding slot revisits the PREVIOUS slab (no fresh
-    DMA) instead of fetching slab 0."""
+    DMA) instead of fetching slab 0. ``tags_ref`` (SMEM) holds one tag per
+    layout block."""
     i = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -60,51 +63,43 @@ def _beam_step_kernel(sched_ref, fill_ref, qs_ref, qlo_ref, nbr_ref,
 
     @pl.when(sched_ref[i, j] >= 0)
     def _fold_slab():
-        tag = tag_ref[0]
-        q = jax.lax.dynamic_index_in_dim(qs_ref[...], tag, axis=1,
-                                         keepdims=False)       # (1, d)
-        lo = jax.lax.dynamic_index_in_dim(qlo_ref[...], tag, axis=1,
-                                          keepdims=False)      # (1,)
-        x = x_ref[...].astype(jnp.float32)                     # (TN, d)
+        slab = fill_ref[i, j]
+        tag = tags_ref[slab // bpt]
+        q = qs_ref[0, pl.ds(tag, 1), :]                        # (1, d)
+        lo = qlo_ref[0, pl.ds(tag, 1), :]                      # (1, 1)
+        x = to_f32(x_ref[...])                                 # (TN, d)
         scores = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                                     precision=jax.lax.Precision.HIGHEST,
                                      preferred_element_type=jnp.float32) \
-            + lo[:, None]                                      # (1, TN)
-        # global sorted-row index of every slab row, for the membership
-        # test against this hop's (scalar-prefetched) neighbor set
-        rows = fill_ref[i, j] * tn \
-            + jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1)  # (1, TN)
-        nbrs = nbr_ref[...]                                    # (1, S)
-        member = jnp.any(rows[0, :, None] == nbrs[0, None, :],
-                         axis=1)[None, :]                      # (1, TN)
-        # original ids straight from the sort permutation; candidates
-        # already in the incoming beam are the gathered path's
-        # _beam_member_mask dedupe
-        cand_ids = jnp.broadcast_to(rid_ref[...][None, :], scores.shape)
-        in_beam = jnp.any(cand_ids[0, :, None] == bids_ref[...][0, None, :],
-                          axis=1)[None, :]                     # (1, TN)
-        ok = member & (cand_ids >= 0) & ~in_beam
-        cand_v = jnp.where(ok, scores, NEG_INF)
+            + lo                                               # (1, TN)
+        cand_ids = rid_ref[0]                                  # (1, TN)
+        nbrs = nbr_ref[0]                                      # (1, S)
+        beam_in = bids_ref[0]                                  # (1, B)
+        iota_t = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
 
         # fold: TN rounds of strict-improvement replacement of the running
         # beam's minimum -- the online form of top_k(concat([beam, cand])).
+        # Slab row t folds only if it is in this hop's (scalar-prefetched)
+        # neighbor set, is live, and its ORIGINAL id (from the sort
+        # permutation) is not in the incoming beam: the gathered path's
+        # _beam_member_mask dedupe.
         def fold(t, carry):
             vals, ids = carry                                  # (1, beam)
-            v = jax.lax.dynamic_index_in_dim(cand_v, t, axis=1,
-                                             keepdims=True)    # (1, 1)
-            ci = jax.lax.dynamic_index_in_dim(cand_ids, t, axis=1,
-                                              keepdims=True)   # (1, 1)
+            v = pick(scores, iota_t, t, -jnp.inf)              # (1, 1)
+            ci = pick(cand_ids, iota_t, t, _INT_MIN)           # (1, 1)
+            member = jnp.any(nbrs == slab * tn + t, axis=1, keepdims=True)
+            in_beam = jnp.any(beam_in == ci, axis=1, keepdims=True)
+            v = jnp.where(member & (ci >= 0) & ~in_beam, v, NEG_INF)
             vmin = jnp.min(vals, axis=1, keepdims=True)        # (1, 1)
-            amin = jnp.argmin(vals, axis=1)                    # (1,)
-            hit = (jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-                   == amin[:, None]) & (v > vmin)
-            vals = jnp.where(hit, v, vals)
-            ids = jnp.where(hit, ci, ids)
-            return vals, ids
+            iota_b = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+            amin = jnp.min(jnp.where(vals == vmin, iota_b, tn + vals.size),
+                           axis=1, keepdims=True)              # first argmin
+            hit = (iota_b == amin) & (v > vmin)
+            return jnp.where(hit, v, vals), jnp.where(hit, ci, ids)
 
-        vals, ids = jax.lax.fori_loop(
-            0, tn, fold, (vals_ref[...], ids_ref[...]))
-        vals_ref[...] = vals
-        ids_ref[...] = ids
+        vals, ids = jax.lax.fori_loop(0, tn, fold, (vals_ref[0], ids_ref[0]))
+        vals_ref[0] = vals
+        ids_ref[0] = ids
 
 
 @functools.partial(jax.jit, static_argnames=("layout_block", "tn",
@@ -130,7 +125,10 @@ def graph_scan_beam_step(q_scaled: jax.Array, q_lo: jax.Array,
     top-B multiset of {incoming beam} U {distinct live neighbors not
     already in the beam}, in slot order (NOT sorted -- the traversal's
     final ``top_k`` orders the winners). ``tn`` must divide
-    ``layout_block`` (the dispatcher in ops.py guarantees it).
+    ``layout_block`` (the dispatcher in ops.py guarantees it). Per-query
+    operands carry a unit axis (``(M, 1, S)``, ``(M, 1, B)``, ``(M, C,
+    1)``, ids ``(N // TN, 1, TN)``) so every block's last two dims equal
+    the array's; the block tags and schedules ride in SMEM.
     """
     m, c, d = q_scaled.shape
     n = codes.shape[0]
@@ -156,33 +154,45 @@ def graph_scan_beam_step(q_scaled: jax.Array, q_lo: jax.Array,
     sched_f = jnp.maximum(jax.lax.associative_scan(
         lambda a, b: jnp.where(b >= 0, b, a), sched_t, axis=1), 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(m, s),
-        in_specs=[
-            pl.BlockSpec((1, c, d), lambda i, j, sr, fr: (i, 0, 0)),
-            pl.BlockSpec((1, c), lambda i, j, sr, fr: (i, 0)),
-            pl.BlockSpec((1, s), lambda i, j, sr, fr: (i, 0)),
-            pl.BlockSpec((1,), lambda i, j, sr, fr: (fr[i, j] // bpt,)),
-            pl.BlockSpec((tn,), lambda i, j, sr, fr: (fr[i, j],)),
-            pl.BlockSpec((tn, d), lambda i, j, sr, fr: (fr[i, j], 0)),
-            pl.BlockSpec((1, b), lambda i, j, sr, fr: (i, 0)),
-            pl.BlockSpec((1, b), lambda i, j, sr, fr: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, b), lambda i, j, sr, fr: (i, 0)),
-            pl.BlockSpec((1, b), lambda i, j, sr, fr: (i, 0)),
-        ],
-    )
-    vals, ids = pl.pallas_call(
-        functools.partial(_beam_step_kernel, tn=tn),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((m, b), jnp.float32),
-            jax.ShapeDtypeStruct((m, b), jnp.int32),
-        ],
-        interpret=interpret,
-    )(sched_t, sched_f, q_scaled, q_lo, nbr_sorted, block_tags,
-      row_ids.astype(jnp.int32), codes, beam_vals.astype(jnp.float32),
-      beam_ids.astype(jnp.int32))
-    return vals, ids
+    def run(sched_t, sched_f, q_scaled, q_lo, nbr_sorted, beam_vals,
+            beam_ids):
+        mc = q_scaled.shape[0]
+
+        def per_query(width):
+            return pl.BlockSpec((1, 1, width),
+                                lambda i, j, sr, fr, tg: (i, 0, 0))
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(mc, s),
+            in_specs=[
+                pl.BlockSpec((1, c, d), lambda i, j, sr, fr, tg: (i, 0, 0)),
+                pl.BlockSpec((1, c, 1), lambda i, j, sr, fr, tg: (i, 0, 0)),
+                per_query(s),
+                pl.BlockSpec((1, 1, tn),
+                             lambda i, j, sr, fr, tg: (fr[i, j], 0, 0)),
+                pl.BlockSpec((tn, d), lambda i, j, sr, fr, tg: (fr[i, j], 0)),
+                per_query(b),
+                per_query(b),
+            ],
+            out_specs=[per_query(b), per_query(b)],
+        )
+        return pl.pallas_call(
+            functools.partial(_beam_step_kernel, tn=tn, bpt=bpt),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((mc, 1, b), jnp.float32),
+                jax.ShapeDtypeStruct((mc, 1, b), jnp.int32),
+            ],
+            interpret=interpret,
+        )(sched_t, sched_f, block_tags.astype(jnp.int32),
+          q_scaled.astype(jnp.float32), q_lo.astype(jnp.float32)[..., None],
+          nbr_sorted[:, None, :],
+          row_ids.astype(jnp.int32).reshape(n // tn, 1, tn), codes,
+          beam_vals.astype(jnp.float32)[:, None, :],
+          beam_ids.astype(jnp.int32)[:, None, :])
+
+    vals, ids = query_chunks(run, m, s, block_tags.shape[0], sched_t,
+                             sched_f, q_scaled, q_lo, nbr_sorted, beam_vals,
+                             beam_ids)
+    return vals[:, 0], ids[:, 0]

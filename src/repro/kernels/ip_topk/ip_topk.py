@@ -8,8 +8,11 @@ tile on the MXU, and folds it into a running (TM, k) top-k held in VMEM
 scratch across the sequential N grid dimension -- scores never round-trip
 to HBM.
 
-Top-k folding uses k iterations of (max, argmax, mask) on the VPU; k is small
-(10..128) in every paper configuration.
+Top-k folding uses k iterations of (max, first-argmax, mask) on the VPU
+(compare/select only: Mosaic has no in-kernel lane gather); k is small
+(10..128) in every paper configuration. Integer codes are widened through
+i32 on load, and the tile contraction runs at HIGHEST precision, so the
+scores equal the f32 reference's on the TPU too.
 
 VMEM budget per step (TM=128, TN=512, d=160, k=16, fp32):
   q tile 128*160*4 = 80 KiB, x tile 512*160*4 = 320 KiB,
@@ -23,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-NEG_INF = -3.4e38  # python scalar: safe to close over inside the kernel
+from repro.kernels._mosaic import NEG_INF, fold_topk, lanes, to_f32
 
 
 def _ip_topk_kernel(q_ref, x_ref, vals_ref, ids_ref, *, k: int, tn: int,
@@ -35,40 +38,19 @@ def _ip_topk_kernel(q_ref, x_ref, vals_ref, ids_ref, *, k: int, tn: int,
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         ids_ref[...] = jnp.full_like(ids_ref, -1)
 
-    q = q_ref[...].astype(jnp.float32)                     # (TM, d)
-    x = x_ref[...].astype(jnp.float32)                     # (TN, d)
+    q = to_f32(q_ref[...])                                 # (TM, d)
+    x = to_f32(x_ref[...])                                 # (TN, d)
     scores = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                # (TM, TN)
     base = nj * tn
     col_ids = base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     scores = jnp.where(col_ids < n_total, scores, NEG_INF)
-
-    run_v = vals_ref[...]
-    run_i = ids_ref[...]
     # fold the tile into the running top-k: k rounds of max/mask over the
-    # concatenated (TM, TN + k) candidates.
-    cat_v = jnp.concatenate([run_v, scores], axis=1)
-    cat_i = jnp.concatenate([run_i, col_ids], axis=1)
-
-    def fold(j, carry):
-        cat_v, cat_i, out_v, out_i = carry
-        best = jnp.max(cat_v, axis=1)                       # (TM,)
-        arg = jnp.argmax(cat_v, axis=1)                     # (TM,)
-        bid = jnp.take_along_axis(cat_i, arg[:, None], axis=1)[:, 0]
-        out_v = jax.lax.dynamic_update_index_in_dim(out_v, best, j, 1)
-        out_i = jax.lax.dynamic_update_index_in_dim(out_i, bid, j, 1)
-        hit = (jax.lax.broadcasted_iota(jnp.int32, cat_v.shape, 1)
-               == arg[:, None])
-        cat_v = jnp.where(hit, NEG_INF, cat_v)
-        return cat_v, cat_i, out_v, out_i
-
-    out_v = jnp.zeros_like(run_v)
-    out_i = jnp.zeros_like(run_i)
-    _, _, out_v, out_i = jax.lax.fori_loop(
-        0, k, fold, (cat_v, cat_i, out_v, out_i))
-    vals_ref[...] = out_v
-    ids_ref[...] = out_i
+    # running (TM, k) and the tile's (TM, TN) candidates.
+    vals_ref[...], ids_ref[...] = fold_topk(vals_ref[...], ids_ref[...],
+                                            scores, col_ids, k)
 
 
 @functools.partial(jax.jit,
@@ -90,6 +72,7 @@ def ip_topk(q: jax.Array, x: jax.Array, k: int, tm: int = 128, tn: int = 512,
     if n_pad:
         x = jnp.pad(x, ((0, n_pad), (0, 0)))
     grid = ((m + m_pad) // tm, (n + n_pad) // tn)
+    kp = lanes(k)                  # running top-k block, lane-aligned
 
     vals, ids = pl.pallas_call(
         functools.partial(_ip_topk_kernel, k=k, tn=tn, n_total=n),
@@ -99,13 +82,13 @@ def ip_topk(q: jax.Array, x: jax.Array, k: int, tm: int = 128, tn: int = 512,
             pl.BlockSpec((tn, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((tm, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((tm, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((tm, kp), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(((m + m_pad), k), jnp.float32),
-            jax.ShapeDtypeStruct(((m + m_pad), k), jnp.int32),
+            jax.ShapeDtypeStruct(((m + m_pad), kp), jnp.float32),
+            jax.ShapeDtypeStruct(((m + m_pad), kp), jnp.int32),
         ],
         interpret=interpret,
     )(q, x)
-    return vals[:m], ids[:m]
+    return vals[:m, :k], ids[:m, :k]

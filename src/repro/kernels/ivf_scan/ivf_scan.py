@@ -21,8 +21,9 @@ schedule (the per-query views (1, C, d) stay resident across the whole
 inner dimension -- their block index does not change with ``j``).
 
 HBM traffic per grid step: TN * d bytes of codes (u8, or f32 for the
-unquantized sorted scorer) + TN * 4 bytes of ids + 4 bytes of tag; per
-query: C * d * 4 + C * 4 bytes of prepared views. Nothing else.
+unquantized sorted scorer) + TN * 4 bytes of ids; per query: C * d * 4 +
+C * 4 bytes of prepared views; per call: the block tags, 4 bytes per
+layout block, into SMEM with the schedules. Nothing else.
 """
 from __future__ import annotations
 
@@ -33,17 +34,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -3.4e38  # python scalar: safe to close over inside the kernel
+from repro.kernels._mosaic import (NEG_INF, fold_topk, lanes,
+                                   query_chunks, to_f32)
 
 
-def _range_scan_kernel(sched_ref, fill_ref, qs_ref, qlo_ref, tag_ref,
-                       rid_ref, x_ref, vals_ref, ids_ref, *, k: int):
+def _range_scan_kernel(sched_ref, fill_ref, tags_ref, qs_ref, qlo_ref,
+                       rid_ref, x_ref, vals_ref, ids_ref, *, k: int,
+                       bpt: int):
     """One (1, TN) tile of one query's schedule, folded into its running
     (1, k) top-k. ``sched_ref`` is the scalar-prefetched tile schedule (a
     negative entry marks a padding slot that must not score); ``fill_ref``
     is its forward-filled twin the BlockSpec index maps read, so a padding
     slot revisits the PREVIOUS slab (no fresh DMA) instead of fetching
-    slab 0."""
+    slab 0. ``tags_ref`` (SMEM) holds one tag per layout block."""
     i = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -52,42 +55,22 @@ def _range_scan_kernel(sched_ref, fill_ref, qs_ref, qlo_ref, tag_ref,
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         ids_ref[...] = jnp.full_like(ids_ref, -1)
 
-    tag = tag_ref[0]
-    q = jax.lax.dynamic_index_in_dim(qs_ref[...], tag, axis=1,
-                                     keepdims=False)       # (1, d)
-    lo = jax.lax.dynamic_index_in_dim(qlo_ref[...], tag, axis=1,
-                                      keepdims=False)      # (1,)
-    x = x_ref[...].astype(jnp.float32)                     # (TN, d)
+    tag = tags_ref[fill_ref[i, j] // bpt]
+    q = qs_ref[0, pl.ds(tag, 1), :]                        # (1, d)
+    lo = qlo_ref[0, pl.ds(tag, 1), :]                      # (1, 1)
+    x = to_f32(x_ref[...])                                 # (TN, d)
     scores = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                                 precision=jax.lax.Precision.HIGHEST,
                                  preferred_element_type=jnp.float32) \
-        + lo[:, None]                                      # (1, TN)
-    col_ids = jnp.broadcast_to(rid_ref[...][None, :], scores.shape)
+        + lo                                               # (1, TN)
+    col_ids = rid_ref[0]                                   # (1, TN)
     ok = (col_ids >= 0) & (sched_ref[i, j] >= 0)
     scores = jnp.where(ok, scores, NEG_INF)
-
     # fold the tile into the running top-k: k rounds of max/mask over the
-    # concatenated (1, TN + k) candidates (same scheme as gleanvec_sq_topk).
-    cat_v = jnp.concatenate([vals_ref[...], scores], axis=1)
-    cat_i = jnp.concatenate([ids_ref[...], col_ids], axis=1)
-
-    def fold(r, carry):
-        cat_v, cat_i, out_v, out_i = carry
-        best = jnp.max(cat_v, axis=1)                      # (1,)
-        arg = jnp.argmax(cat_v, axis=1)                    # (1,)
-        bid = jnp.take_along_axis(cat_i, arg[:, None], axis=1)[:, 0]
-        out_v = jax.lax.dynamic_update_index_in_dim(out_v, best, r, 1)
-        out_i = jax.lax.dynamic_update_index_in_dim(out_i, bid, r, 1)
-        hit = (jax.lax.broadcasted_iota(jnp.int32, cat_v.shape, 1)
-               == arg[:, None])
-        cat_v = jnp.where(hit, NEG_INF, cat_v)
-        return cat_v, cat_i, out_v, out_i
-
-    out_v = jnp.zeros_like(vals_ref)
-    out_i = jnp.zeros_like(ids_ref)
-    _, _, out_v, out_i = jax.lax.fori_loop(
-        0, k, fold, (cat_v, cat_i, out_v, out_i))
-    vals_ref[...] = out_v
-    ids_ref[...] = out_i
+    # running (1, k) and the tile's (1, TN) candidates (as ip_topk).
+    vals, ids = fold_topk(vals_ref[0], ids_ref[0], scores, col_ids, k)
+    vals_ref[0] = vals
+    ids_ref[0] = ids
 
 
 @functools.partial(jax.jit, static_argnames=("k", "layout_block", "tn",
@@ -107,7 +90,10 @@ def ivf_scan_topk(q_scaled: jax.Array, q_lo: jax.Array, block_tags: jax.Array,
 
     Returns (vals (M, k) f32, ids (M, k) i32) with -inf winners' ids
     stripped to -1. ``tn`` must divide ``layout_block`` (the dispatcher in
-    ops.py guarantees it).
+    ops.py guarantees it). Per-query operands carry a unit axis --
+    ``(M, 1, k)`` outputs, ``(M, C, 1)`` offsets, ``(N // TN, 1, TN)`` ids
+    -- so every block's last two dims equal the array's (Mosaic's tiling
+    rule); the block tags and schedules ride in SMEM.
     """
     m, c, d = q_scaled.shape
     n = codes.shape[0]
@@ -115,6 +101,7 @@ def ivf_scan_topk(q_scaled: jax.Array, q_lo: jax.Array, block_tags: jax.Array,
         (n, layout_block, tn)
     s = sched.shape[1]
     bpt = layout_block // tn                  # tiles per layout block
+    kp = lanes(k)                  # running top-k block, lane-aligned
     # expand the block schedule to tile indices (still -1-padded)
     sched_t = jnp.where(
         sched[:, :, None] >= 0,
@@ -127,31 +114,39 @@ def ivf_scan_topk(q_scaled: jax.Array, q_lo: jax.Array, block_tags: jax.Array,
     sched_f = jnp.maximum(jax.lax.associative_scan(
         lambda a, b: jnp.where(b >= 0, b, a), sched_t, axis=1), 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(m, s * bpt),
-        in_specs=[
-            pl.BlockSpec((1, c, d), lambda i, j, sr, fr: (i, 0, 0)),
-            pl.BlockSpec((1, c), lambda i, j, sr, fr: (i, 0)),
-            pl.BlockSpec((1,), lambda i, j, sr, fr: (fr[i, j] // bpt,)),
-            pl.BlockSpec((tn,), lambda i, j, sr, fr: (fr[i, j],)),
-            pl.BlockSpec((tn, d), lambda i, j, sr, fr: (fr[i, j], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, j, sr, fr: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j, sr, fr: (i, 0)),
-        ],
-    )
-    vals, ids = pl.pallas_call(
-        functools.partial(_range_scan_kernel, k=k),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((m, k), jnp.float32),
-            jax.ShapeDtypeStruct((m, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(sched_t, sched_f, q_scaled, q_lo, block_tags,
-      row_ids.astype(jnp.int32), codes)
+    def run(sched_t, sched_f, q_scaled, q_lo):
+        mc = q_scaled.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(mc, s * bpt),
+            in_specs=[
+                pl.BlockSpec((1, c, d), lambda i, j, sr, fr, tg: (i, 0, 0)),
+                pl.BlockSpec((1, c, 1), lambda i, j, sr, fr, tg: (i, 0, 0)),
+                pl.BlockSpec((1, 1, tn),
+                             lambda i, j, sr, fr, tg: (fr[i, j], 0, 0)),
+                pl.BlockSpec((tn, d), lambda i, j, sr, fr, tg: (fr[i, j], 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, kp), lambda i, j, sr, fr, tg: (i, 0, 0)),
+                pl.BlockSpec((1, 1, kp), lambda i, j, sr, fr, tg: (i, 0, 0)),
+            ],
+        )
+        return pl.pallas_call(
+            functools.partial(_range_scan_kernel, k=k, bpt=bpt),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((mc, 1, kp), jnp.float32),
+                jax.ShapeDtypeStruct((mc, 1, kp), jnp.int32),
+            ],
+            interpret=interpret,
+        )(sched_t, sched_f, block_tags.astype(jnp.int32),
+          q_scaled.astype(jnp.float32),
+          q_lo.astype(jnp.float32)[..., None],
+          row_ids.astype(jnp.int32).reshape(n // tn, 1, tn), codes)
+
+    vals, ids = query_chunks(run, m, s * bpt, block_tags.shape[0],
+                             sched_t, sched_f, q_scaled, q_lo)
+    vals, ids = vals[:, 0, :k], ids[:, 0, :k]
     # the top-k fold can recycle an already-taken slot's id once everything
     # left is -inf; strip those ids like the gathered IVF path does.
     return vals, jnp.where(vals > NEG_INF, ids, -1)

@@ -1,6 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines: jax locks the device count on first init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST come first: jax locks the platform and device count on first
+# init. The dry-run compiles for a fake CPU mesh and never takes an
+# accelerator, so neither this process nor its --all children (which
+# inherit the environment) contend for a chip.
 """Multi-pod dry-run: lower + compile every (architecture x input-shape)
 cell on the production meshes and extract the roofline inputs.
 

@@ -68,6 +68,7 @@ import argparse
 import tempfile
 import threading
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +84,8 @@ from repro.index.protocol import replace
 from repro.serve import faults, frontend, lifecycle
 from repro.serve.engine import ServingEngine
 from repro.train import checkpoint
+from repro.utils import runtime
+from repro.utils.jax_compat import make_mesh
 
 
 def build_index(args, X, scorer, model):
@@ -223,7 +226,7 @@ def run_stream(args):
     step = (args.n - n0) // args.cycles
     ds = vectors.make_dataset("serve-stream", n=args.n, d=args.dim,
                               n_queries=max(512, args.batch * args.cycles),
-                              ood=True, seed=0)
+                              ood=True, seed=args.seed)
     X = jnp.asarray(ds.database)
     QT = np.asarray(ds.queries_test)
     rng = np.random.default_rng(0)
@@ -512,7 +515,7 @@ def run_frontend(args):
     diagram)."""
     ds = vectors.make_dataset("serve-frontend", n=args.n, d=args.dim,
                               n_queries=max(512, args.batch * 8), ood=True,
-                              seed=0)
+                              seed=args.seed)
     X = jnp.asarray(ds.database)
     QT = np.asarray(ds.queries_test)              # OOD (drifted) traffic
     rng = np.random.default_rng(0)
@@ -592,7 +595,113 @@ def run_frontend(args):
           f"(zero recompiles after warmup: True)")
 
 
-def main():
+# The DR model is fit on at most this many database rows, drawn uniformly
+# (the paper fits its k-means on a 1e5-row sample): the fit's (rows, D)
+# temporaries then stay bounded at deployment scale.
+FIT_ROWS = 1 << 18
+
+
+class SearchRun(NamedTuple):
+    """What :func:`run_search` built and measured: the warmed engine over
+    the served state, the dataset and model it came from, recall@10 of
+    ``engine.submit`` over the test queries, and the host-clock seconds of
+    the build (data excluded) and of the engine's compile + warmup."""
+
+    engine: ServingEngine
+    ds: vectors.VectorDataset
+    model: object
+    ids: np.ndarray
+    recall: float
+    build_s: float
+    compile_s: float
+
+
+def make_data(args) -> vectors.VectorDataset:
+    """The served collection: generated from ``--seed``, OOD queries."""
+    return vectors.make_dataset("serve", n=args.n, d=args.dim,
+                                n_queries=512, ood=True, seed=args.seed)
+
+
+def fit_model(args, ds: vectors.VectorDataset):
+    """The ``--mode``'s DR model (None for ``full``), fit on the learning
+    queries and at most :data:`FIT_ROWS` uniformly drawn database rows."""
+    if args.mode == "full":
+        return None
+    rows = ds.database
+    if rows.shape[0] > FIT_ROWS:
+        pick = np.random.default_rng(args.seed).choice(
+            rows.shape[0], FIT_ROWS, replace=False)
+        rows = rows[np.sort(pick)]
+    X, Q = jnp.asarray(rows), jnp.asarray(ds.queries_learn)
+    if args.mode.startswith("sphering"):
+        return lvs.fit(Q, X, args.d)
+    return gv.fit(jax.random.PRNGKey(0), Q, X, c=args.clusters, d=args.d)
+
+
+def shard_mesh(n_shards: int):
+    """A 1-D ``("shard",)`` mesh over the first ``n_shards`` devices when
+    that many exist, else None (the shards then run one after another on
+    one device)."""
+    if len(jax.devices()) < n_shards:
+        return None
+    return make_mesh((n_shards,), ("shard",),
+                     devices=jax.devices()[:n_shards])
+
+
+def build_state(args, X, model) -> msearch.ServingState:
+    """Artifacts + traversal for ``args`` over the database ``X`` (a host
+    array: the sharded build places its row shards device by device)."""
+    if args.shards:
+        # the stacked per-shard scorer IS the serving scorer -- don't also
+        # encode the whole database into a global one just to discard it
+        mesh = shard_mesh(args.shards)
+        index, stacked = distributed.build_sharded_index(
+            args.index, args.mode, X, model, n_shards=args.shards,
+            mesh=mesh, key=jax.random.PRNGKey(1), n_lists=args.lists,
+            nprobe=args.nprobe, reduced_probe=args.reduced_probe,
+            aligned=args.aligned, beam=args.beam, max_hops=args.max_hops,
+            expand=args.expand, fused_graph=args.fused_graph,
+            graph_kwargs={"r": args.graph_degree, "n_iters": 4, "seed": 0,
+                          "method": args.graph_build})
+        artifacts = msearch.SearchArtifacts(
+            scorer=stacked, x_full=distributed.place_rows(X, index),
+            model=model)
+        if args.host_rerank:
+            # spill-to-host: per-shard rerank tiers demote to host buffers
+            artifacts = msearch.demote_rerank_tier(artifacts,
+                                                   shards=args.shards)
+    else:
+        X = jnp.asarray(X)
+        artifacts = msearch.build_artifacts(args.mode, X, model)
+        index = build_index(args, X, artifacts.scorer, model)
+        if args.host_rerank:
+            artifacts = msearch.demote_rerank_tier(artifacts)
+    return msearch.make_state(artifacts, index=index)
+
+
+def run_search(args, ds=None, model=None) -> SearchRun:
+    """The one-shot serving run ``main`` performs: data (``ds`` when
+    given), model (``model`` when given), served state, a warmed
+    :class:`ServingEngine`, and recall@10 of the test queries against the
+    exact ground truth."""
+    ds = make_data(args) if ds is None else ds
+    t0 = time.perf_counter()
+    if model is None:
+        model = fit_model(args, ds)
+    state = build_state(args, ds.database, model)
+    jax.block_until_ready(state)
+    t1 = time.perf_counter()
+    kappa = 10 if args.mode == "full" else args.kappa
+    engine = ServingEngine(state, k=10, kappa=kappa, batch_size=args.batch,
+                           dim=args.dim)
+    t2 = time.perf_counter()
+    ids = engine.submit(ds.queries_test)
+    rec = metrics.recall_at_k(jnp.asarray(ids), jnp.asarray(ds.gt[:, :10]))
+    return SearchRun(engine=engine, ds=ds, model=model, ids=np.asarray(ids),
+                     recall=float(rec), build_s=t1 - t0, compile_s=t2 - t1)
+
+
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="gleanvec", choices=list(MODES))
     ap.add_argument("--index", default="flat",
@@ -674,7 +783,15 @@ def main():
                          "Lifecycle kinds need --stream; concurrency kinds "
                          "(stuck-worker / slow-refresh / poison-burst / "
                          "queue-overflow) need --frontend")
-    args = ap.parse_args()
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the generated collection (and of the fit "
+                         "sample of a search run)")
+    return ap
+
+
+def main(argv=None):
+    args = parser().parse_args(argv)
+    runtime.configure()
 
     if args.inject_fault in faults.FRONTEND_FAULTS and not args.frontend:
         raise SystemExit(f"--inject-fault {args.inject_fault} is a "
@@ -704,54 +821,14 @@ def main():
         raise SystemExit("--snapshot-dir/--restore/--inject-fault are "
                          "lifecycle flags: they need --stream")
 
-    ds = vectors.make_dataset("serve", n=args.n, d=args.dim, n_queries=512,
-                              ood=True, seed=0)
-    X = jnp.asarray(ds.database)
-    Q = jnp.asarray(ds.queries_learn)
-
-    if args.mode == "full":
-        model = None
-    elif args.mode.startswith("sphering"):
-        model = lvs.fit(Q, X, args.d)
-    else:
-        model = gv.fit(jax.random.PRNGKey(0), Q, X, c=args.clusters,
-                       d=args.d)
-    if args.shards:
-        # the stacked per-shard scorer IS the serving scorer -- don't also
-        # encode the whole database into a global one just to discard it
-        index, stacked = distributed.build_sharded_index(
-            args.index, args.mode, X, model, n_shards=args.shards,
-            key=jax.random.PRNGKey(1), n_lists=args.lists,
-            nprobe=args.nprobe, reduced_probe=args.reduced_probe,
-            aligned=args.aligned, beam=args.beam, max_hops=args.max_hops,
-            expand=args.expand, fused_graph=args.fused_graph,
-            graph_kwargs={"r": args.graph_degree, "n_iters": 4, "seed": 0,
-                          "method": args.graph_build})
-        artifacts = msearch.SearchArtifacts(scorer=stacked, x_full=X,
-                                            model=model)
-        if args.host_rerank:
-            # spill-to-host: per-shard rerank tiers demote to host buffers
-            artifacts = msearch.demote_rerank_tier(artifacts,
-                                                   shards=args.shards)
-    else:
-        artifacts = msearch.build_artifacts(args.mode, X, model)
-        index = build_index(args, X, artifacts.scorer, model)
-        if args.host_rerank:
-            artifacts = msearch.demote_rerank_tier(artifacts)
-    kappa = 10 if args.mode == "full" else args.kappa
-
-    engine = ServingEngine(msearch.make_state(artifacts, index=index),
-                           k=10, kappa=kappa, batch_size=args.batch,
-                           dim=args.dim)
-    ids = engine.submit(ds.queries_test)
-    rec = metrics.recall_at_k(jnp.asarray(ids), jnp.asarray(ds.gt[:, :10]))
-    s = engine.stats
+    run = run_search(args)
+    s = run.engine.stats
     placement = f"shards={args.shards}" if args.shards else "single"
     print(f"mode={args.mode} index={args.index} {placement} "
           f"n={args.n} D={args.dim} d={args.d} "
           f"reduced_probe={args.reduced_probe}")
     print(f"QPS={s.qps:.0f} p50={s.percentile_ms(50):.1f}ms "
-          f"p99={s.percentile_ms(99):.1f}ms recall@10={float(rec):.3f}")
+          f"p99={s.percentile_ms(99):.1f}ms recall@10={run.recall:.3f}")
 
 
 if __name__ == "__main__":

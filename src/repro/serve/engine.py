@@ -86,6 +86,31 @@ def _candidates_step(queries, state: msearch.ServingState, *, kappa: int):
     return cand, state
 
 
+class _Step:
+    """A compiled ``(queries, state) -> (out, state)`` serving step. With
+    donation the state round-trips through the program, where XLA aliases
+    it input -> output; without, the program returns ``out`` alone and the
+    state passes through on the host -- an echoed state that is not
+    donated is a copy of every leaf, the rerank store included, on every
+    call (and twice the state's device memory)."""
+
+    def __init__(self, fn, donate: bool):
+        self.donate = donate
+        self.jitted = jax.jit(fn if donate else (lambda q, s: fn(q, s)[0]),
+                              donate_argnums=(1,) if donate else ())
+
+    def __call__(self, queries, state):
+        if self.donate:
+            return self.jitted(queries, state)
+        return self.jitted(queries, state), state
+
+    def lower(self, queries, state):
+        return self.jitted.lower(queries, state)
+
+    def _cache_size(self) -> int:
+        return self.jitted._cache_size()
+
+
 def make_search_fn(artifacts, k: int, kappa: int, block: int = 4096,
                    index=None):
     """One-shot convenience: bind ``artifacts`` (+ optional Index-protocol
@@ -227,16 +252,14 @@ class ServingEngine:
         dummy = jnp.zeros((batch_size, dim), jnp.float32)
         if self._host is None:
             self._cand_fn = None
-            self._fn = jax.jit(
-                functools.partial(_engine_step, k=k, kappa=kappa),
-                donate_argnums=(1,) if donate else ())
+            self._fn = _Step(
+                functools.partial(_engine_step, k=k, kappa=kappa), donate)
             # warmup/compile with a dummy batch
             ids, self.state = self._fn(dummy, self.state)
         else:
             self._fn = None
-            self._cand_fn = jax.jit(
-                functools.partial(_candidates_step, kappa=kappa),
-                donate_argnums=(1,) if donate else ())
+            self._cand_fn = _Step(
+                functools.partial(_candidates_step, kappa=kappa), donate)
             # warmup compiles BOTH stages for this shape family
             cand, new_state = self._cand_fn(dummy, self.state)
             self.state = self._reattach(new_state)

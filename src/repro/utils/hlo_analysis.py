@@ -38,15 +38,9 @@ __all__ = ["analyze_hlo", "buffer_shapes", "normalize_cost", "HLOStats"]
 
 
 def normalize_cost(cost) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` output to one properties dict.
-
-    jax >= 0.6 returns the flat dict directly; jax 0.4/0.5 returns a
-    one-element list of per-device dicts. Callers index by property name
-    (``cost["flops"]``), so hand them the dict either way.
-    """
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return cost
+    """``Compiled.cost_analysis()`` as a plain properties dict (callers
+    index by property name, ``cost["flops"]``); None becomes ``{}``."""
+    return dict(cost or {})
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

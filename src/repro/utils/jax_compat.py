@@ -1,11 +1,9 @@
-"""Version-portability shims over the pinned jax.
+"""The one call site of jax's mesh and shard_map surface.
 
-The codebase is written against the modern jax surface (``jax.shard_map``,
-``jax.set_mesh``, ``jax.make_mesh(..., axis_types=...)``,
-``jax.sharding.AxisType``) but must also run on jax 0.4.x where those
-either live under ``jax.experimental`` or do not exist. Every call site
-routes through this module so the rest of the tree reads as if on current
-jax and the fallback logic lives in exactly one place.
+``analysis.source_rules.NoRawCompatAPIs`` keeps every use of
+``make_mesh`` / ``shard_map`` / ``set_mesh`` routed through here, so the
+settings the codebase relies on (Auto axis types, no replication check)
+are chosen in exactly one place.
 """
 from __future__ import annotations
 
@@ -14,51 +12,23 @@ import jax
 __all__ = ["make_mesh", "shard_map", "set_mesh"]
 
 
-def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the concept exists.
-
-    jax >= 0.5 wants explicit ``axis_types`` (Auto keeps the historical
-    implicit-sharding behavior); jax 0.4.x predates ``AxisType`` and its
-    ``make_mesh`` takes no such argument.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+def make_mesh(axis_shapes, axis_names, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axis types (the implicit-sharding
+    behavior every caller expects), over ``devices`` when given."""
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def shard_map(f, *, mesh=None, in_specs, out_specs):
-    """Replication-check-free shard_map on either API generation.
-
-    ``check_vma`` (jax >= 0.6) and ``check_rep`` (jax 0.4/0.5 experimental)
-    are the same knob under two names; both are disabled because the scan
-    carries in ``blocked_topk`` are axis-agnostic and fail the inference.
-    ``mesh=None`` uses the ambient mesh (installed via :func:`set_mesh`);
-    old jax requires an explicit mesh, so we resolve it from the active
-    ``with mesh:`` context there.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if mesh is None else {"mesh": mesh}
-        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if mesh is None:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        if mesh.empty:
-            raise ValueError("shard_map needs a mesh: pass mesh= or enter "
-                             "a repro.utils.jax_compat.set_mesh context")
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` without the replication check: the scan carries
+    in ``blocked_topk`` are axis-agnostic and fail its inference.
+    ``mesh=None`` uses the ambient mesh (installed via :func:`set_mesh`)."""
+    kwargs = {} if mesh is None else {"mesh": mesh}
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False, **kwargs)
 
 
 def set_mesh(mesh: jax.sharding.Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on current jax; on 0.4.x ``Mesh`` itself is the
-    context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)

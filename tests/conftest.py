@@ -34,17 +34,10 @@ class CompileCounter:
 def compile_counter():
     """Yields a live CompileCounter; the listener is removed on teardown."""
     from jax import monitoring
-    from jax._src import monitoring as _monitoring_impl
 
     counter = CompileCounter()
     monitoring.register_event_duration_secs_listener(counter._listener)
     try:
         yield counter
     finally:
-        unregister = getattr(
-            _monitoring_impl,
-            "_unregister_event_duration_listener_by_callback", None)
-        if unregister is not None:
-            unregister(counter._listener)
-        else:       # very old/new jax: drop every listener (tests only)
-            monitoring.clear_event_listeners()
+        monitoring.unregister_event_duration_listener(counter._listener)
