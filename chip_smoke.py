@@ -1,7 +1,6 @@
 """Smoke run of the served vector-search path on a TPU.
 
-    python chip_smoke.py                # one chip: kernels, flat, ivf, graph
-    python chip_smoke.py --four-chips   # four chips: the sharded phase only
+    python chip_smoke.py        # one chip: kernels, flat, ivf, graph
 
 Every phase builds its collection and index through the functions
 ``python -m repro.launch.serve`` runs (``serve.make_data`` / ``fit_model``
@@ -25,11 +24,6 @@ queries, k=10, kappa=100):
   the same collection (the ``ivf_scan`` kernel).
 * ``graph``: ``gleanvec-int8-sorted`` fused graph at n=1M, built on the
   device (``ip_topk`` self-join) and traversed with ``graph_scan``.
-
-Four chips (RQA-10M-shaped: D=768, d=160, C=48): ``gleanvec-int8`` on a
-flat ``ShardedIndex`` over a 4-device mesh at n=10M, with the bytes each
-device holds, and id parity of the same 4-shard index with and without
-the mesh at n=1M.
 """
 from __future__ import annotations
 
@@ -52,14 +46,17 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 # gave, on the CPU: flat 0.9996 at n=300,000 and at 3,000,000; ivf 0.9996
 # at n=300,000; graph 0.9994 at n=8,192 and 0.9367 at n=100,000 (graph
 # recall falls as n grows at a fixed beam). The floors leave room for 7M /
-# 1M rows: 0.95 for the scans, 0.80 for the graph; the sharded floor is
-# the scans'.
-RECALL_FLOOR = {"flat": 0.95, "ivf": 0.95, "graph": 0.80, "sharded": 0.95}
+# 1M rows: 0.95 for the scans, 0.80 for the graph.
+RECALL_FLOOR = {"flat": 0.95, "ivf": 0.95, "graph": 0.80}
 # fused vs gathered traversal: top-10 ids that must agree (the chip's f32
 # matmuls may split exact ties differently on the two paths)
 MIN_OVERLAP = 0.99
+# the fused ivf scan splits a batch into query chunks once two int32 probe
+# schedules per query (the probed lists' 512-row blocks) outgrow the SMEM
+# budget: at 7M rows in 48 clusters, 4 probed lists are ~2,300 blocks, so a
+# 64-query batch takes about three chunks
+IVF_CHUNKED_NPROBE = 4
 T2I = dict(dim=200, d=192, clusters=48)
-RQA = dict(dim=768, d=160, clusters=48)
 # T2I-10M is cut to 7M rows on one chip. The TPU's default layout of an
 # (n, 200) f32 array is column-major (200 lanes would pad to 256), so the
 # rerank's row gather relayouts the whole store into a padded row-major
@@ -68,9 +65,6 @@ RQA = dict(dim=768, d=160, clusters=48)
 # at 8M, too close to the chip's 15.75 GiB; 14.1 GB (13.1 GiB) at 7M.
 N_ONE_CHIP = 7_000_000
 N_GRAPH = 1_000_000
-# RQA-10M on four chips: 30.7 GB of f32 store, 7.7 GB per chip.
-N_FOUR_CHIPS = 10_000_000
-N_PARITY = 1_000_000
 
 
 def log(msg: str) -> None:
@@ -109,23 +103,36 @@ def has_kernel(engine) -> bool:
     return "tpu_custom_call" in program.text
 
 
-def device_bytes(devices) -> list:
-    """Bytes each device's allocator holds (``memory_stats``)."""
-    return [d.memory_stats()["bytes_in_use"] for d in devices]
-
-
 @jax.jit
 def _top10(queries, index, scorer):
-    from repro.utils.runtime import F32_MATMULS
-    with jax.default_matmul_precision(F32_MATMULS):
-        return index.search(queries, scorer, 10)[1]
+    return index.search(queries, scorer, 10)[1]
 
 
 def top10(index, queries, state):
     """Top-10 candidate ids of ``index``'s traversal alone (no rerank) over
-    the state's scorer, at the served path's matmul precision (compiled
-    once per index variant and query shape)."""
+    the state's scorer (compiled once per index variant and query
+    shape)."""
     return _top10(queries, index, state.artifacts.scorer)
+
+
+def count_chunks(module) -> list:
+    """Make ``module.query_chunks`` record, each time a kernel call is
+    traced, how many query chunks it split the batch into."""
+    inner, counts = module.query_chunks, []
+
+    def counted(run, *args):
+        n = [0]
+
+        def run_one(*rows):
+            n[0] += 1
+            return run(*rows)
+
+        out = inner(run_one, *args)
+        counts.append(n[0])
+        return out
+
+    module.query_chunks = counted
+    return counts
 
 
 def tripwires() -> None:
@@ -219,8 +226,7 @@ def phase_kernels(seed: int) -> None:
         t0 = time.perf_counter()
         kv, ki = jax.block_until_ready(kernel())
         secs = time.perf_counter() - t0
-        with jax.default_matmul_precision("highest"):
-            rv, ri = ref()
+        rv, ri = ref()
         ov = overlap(ki, ri)
         kv, rv = (np.sort(np.asarray(v), axis=1) for v in (kv, rv))
         live = rv > -1e38                  # slots a finite candidate filled
@@ -284,6 +290,7 @@ def phase_frontend(run, n_clients: int = 4, per_client: int = 16) -> None:
 
 def one_chip(seed: int, n_big: int, n_graph: int) -> None:
     from repro.index.protocol import replace
+    from repro.kernels.ivf_scan import ivf_scan
     from repro.launch import serve
 
     phase_kernels(seed)
@@ -309,17 +316,22 @@ def one_chip(seed: int, n_big: int, n_graph: int) -> None:
                       "ivf", "--aligned", "--reduced-probe")
     ivf = serve.run_search(args, ds=ds, model=model)   # same fit
     state = ivf.engine.state
-    # the gathered fine step holds (m, nprobe * list_len, d) rows; compare
-    # it with the fused one query at a time over two probed lists
-    fused = replace(state.index, nprobe=2)
+    # fused vs gathered on one served batch, at a probe count whose probe
+    # schedules overflow one kernel call's SMEM, so the fused scan splits
+    # the batch into query chunks. The gathered fine step holds
+    # (m, nprobe * list_len, d) rows: it runs one query at a time.
+    fused = replace(state.index, nprobe=IVF_CHUNKED_NPROBE)
     gathered = replace(fused, aligned_layout=False)
-    qt = jnp.asarray(ds.queries_test[:16])
-    pairs = [(top10(fused, qt[i:i + 1], state), top10(gathered, qt[i:i + 1],
-                                                      state))
-             for i in range(qt.shape[0])]
-    ivf_overlap = sum(overlap(a, b) for a, b in pairs) / len(pairs)
-    phase_report("ivf", args, ivf, has_kernel(ivf.engine), ivf_overlap,
-                 need_kernel=True)
+    qt = jnp.asarray(ds.queries_test[:ivf.engine.batch_size])
+    chunks = count_chunks(ivf_scan)
+    got = np.asarray(top10(fused, qt, state))
+    want = np.concatenate([top10(gathered, qt[i:i + 1], state)
+                           for i in range(qt.shape[0])])
+    log(f"[ivf] fused scan of {qt.shape[0]} queries at "
+        f"nprobe={fused.nprobe}: {chunks} query chunks")
+    check(chunks and max(chunks) > 1, "the fused ivf scan was not chunked")
+    phase_report("ivf", args, ivf, has_kernel(ivf.engine),
+                 overlap(got, want), need_kernel=True)
     del ivf, state, ds
     gc.collect()
 
@@ -335,61 +347,8 @@ def one_chip(seed: int, n_big: int, n_graph: int) -> None:
                  need_kernel=True)
 
 
-def four_chips(seed: int, n_big: int, n_parity: int) -> None:
-    from repro.core import search as msearch
-    from repro.index import distributed
-    from repro.launch import serve
-
-    check(len(jax.devices()) >= 4, "--four-chips needs four devices")
-    args = serve_args(n_big, "gleanvec-int8", RQA, seed, "--shards", "4")
-    t0 = time.perf_counter()
-    ds = serve.make_data(args)
-    log(f"[data] n={n_big} D={RQA['dim']} generated with exact ground "
-        f"truth in {time.perf_counter() - t0:.1f}s")
-    run = serve.run_search(args, ds=ds)
-    index = run.engine.state.index
-    check(index.mesh is not None, "the sharded index has no mesh")
-    devices = jax.devices()[:4]
-    state_bytes = dict.fromkeys(devices, 0)
-    for leaf in jax.tree.leaves(run.engine.state):
-        for shard in leaf.addressable_shards:
-            state_bytes[shard.device] += shard.data.nbytes
-    total = sum(state_bytes.values())
-    log(f"[sharded] served state bytes per device: "
-        f"{list(state_bytes.values())} (shares "
-        f"{[round(b / total, 4) for b in state_bytes.values()]})")
-    held = device_bytes(devices)
-    log(f"[sharded] memory_stats bytes_in_use per device: {held} "
-        f"(max/min {max(held) / min(held):.3f})")
-    phase_report("sharded", args, run, False, None)
-    check(max(held) / min(held) < 1.25, "shards are not spread evenly")
-    model = run.model
-    del run, index, ds
-
-    args = serve_args(n_parity, "gleanvec-int8", RQA, seed, "--shards", "4")
-    ds = serve.make_data(args)
-    out = {}
-    for name, mesh in (("mesh", serve.shard_mesh(4)), ("one chip", None)):
-        index, stacked = distributed.build_sharded_index(
-            "flat", args.mode, ds.database, model, mesh=mesh, n_shards=4)
-        art = msearch.SearchArtifacts(
-            scorer=stacked, x_full=distributed.place_rows(ds.database, index),
-            model=model)
-        state = msearch.make_state(art, index=index)
-        search = jax.jit(lambda q, s: msearch.state_search(q, s, 10, 100))
-        out[name] = np.asarray(search(ds.queries_test, state))
-        del index, stacked, art, state
-    same = float(np.mean(np.all(out["mesh"] == out["one chip"], axis=1)))
-    log(f"[sharded] n={n_parity}: ids with the mesh equal the mesh-free "
-        f"4-shard index's for {same:.4f} of {len(out['mesh'])} queries "
-        f"(overlap {overlap(out['mesh'], out['one chip']):.4f})")
-    check(same >= MIN_OVERLAP, "mesh and mesh-free shards disagree")
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--four-chips", action="store_true",
-                    help="run only the sharded phase on a 4-device mesh")
     ap.add_argument("--seed", type=int, default=0,
                     help="seed of every generated collection")
     args = ap.parse_args(argv)
@@ -403,10 +362,7 @@ def main(argv=None) -> int:
     log(f"[setup] {dev.device_kind} x{len(jax.devices())}, compile cache "
         f"{runtime.configure()}")
     t0 = time.perf_counter()
-    if args.four_chips:
-        four_chips(args.seed, n_big=N_FOUR_CHIPS, n_parity=N_PARITY)
-    else:
-        one_chip(args.seed, n_big=N_ONE_CHIP, n_graph=N_GRAPH)
+    one_chip(args.seed, n_big=N_ONE_CHIP, n_graph=N_GRAPH)
     log(f"[done] all phases passed in {time.perf_counter() - t0:.1f}s")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -69,7 +69,7 @@ def per_cluster_moments(x: jax.Array, tags: jax.Array, c: int) -> jax.Array:
     def add(acc, xb, tb, fresh):
         onehot = jax.nn.one_hot(tb, c, dtype=jnp.float32) * fresh[:, None]
         return acc + jnp.einsum("nc,nd,ne->cde", onehot, xb, xb,
-                                precision=jax.lax.Precision.HIGHEST)
+                                precision=linalg.F32)
 
     return linalg.fold_row_blocks(
         add, jnp.zeros((c, d_full, d_full), jnp.float32), [x, tags],
@@ -83,10 +83,10 @@ def fit_from_moments(centers: jax.Array, k_q: jax.Array,
     w, w_pinv = linalg.sphering_from_moment(k_q, rel_eps)
 
     def fit_one(k_x_c):
-        m = w @ k_x_c @ w
+        m = linalg.dot(linalg.dot(w, k_x_c), w)
         m = 0.5 * (m + m.T)
         p = linalg.topk_eigvecs(m, d)
-        return p @ w_pinv, p @ w
+        return linalg.dot(p, w_pinv), linalg.dot(p, w)
 
     a, b = jax.vmap(fit_one)(k_x_per_cluster)
     return GleanVecModel(centers=centers, a=a, b=b, w=w, w_pinv=w_pinv)
@@ -122,10 +122,10 @@ def assign_tags(model: GleanVecModel, database: jax.Array) -> jax.Array:
 def encode_rows(model: GleanVecModel, tags: jax.Array,
                 database: jax.Array) -> jax.Array:
     """``x_low_i = B_{tags_i} x_i`` for a slice of rows: gather the (d, D)
-    blocks then contract (f32 throughout, HIGHEST matmul precision)."""
+    blocks then contract (f32 throughout)."""
     return jnp.einsum("ndk,nk->nd", model.b[tags],
                       database.astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST)
+                      precision=linalg.F32)
 
 
 def encode_database(model: GleanVecModel, database: jax.Array):
@@ -146,14 +146,16 @@ def encode_database(model: GleanVecModel, database: jax.Array):
 
 def project_queries_eager(model: GleanVecModel, queries: jax.Array):
     """Alg. 4 preprocess: all views q_c = A_c q. (m, C, d)."""
-    return jnp.einsum("cdk,mk->mcd", model.a, queries.astype(jnp.float32))
+    return jnp.einsum("cdk,mk->mcd", model.a, queries.astype(jnp.float32),
+                      precision=linalg.F32)
 
 
 def inner_products_lazy(model: GleanVecModel, query: jax.Array,
                         tags: jax.Array, x_low: jax.Array) -> jax.Array:
     """Alg. 3: per-vector on-the-fly A_{c_i} q. query: (D,) -> (n,) scores."""
     a_sel = model.a[tags]                      # (n, d, D) gather
-    q_proj = jnp.einsum("ndk,k->nd", a_sel, query.astype(jnp.float32))
+    q_proj = jnp.einsum("ndk,k->nd", a_sel, query.astype(jnp.float32),
+                        precision=linalg.F32)
     return jnp.sum(q_proj * x_low, axis=-1)
 
 

@@ -63,10 +63,11 @@ def fit_from_moments(k_q: jax.Array, k_x: jax.Array, d: int,
     """
     w, w_pinv = linalg.sphering_from_moment(k_q, rel_eps)
     # eig(W K_X W) shares eigenvectors with the left singular vectors of W X.
-    m = w @ k_x @ w
+    m = linalg.dot(linalg.dot(w, k_x), w)
     m = 0.5 * (m + m.T)  # re-symmetrize for numerical stability
     p = linalg.topk_eigvecs(m, d)
-    return SpheringModel(a=p @ w_pinv, b=p @ w, p=p, w=w, w_pinv=w_pinv)
+    return SpheringModel(a=linalg.dot(p, w_pinv), b=linalg.dot(p, w), p=p,
+                         w=w, w_pinv=w_pinv)
 
 
 def fit(queries: jax.Array, database: jax.Array, d: int,
@@ -104,9 +105,9 @@ def full_rotation_model(queries: jax.Array, database: jax.Array,
 
 def project_queries(model: SpheringModel, queries: jax.Array) -> jax.Array:
     """f(q) = A q, batched: (m, D) -> (m, d)."""
-    return queries @ model.a.T
+    return linalg.dot(queries, model.a.T)
 
 
 def project_database(model: SpheringModel, database: jax.Array) -> jax.Array:
     """g(x) = B x, batched: (n, D) -> (n, d)."""
-    return database @ model.b.T
+    return linalg.dot(database, model.b.T)

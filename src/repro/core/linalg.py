@@ -4,6 +4,17 @@ All functions are jit-safe and operate on second-moment (Gram) matrices where
 possible so that the data-touching part is a single sharded einsum (psum under
 GSPMD) and the O(D^3) part runs replicated on D x D matrices (D <= ~1024 for
 every dataset in the paper).
+
+Every f32 contraction of the fit, the encoders, the query preparation,
+the scans and the rerank runs at :data:`F32` precision, named at each call
+site. The TPU's default f32 matmul rounds its inputs to bf16 in one pass.
+The GleanVec fit (sphering by K_Q^{-1/2}, whose small-eigenvalue
+directions carry large weights) and the int8 scorers' query offsets
+<A_c q, lo_c> (which cancel against the code products) both lose the
+near-tie order that recall depends on: flat recall@10 fell to 0.37 at 7M
+rows of T2I shape on a v5e. Emulating the rounding on the CPU at 1M rows
+gives 0.90 for the fit alone and 0.82 for the query preparation alone
+(1.0 in f32). XLA:CPU computes f32 matmuls in full either way.
 """
 from __future__ import annotations
 
@@ -11,6 +22,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "F32",
+    "dot",
     "second_moment",
     "cross_moment",
     "sphering_from_moment",
@@ -23,6 +36,14 @@ __all__ = [
     "fold_row_blocks",
 ]
 
+# matmul precision of every f32 contraction (see the module docstring)
+F32 = jax.lax.Precision.HIGHEST
+
+
+def dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` at :data:`F32` precision."""
+    return jnp.matmul(a, b, precision=F32)
+
 
 def second_moment(x: jax.Array, dtype=jnp.float32) -> jax.Array:
     """K = sum_i x_i x_i^T  for row-major data ``x: (n, D)`` -> ``(D, D)``.
@@ -30,13 +51,13 @@ def second_moment(x: jax.Array, dtype=jnp.float32) -> jax.Array:
     Under pjit with ``x`` row-sharded this lowers to a local einsum + psum.
     """
     x = x.astype(dtype)
-    return jnp.einsum("nd,ne->de", x, x)
+    return jnp.einsum("nd,ne->de", x, x, precision=F32)
 
 
 def cross_moment(x: jax.Array, w: jax.Array, dtype=jnp.float32) -> jax.Array:
     """Weighted moment ``sum_i w_i x_i x_i^T`` with per-row weights ``w: (n,)``."""
     x = x.astype(dtype)
-    return jnp.einsum("n,nd,ne->de", w.astype(dtype), x, x)
+    return jnp.einsum("n,nd,ne->de", w.astype(dtype), x, x, precision=F32)
 
 
 def safe_inv_sqrt_spectrum(s: jax.Array, rel_eps: float = 1e-4):
@@ -62,9 +83,9 @@ def sphering_from_moment(k_q: jax.Array, rel_eps: float = 1e-4):
     evals, u = jnp.linalg.eigh(k_q.astype(jnp.float32))
     evals = jnp.maximum(evals, 0.0)
     s = jnp.sqrt(evals)
-    w = (u * s[None, :]) @ u.T
+    w = dot(u * s[None, :], u.T)
     s_inv = safe_inv_sqrt_spectrum(s, rel_eps)
-    w_pinv = (u * s_inv[None, :]) @ u.T
+    w_pinv = dot(u * s_inv[None, :], u.T)
     return w, w_pinv
 
 
@@ -84,14 +105,14 @@ def orthonormalize_rows(a: jax.Array) -> jax.Array:
     """Project ``a: (d, D)`` onto the Stiefel manifold St(D, d) (row-orthonormal)
     via the polar decomposition: argmin_{U in St} ||U - a||_F."""
     u, _, vt = jnp.linalg.svd(a, full_matrices=False)
-    return u @ vt
+    return dot(u, vt)
 
 
 def polar(a: jax.Array) -> jax.Array:
     """Polar factor of ``a`` (same shape); the LMO direction over the unit
     spectral-norm ball (convex hull of the Stiefel manifold)."""
     u, _, vt = jnp.linalg.svd(a, full_matrices=False)
-    return u @ vt
+    return dot(u, vt)
 
 
 def block_rows(bytes_per_row: int, budget: int = 1 << 28) -> int:

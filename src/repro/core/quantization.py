@@ -19,6 +19,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.linalg import dot
+
 __all__ = ["SQDatabase", "ClusteredSQDatabase", "quantize",
            "quantize_per_cluster", "cluster_ranges", "cluster_steps",
            "quantize_rows", "dequantize", "quantized_inner_products"]
@@ -125,4 +127,5 @@ def quantized_inner_products(query: jax.Array, db: SQDatabase) -> jax.Array:
     ``query (d,)`` -> scores ``(n,)``.
     """
     q_scaled = query * db.delta
-    return db.codes.astype(jnp.float32) @ q_scaled + query @ db.lo
+    return (dot(db.codes.astype(jnp.float32), q_scaled)
+            + dot(query, db.lo))

@@ -277,7 +277,7 @@ class LinearScorer(NamedTuple):
 
     def prepare_queries(self, queries: jax.Array) -> jax.Array:
         q = queries.astype(jnp.float32)
-        return q if self.a is None else q @ self.a.T
+        return q if self.a is None else linalg.dot(q, self.a.T)
 
     def pad_rows(self, pad: int) -> "LinearScorer":
         if not pad:
@@ -288,12 +288,14 @@ class LinearScorer(NamedTuple):
 
     def score_block(self, qstate: jax.Array, start, block: int) -> jax.Array:
         blk = jax.lax.dynamic_slice_in_dim(self.x_low, start, block, axis=0)
-        return _mask_live_block(self.live, start, block, qstate @ blk.T)
+        return _mask_live_block(self.live, start, block,
+                                linalg.dot(qstate, blk.T))
 
     def score_ids(self, qstate: jax.Array, ids: jax.Array) -> jax.Array:
         vecs = self.x_low[ids]                          # (m, p, d)
         return _mask_live_ids(self.live, ids,
-                              jnp.einsum("mpd,md->mp", vecs, qstate))
+                              jnp.einsum("mpd,md->mp", vecs, qstate,
+                                         precision=linalg.F32))
 
     def shard_specs(self, axes) -> "LinearScorer":
         from jax.sharding import PartitionSpec as P
@@ -314,7 +316,7 @@ class LinearScorer(NamedTuple):
                     model=None) -> "LinearScorer":
         """Encode full-D ``rows`` into slots ``ids`` and mark them live."""
         rows = jnp.asarray(rows, jnp.float32)
-        enc = rows if self.a is None else rows @ model.b.T
+        enc = rows if self.a is None else linalg.dot(rows, model.b.T)
         return self._replace(
             x_low=self.x_low.at[ids].set(enc),
             live=_set_live(self.live, ids, True, self.n_rows))
@@ -333,9 +335,9 @@ class LinearScorer(NamedTuple):
         if self.a is None:
             return self     # exact scorer: stores the raw vectors
         if x_full is not None:
-            new_low = jnp.asarray(x_full, jnp.float32) @ model.b.T
+            new_low = linalg.dot(jnp.asarray(x_full, jnp.float32), model.b.T)
         else:
-            new_low = self.x_low @ transition.T
+            new_low = linalg.dot(self.x_low, transition.T)
         if pending is not None:
             new_low = jnp.where(pending[:, None], new_low, self.x_low)
         return self._replace(x_low=new_low, a=model.a)
@@ -351,7 +353,7 @@ class LinearScorer(NamedTuple):
         if model is None:
             raise ValueError("encode_centers on a reduced LinearScorer "
                              "needs the DR model (its B matrix)")
-        return LinearScorer(x_low=c @ model.b.T)
+        return LinearScorer(x_low=linalg.dot(c, model.b.T))
 
 
 class GleanVecScorer(NamedTuple):
@@ -371,7 +373,7 @@ class GleanVecScorer(NamedTuple):
             raise ValueError("GleanVecScorer without `a` cannot prepare "
                              "queries; pass precomputed (m, C, d) views")
         return jnp.einsum("cdk,mk->mcd", self.a,
-                          queries.astype(jnp.float32))
+                          queries.astype(jnp.float32), precision=linalg.F32)
 
     def pad_rows(self, pad: int) -> "GleanVecScorer":
         if not pad:
@@ -386,7 +388,8 @@ class GleanVecScorer(NamedTuple):
         tag = jax.lax.dynamic_slice_in_dim(self.tags, start, block, axis=0)
         q_sel = qstate[:, tag, :]                       # (m, block, d)
         return _mask_live_block(self.live, start, block,
-                                jnp.einsum("mbd,bd->mb", q_sel, blk))
+                                jnp.einsum("mbd,bd->mb", q_sel, blk,
+                                           precision=linalg.F32))
 
     def score_ids(self, qstate: jax.Array, ids: jax.Array) -> jax.Array:
         vecs = self.x_low[ids]                          # (m, p, d)
@@ -431,10 +434,11 @@ class GleanVecScorer(NamedTuple):
         untouched -- the k-means landmarks are fixed under streaming."""
         if x_full is not None:
             new_low = jnp.einsum("ndk,nk->nd", model.b[self.tags],
-                                 jnp.asarray(x_full, jnp.float32))
+                                 jnp.asarray(x_full, jnp.float32),
+                                 precision=linalg.F32)
         else:
             new_low = jnp.einsum("nij,nj->ni", transition[self.tags],
-                                 self.x_low)
+                                 self.x_low, precision=linalg.F32)
         if pending is not None:
             new_low = jnp.where(pending[:, None], new_low, self.x_low)
         return self._replace(x_low=new_low, a=model.a)
@@ -463,9 +467,9 @@ class QuantizedScorer(NamedTuple):
     def prepare_queries(self, queries: jax.Array) -> QuantQueryState:
         q = queries.astype(jnp.float32)
         if self.a is not None:
-            q = q @ self.a.T
+            q = linalg.dot(q, self.a.T)
         return QuantQueryState(q_scaled=q * self.delta[None, :],
-                               q_lo=q @ self.lo)
+                               q_lo=linalg.dot(q, self.lo))
 
     def pad_rows(self, pad: int) -> "QuantizedScorer":
         if not pad:
@@ -478,13 +482,15 @@ class QuantizedScorer(NamedTuple):
                     block: int) -> jax.Array:
         c = jax.lax.dynamic_slice_in_dim(self.codes, start, block, axis=0)
         return _mask_live_block(self.live, start, block,
-                                qstate.q_scaled @ c.astype(jnp.float32).T
+                                linalg.dot(qstate.q_scaled,
+                                           c.astype(jnp.float32).T)
                                 + qstate.q_lo[:, None])
 
     def score_ids(self, qstate: QuantQueryState, ids: jax.Array) -> jax.Array:
         c = self.codes[ids].astype(jnp.float32)         # (m, p, d)
         return _mask_live_ids(self.live, ids,
-                              jnp.einsum("mpd,md->mp", c, qstate.q_scaled)
+                              jnp.einsum("mpd,md->mp", c, qstate.q_scaled,
+                                         precision=linalg.F32)
                               + qstate.q_lo[:, None])
 
     def shard_specs(self, axes) -> "QuantizedScorer":
@@ -510,7 +516,7 @@ class QuantizedScorer(NamedTuple):
         scorer stores no ``bits`` field; sub-8-bit stores would need
         one)."""
         rows = jnp.asarray(rows, jnp.float32)
-        low = rows if self.a is None else rows @ model.b.T
+        low = rows if self.a is None else linalg.dot(rows, model.b.T)
         levels = 255
         enc = jnp.clip(jnp.round((low - self.lo[None, :])
                                  / self.delta[None, :]), 0,
@@ -530,9 +536,9 @@ class QuantizedScorer(NamedTuple):
         old_low = self.codes.astype(jnp.float32) * self.delta[None, :] \
             + self.lo[None, :]
         if x_full is not None:
-            new_low = jnp.asarray(x_full, jnp.float32) @ model.b.T
+            new_low = linalg.dot(jnp.asarray(x_full, jnp.float32), model.b.T)
         else:
-            new_low = old_low @ transition.T
+            new_low = linalg.dot(old_low, transition.T)
         if pending is not None:
             new_low = jnp.where(pending[:, None], new_low, old_low)
         db = quant.quantize(new_low, valid=self.live)
@@ -549,7 +555,7 @@ class QuantizedScorer(NamedTuple):
         if model is None:
             raise ValueError("encode_centers on a QuantizedScorer needs "
                              "the DR model (its B matrix)")
-        low = jnp.asarray(centers, jnp.float32) @ model.b.T
+        low = linalg.dot(jnp.asarray(centers, jnp.float32), model.b.T)
         return QuantizedScorer(codes=(low - self.lo[None, :])
                                / self.delta[None, :],
                                lo=self.lo, delta=self.delta)
@@ -573,9 +579,11 @@ class GleanVecQuantizedScorer(NamedTuple):
 
     def prepare_queries(self, queries: jax.Array) -> QuantQueryState:
         qv = jnp.einsum("cdk,mk->mcd", self.a,
-                        queries.astype(jnp.float32))    # (m, C, d)
+                        queries.astype(jnp.float32),
+                        precision=linalg.F32)                 # (m, C, d)
         return QuantQueryState(q_scaled=qv * self.delta[None],
-                               q_lo=jnp.einsum("mcd,cd->mc", qv, self.lo))
+                               q_lo=jnp.einsum("mcd,cd->mc", qv, self.lo,
+                                               precision=linalg.F32))
 
     def pad_rows(self, pad: int) -> "GleanVecQuantizedScorer":
         if not pad:
@@ -590,7 +598,8 @@ class GleanVecQuantizedScorer(NamedTuple):
         c = jax.lax.dynamic_slice_in_dim(self.codes, start, block, axis=0)
         tag = jax.lax.dynamic_slice_in_dim(self.tags, start, block, axis=0)
         q_sel = qstate.q_scaled[:, tag, :]              # (m, block, d)
-        scores = jnp.einsum("mbd,bd->mb", q_sel, c.astype(jnp.float32))
+        scores = jnp.einsum("mbd,bd->mb", q_sel, c.astype(jnp.float32),
+                            precision=linalg.F32)
         return _mask_live_block(self.live, start, block,
                                 scores + qstate.q_lo[:, tag])
 
@@ -645,10 +654,11 @@ class GleanVecQuantizedScorer(NamedTuple):
             + self.lo[self.tags]
         if x_full is not None:
             new_low = jnp.einsum("ndk,nk->nd", model.b[self.tags],
-                                 jnp.asarray(x_full, jnp.float32))
+                                 jnp.asarray(x_full, jnp.float32),
+                                 precision=linalg.F32)
         else:
             new_low = jnp.einsum("nij,nj->ni", transition[self.tags],
-                                 old_low)
+                                 old_low, precision=linalg.F32)
         if pending is not None:
             new_low = jnp.where(pending[:, None], new_low, old_low)
         db = quant.quantize_per_cluster(new_low, self.tags,
@@ -700,7 +710,7 @@ class SortedGleanVecScorer(NamedTuple):
                              "prepare queries; pass precomputed (m, C, d) "
                              "views")
         return jnp.einsum("cdk,mk->mcd", self.a,
-                          queries.astype(jnp.float32))
+                          queries.astype(jnp.float32), precision=linalg.F32)
 
     def pad_rows(self, pad: int) -> "SortedGleanVecScorer":
         if pad:
@@ -723,9 +733,10 @@ class SortedGleanVecScorer(NamedTuple):
         pm = jax.lax.dynamic_slice_in_dim(self.perm, start, block, axis=0)
         q_one, q_per_row = self._block_views(qstate, start, block)
         if q_one is not None:
-            scores = q_one @ blk.T                          # (m, block)
+            scores = linalg.dot(q_one, blk.T)              # (m, block)
         else:
-            scores = jnp.einsum("mbd,bd->mb", q_per_row, blk)
+            scores = jnp.einsum("mbd,bd->mb", q_per_row, blk,
+                                precision=linalg.F32)
         return jnp.where(pm[None, :] >= 0, scores, NEG_INF)
 
     def score_ids(self, qstate: jax.Array, ids: jax.Array) -> jax.Array:
@@ -841,11 +852,12 @@ class SortedGleanVecScorer(NamedTuple):
         if x_full is not None:
             safe = jnp.where(valid, self.perm, 0)
             full_rows = jnp.asarray(x_full, jnp.float32)[safe]
-            new_low = jnp.einsum("ndk,nk->nd", model.b[row_tags], full_rows)
+            new_low = jnp.einsum("ndk,nk->nd", model.b[row_tags], full_rows,
+                                 precision=linalg.F32)
             new_low = jnp.where(valid[:, None], new_low, 0.0)
         else:
             new_low = jnp.einsum("nij,nj->ni", transition[row_tags],
-                                 self.x_low)
+                                 self.x_low, precision=linalg.F32)
         if pending is not None:
             p_rows = valid & pending[jnp.where(valid, self.perm, 0)]
             new_low = jnp.where(p_rows[:, None], new_low, self.x_low)
@@ -880,9 +892,11 @@ class SortedGleanVecQuantizedScorer(NamedTuple):
 
     def prepare_queries(self, queries: jax.Array) -> QuantQueryState:
         qv = jnp.einsum("cdk,mk->mcd", self.a,
-                        queries.astype(jnp.float32))        # (m, C, d)
+                        queries.astype(jnp.float32),
+                        precision=linalg.F32)                 # (m, C, d)
         return QuantQueryState(q_scaled=qv * self.delta[None],
-                               q_lo=jnp.einsum("mcd,cd->mc", qv, self.lo))
+                               q_lo=jnp.einsum("mcd,cd->mc", qv, self.lo,
+                                               precision=linalg.F32))
 
     def pad_rows(self, pad: int) -> "SortedGleanVecQuantizedScorer":
         if pad:
@@ -899,13 +913,14 @@ class SortedGleanVecQuantizedScorer(NamedTuple):
             tag = jax.lax.dynamic_index_in_dim(self.block_tags, start // lb,
                                                keepdims=False)
             q_sel = jnp.take(qstate.q_scaled, tag, axis=1)  # (m, d)
-            scores = q_sel @ c.astype(jnp.float32).T \
+            scores = linalg.dot(q_sel, c.astype(jnp.float32).T) \
                 + jnp.take(qstate.q_lo, tag, axis=1)[:, None]
         else:
             tag = self.block_tags[(start + jnp.arange(block)) // lb]
             q_sel = qstate.q_scaled[:, tag, :]              # (m, block, d)
             scores = jnp.einsum("mbd,bd->mb", q_sel,
-                                c.astype(jnp.float32)) + qstate.q_lo[:, tag]
+                                c.astype(jnp.float32), precision=linalg.F32) \
+                + qstate.q_lo[:, tag]
         return jnp.where(pm[None, :] >= 0, scores, NEG_INF)
 
     def score_ids(self, qstate: QuantQueryState, ids: jax.Array) -> jax.Array:
@@ -1019,10 +1034,11 @@ class SortedGleanVecQuantizedScorer(NamedTuple):
         if x_full is not None:
             safe = jnp.where(valid, self.perm, 0)
             full_rows = jnp.asarray(x_full, jnp.float32)[safe]
-            new_low = jnp.einsum("ndk,nk->nd", model.b[row_tags], full_rows)
+            new_low = jnp.einsum("ndk,nk->nd", model.b[row_tags], full_rows,
+                                 precision=linalg.F32)
         else:
             new_low = jnp.einsum("nij,nj->ni", transition[row_tags],
-                                 old_low)
+                                 old_low, precision=linalg.F32)
         if pending is not None:
             p_rows = valid & pending[jnp.where(valid, self.perm, 0)]
             new_low = jnp.where(p_rows[:, None], new_low, old_low)
@@ -1049,7 +1065,7 @@ def exact_scorer(database: jax.Array) -> LinearScorer:
 
 def linear_scorer(model, database: jax.Array) -> LinearScorer:
     """LeanVec-Sphering: x_low = Bx, queries mapped by A."""
-    x_low = jnp.asarray(database, jnp.float32) @ model.b.T
+    x_low = linalg.dot(jnp.asarray(database, jnp.float32), model.b.T)
     return LinearScorer(x_low=x_low, a=model.a)
 
 
@@ -1063,7 +1079,7 @@ def quantized_scorer(model, database: jax.Array,
                      bits: int = 8) -> QuantizedScorer:
     """LeanVec-Sphering + int8 SQ of the reduced vectors (LeanVec paper's
     compounded compression: D*4 bytes -> d bytes per vector)."""
-    x_low = jnp.asarray(database, jnp.float32) @ model.b.T
+    x_low = linalg.dot(jnp.asarray(database, jnp.float32), model.b.T)
     db = quant.quantize(x_low, bits)
     return QuantizedScorer(codes=db.codes, lo=db.lo, delta=db.delta,
                            a=model.a)

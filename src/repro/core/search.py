@@ -19,10 +19,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import rerank_tier
+from repro.core import linalg, rerank_tier
 from repro.core import scorer as sc
 from repro.index.topk import NEG_INF
-from repro.utils.runtime import F32_MATMULS
 
 __all__ = ["SearchArtifacts", "ServingState", "build_artifacts",
            "build_artifacts_sphering", "build_artifacts_gleanvec",
@@ -141,11 +140,8 @@ def state_candidates(queries: jax.Array, state: ServingState,
     function serving engines compile when ``host_tier(artifacts)`` is
     set -- the host gather + :func:`rerank_candidates` run outside."""
     scorer = state.artifacts.scorer
-    # f32 matmuls traced into the step, whatever the caller's default
-    # (see repro.utils.runtime)
-    with jax.default_matmul_precision(F32_MATMULS):
-        qstate = state.index.prepare_queries(scorer, queries)
-        _, candidates = state.index.candidates(qstate, scorer, kappa)
+    qstate = state.index.prepare_queries(scorer, queries)
+    _, candidates = state.index.candidates(qstate, scorer, kappa)
     return candidates
 
 
@@ -179,7 +175,7 @@ def _rerank_math(q_full: jax.Array, cand_vecs: jax.Array,
     row with fewer than k live candidates pads its tail with -1 (never an
     arbitrary id)."""
     scores = jnp.einsum("mkd,md->mk", cand_vecs, q_full,
-                        precision=jax.lax.Precision.HIGHEST)
+                        precision=linalg.F32)
     scores = jnp.where(candidates >= 0, scores, NEG_INF)
     top = jax.lax.top_k(scores, k)[1]                    # (m, k)
     return jnp.take_along_axis(candidates, top, axis=1)
@@ -194,7 +190,7 @@ rerank_candidates = jax.jit(_rerank_math, static_argnames=("k",))
 
 def _rotate_queries(queries: jax.Array, artifacts: SearchArtifacts):
     return queries if artifacts.rerank_a is None \
-        else queries @ artifacts.rerank_a.T
+        else linalg.dot(queries, artifacts.rerank_a.T)
 
 
 def rerank(queries: jax.Array, artifacts: SearchArtifacts,
@@ -252,11 +248,10 @@ def multi_step_search(queries: jax.Array, artifacts: SearchArtifacts,
     ``kappa >= k`` trades accuracy for rerank cost.
     """
     scorer = artifacts.scorer
-    with jax.default_matmul_precision(F32_MATMULS):   # as state_candidates
-        if hasattr(index_search, "candidates"):     # Index protocol
-            qstate = index_search.prepare_queries(scorer, queries)
-            _, candidates = index_search.candidates(qstate, scorer, kappa)
-        else:                                       # legacy callable
-            q_low = scorer.prepare_queries(queries)
-            candidates = index_search(q_low, artifacts, kappa)
-        return rerank(queries, artifacts, candidates, k)
+    if hasattr(index_search, "candidates"):     # Index protocol
+        qstate = index_search.prepare_queries(scorer, queries)
+        _, candidates = index_search.candidates(qstate, scorer, kappa)
+    else:                                       # legacy callable
+        q_low = scorer.prepare_queries(queries)
+        candidates = index_search(q_low, artifacts, kappa)
+    return rerank(queries, artifacts, candidates, k)

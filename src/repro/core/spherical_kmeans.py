@@ -16,6 +16,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.linalg import dot
+
 __all__ = ["KMeansState", "normalize_rows", "kmeanspp_init", "fit", "assign"]
 
 
@@ -31,7 +33,7 @@ def normalize_rows(x: jax.Array, eps: float = 1e-12) -> jax.Array:
 
 def assign(x_unit: jax.Array, centers: jax.Array) -> jax.Array:
     """Cluster tags via Eq. (14)/(23): argmax_c <x_i, mu_c>. (n,) int32."""
-    sims = jnp.dot(x_unit, centers.T, precision=jax.lax.Precision.HIGHEST)
+    sims = dot(x_unit, centers.T)
     return jnp.argmax(sims, axis=-1).astype(jnp.int32)
 
 
@@ -47,11 +49,11 @@ def kmeanspp_init(key: jax.Array, x_unit: jax.Array, c: int) -> jax.Array:
         idx = jax.random.choice(key_i, n, p=probs)
         new = x_unit[idx]
         centers = centers.at[n_chosen].set(new)
-        d2 = 2.0 - 2.0 * (x_unit @ new)
+        d2 = 2.0 - 2.0 * dot(x_unit, new)
         return (centers, n_chosen + 1, jnp.minimum(min_d2, d2)), None
 
     centers0 = jnp.zeros((c, x_unit.shape[1]), x_unit.dtype).at[0].set(first)
-    d2_0 = 2.0 - 2.0 * (x_unit @ first)
+    d2_0 = 2.0 - 2.0 * dot(x_unit, first)
     (centers, _, _), _ = jax.lax.scan(
         body, (centers0, 1, d2_0), jax.random.split(key, c - 1))
     return centers
@@ -66,10 +68,10 @@ def fit(key: jax.Array, x: jax.Array, c: int, n_iters: int = 25) -> KMeansState:
     centers = kmeanspp_init(init_key, x_unit, c)
 
     def step(_, centers):
-        sims = x_unit @ centers.T                      # (n, C)
+        sims = dot(x_unit, centers.T)                  # (n, C)
         tags = jnp.argmax(sims, axis=-1)
         onehot = jax.nn.one_hot(tags, c, dtype=jnp.float32)
-        sums = onehot.T @ x_unit                       # Eq. (24) numerator
+        sums = dot(onehot.T, x_unit)                   # Eq. (24) numerator
         counts = jnp.sum(onehot, axis=0)
         # Empty clusters: re-seed at the globally worst-served points.
         worst = jnp.argsort(jnp.max(sims, axis=-1))[:c]
@@ -80,5 +82,5 @@ def fit(key: jax.Array, x: jax.Array, c: int, n_iters: int = 25) -> KMeansState:
         return normalize_rows(new)
 
     centers = jax.lax.fori_loop(0, n_iters, step, centers)
-    inertia = jnp.mean(jnp.max(x_unit @ centers.T, axis=-1))
+    inertia = jnp.mean(jnp.max(dot(x_unit, centers.T), axis=-1))
     return KMeansState(centers=centers, inertia=inertia)

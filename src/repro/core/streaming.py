@@ -188,8 +188,9 @@ def transition_matrix(state: StreamingState) -> jax.Array:
     prev = state.prev_bw
     new = state.model.b
     if prev.ndim == 3:
-        return jax.vmap(lambda nw, pv: nw @ jnp.linalg.pinv(pv))(new, prev)
-    return new @ jnp.linalg.pinv(prev)
+        return jax.vmap(
+            lambda nw, pv: linalg.dot(nw, jnp.linalg.pinv(pv)))(new, prev)
+    return linalg.dot(new, jnp.linalg.pinv(prev))
 
 
 def transition_condition(state: StreamingState) -> float:
@@ -227,9 +228,9 @@ def reproject(state: StreamingState, x_low: jax.Array,
         if tags is None:
             raise ValueError("per-cluster reprojection needs the rows' "
                              "cluster tags")
-        new = jnp.einsum("nij,nj->ni", t[tags], x_low)
+        new = jnp.einsum("nij,nj->ni", t[tags], x_low, precision=linalg.F32)
     else:
-        new = x_low @ t.T
+        new = linalg.dot(x_low, t.T)
     if pending is None:
         return new
     return jnp.where(pending[:, None], new, x_low)

@@ -38,7 +38,7 @@ from typing import Any, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core import scorer as sc
@@ -50,8 +50,8 @@ from repro.utils.jax_compat import shard_map
 
 __all__ = ["sharded_search", "make_sharded_search",
            "sharded_search_scorer", "make_sharded_search_scorer",
-           "stack_shards", "shard_rows", "place_rows", "ShardedIndex",
-           "build_sharded_index", "build_sharded_artifacts"]
+           "stack_shards", "ShardedIndex", "build_sharded_index",
+           "build_sharded_artifacts"]
 
 
 def _local_merge(queries, scorer, mesh: Mesh, axes, k: int, kappa: int,
@@ -144,70 +144,17 @@ def _pad_leaf(a: jax.Array, shape) -> jax.Array:
     return jnp.pad(a, pads, constant_values=val)
 
 
-def _shard_devices(mesh: Mesh, axes, n_shards: int):
-    """The device that holds shard ``s`` under spec ``P(axes)``, for every
-    ``s`` (one device per shard)."""
-    sharding = NamedSharding(mesh, P(tuple(axes)))
-    index = sharding.devices_indices_map((n_shards,))
-    by_shard = {idx[0].start or 0: dev for dev, idx in index.items()}
-    return [by_shard[s] for s in range(n_shards)]
-
-
-def shard_rows(database, n_shards: int, mesh: Optional[Mesh] = None,
-               axes: Sequence[str] = ()):
-    """The database's equal contiguous row shards. With a ``mesh``, shard
-    ``s`` is put straight on the device that serves it, so the whole
-    database never sits on one device (a host array stays on the host)."""
-    n = database.shape[0]
-    if n % n_shards:
-        raise ValueError(f"n={n} not divisible by n_shards={n_shards}")
-    per = n // n_shards
-    if mesh is None:
-        X = jnp.asarray(database, jnp.float32)
-        return [X[s * per:(s + 1) * per] for s in range(n_shards)]
-    # host -> shard device directly: staging each shard on the default
-    # device first would hold two shards there at once
-    devices = _shard_devices(mesh, axes or mesh.axis_names, n_shards)
-    return [jax.device_put(np.asarray(database[s * per:(s + 1) * per],
-                                      np.float32), devices[s])
-            for s in range(n_shards)]
-
-
-def place_rows(database, index: "ShardedIndex"):
-    """The (n, D) rerank store laid out like ``index``: row-sharded over
-    its mesh, shard ``s``'s rows on shard ``s``'s device (a plain device
-    array when the index has no mesh)."""
-    if index.mesh is None:
-        return jnp.asarray(database, jnp.float32)
-    rows = shard_rows(database, index.n_shards, index.mesh, index.axes)
-    sharding = NamedSharding(index.mesh, P(tuple(index.axes), None))
-    return jax.make_array_from_single_device_arrays(
-        (database.shape[0],) + tuple(database.shape[1:]), sharding, rows)
-
-
-def stack_shards(shards: Sequence[Any], mesh: Optional[Mesh] = None,
-                 axes: Sequence[str] = ()):
+def stack_shards(shards: Sequence[Any]):
     """Stack per-shard pytrees (same treedef) into ONE pytree whose leaves
     carry a leading shard axis, padding ragged leaves (per-shard sorted
     layouts, posting-list lengths, entry-point counts) to the maximum
     shape. The result is what shard_map distributes: spec ``P(axes)`` on
-    every leaf puts shard ``s``'s slice on device ``s``. With a ``mesh``
-    the stacked leaves are assembled in place from the per-shard slices,
-    each on its own device -- no device ever holds another shard's."""
-    n_shards = len(shards)
-    devices = None if mesh is None else _shard_devices(
-        mesh, axes or mesh.axis_names, n_shards)
+    every leaf puts shard ``s``'s slice on device ``s``."""
 
     def stack(*leaves):
         leaves = [jnp.asarray(x) for x in leaves]
         target = tuple(max(s) for s in zip(*[x.shape for x in leaves]))
-        padded = [_pad_leaf(x, target) for x in leaves]
-        if mesh is None:
-            return jnp.stack(padded)
-        sharding = NamedSharding(mesh, P(tuple(axes or mesh.axis_names)))
-        return jax.make_array_from_single_device_arrays(
-            (n_shards,) + target, sharding,
-            [jax.device_put(x[None], d) for x, d in zip(padded, devices)])
+        return jnp.stack([_pad_leaf(x, target) for x in leaves])
 
     return jax.tree_util.tree_map(stack, *shards)
 
@@ -329,8 +276,7 @@ class ShardedIndex:
             if hasattr(s_index, "refreshed"):
                 s_index = s_index.refreshed(s_scorer, model)
             subs.append(s_index)
-        return replace(self, sub_index=stack_shards(subs, self.mesh,
-                                                    self.axes))
+        return replace(self, sub_index=stack_shards(subs))
 
 
 register_index_pytree(ShardedIndex,
@@ -366,15 +312,18 @@ def build_sharded_index(kind: str, mode: str, database, model=None, *,
     gather-free fused beam step. Returns
     ``(sharded_index, stacked_scorer)``.
     """
-    n = database.shape[0]
+    X = jnp.asarray(database, jnp.float32)
+    n = X.shape[0]
     axes = tuple(shard_axes)
     if mesh is not None:
         axes = axes or tuple(mesh.axis_names)
         n_shards = int(np.prod([mesh.shape[a] for a in axes]))
     if not n_shards:
         raise ValueError("pass a mesh or an explicit n_shards")
-    rows = shard_rows(database, n_shards, mesh, axes)
+    if n % n_shards:
+        raise ValueError(f"n={n} not divisible by n_shards={n_shards}")
     per = n // n_shards
+    rows = [X[s * per:(s + 1) * per] for s in range(n_shards)]
     scorers = [sc.build_scorer(mode, r, model, block=sort_block)
                for r in rows]
 
@@ -385,13 +334,13 @@ def build_sharded_index(kind: str, mode: str, database, model=None, *,
             if not mode.endswith("-sorted"):
                 raise ValueError("aligned IVF sharding needs a sorted "
                                  f"scorer mode, got {mode!r}")
-            subs = ivf_mod.build_aligned_sharded(model, database, n_shards,
+            subs = ivf_mod.build_aligned_sharded(model, X, n_shards,
                                                  nprobe=nprobe)
         else:
             if key is None:
                 key = jax.random.PRNGKey(0)
-            subs = ivf_mod.build_sharded(key, jnp.asarray(database),
-                                         n_lists, n_shards, nprobe=nprobe)
+            subs = ivf_mod.build_sharded(key, X, n_lists, n_shards,
+                                         nprobe=nprobe)
         if reduced_probe:
             subs = [ivf_mod.with_reduced_centers(ix, s, model)
                     for ix, s in zip(subs, scorers)]
@@ -410,9 +359,9 @@ def build_sharded_index(kind: str, mode: str, database, model=None, *,
                          "one of ('flat', 'ivf', 'graph')")
 
     row_starts = jnp.arange(n_shards, dtype=jnp.int32) * per
-    return (ShardedIndex(sub_index=stack_shards(subs, mesh, axes),
+    return (ShardedIndex(sub_index=stack_shards(subs),
                          row_starts=row_starts, mesh=mesh, axes=axes),
-            stack_shards(scorers, mesh, axes))
+            stack_shards(scorers))
 
 
 def build_sharded_artifacts(kind: str, mode: str, database, model=None, *,
@@ -437,10 +386,9 @@ def build_sharded_artifacts(kind: str, mode: str, database, model=None, *,
 
     index, stacked = build_sharded_index(kind, mode, database, model,
                                          **kwargs)
+    x_full = jnp.asarray(database, jnp.float32)
     if spill_host:
-        x_full = rerank_tier.demote(np.asarray(database, np.float32),
+        x_full = rerank_tier.demote(np.asarray(x_full),
                                     shards=index.n_shards)
-    else:
-        x_full = place_rows(database, index)
     return index, SearchArtifacts(scorer=stacked, x_full=x_full,
                                   model=model)

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import gleanvec as gv, spherical_kmeans
+from repro.core import gleanvec as gv, linalg, spherical_kmeans
 from repro.core.scorer import LinearScorer
 from repro.index.protocol import (_offset_ids, register_index_pytree,
                                   replace, stacked_specs)
@@ -309,7 +309,7 @@ def coarse_scores(index: IVFIndex, qstate: IVFQueryState) -> jax.Array:
     centers, else one reduced-space ``score_block`` over all C centers
     (this is the function the probe-bandwidth assertion compiles)."""
     if index.center_scorer is None:
-        return qstate.q_coarse @ index.centers.T
+        return linalg.dot(qstate.q_coarse, index.centers.T)
     return index.center_scorer.score_block(qstate.qstate, 0, index.n_lists)
 
 

@@ -69,19 +69,19 @@ def scorer_scores(scorer, queries, *, use_pallas=None, interpret=False):
     import jax
     import jax.numpy as jnp
 
-    from repro.core import scorer as sc
+    from repro.core import linalg, scorer as sc
 
     kw = dict(use_pallas=use_pallas, interpret=interpret)
     if isinstance(scorer, sc.LinearScorer):
         q_low = scorer.prepare_queries(queries)
-        return _mask_live(scorer, q_low @ scorer.x_low.T)   # plain matmul
+        return _mask_live(scorer, linalg.dot(q_low, scorer.x_low.T))
     if isinstance(scorer, sc.GleanVecScorer):
         q_views = scorer.prepare_queries(queries)
         return _mask_live(scorer, gleanvec_ip(q_views, scorer.tags,
                                               scorer.x_low, **kw))
     if isinstance(scorer, sc.QuantizedScorer):
         q = queries.astype(jnp.float32)
-        q_low = q if scorer.a is None else q @ scorer.a.T
+        q_low = q if scorer.a is None else linalg.dot(q, scorer.a.T)
         return _mask_live(scorer, sq_dot(q_low, scorer.codes, scorer.lo,
                                          scorer.delta, **kw))
     if isinstance(scorer, sc.GleanVecQuantizedScorer):

@@ -12,4 +12,5 @@ def gleanvec_ip_ref(q_views: jax.Array, tags: jax.Array, x_low: jax.Array):
     """
     q_sel = q_views[:, tags, :]                       # (M, N, d)
     return jnp.einsum("mnd,nd->mn", q_sel.astype(jnp.float32),
-                      x_low.astype(jnp.float32))
+                      x_low.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)

@@ -18,7 +18,8 @@ def gleanvec_sq_ref(q_scaled: jax.Array, q_lo: jax.Array, tags: jax.Array,
     """``q_scaled (M, C, d)``, ``q_lo (M, C)``, ``tags (N,)``,
     ``codes (N, d)`` u8/f32 -> scores ``(M, N) f32``."""
     q_sel = q_scaled[:, tags, :].astype(jnp.float32)   # (M, N, d)
-    scores = jnp.einsum("mnd,nd->mn", q_sel, codes.astype(jnp.float32))
+    scores = jnp.einsum("mnd,nd->mn", q_sel, codes.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
     return scores + q_lo[:, tags]
 
 

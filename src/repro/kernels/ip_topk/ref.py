@@ -7,6 +7,7 @@ import jax.numpy as jnp
 
 def ip_topk_ref(q: jax.Array, x: jax.Array, k: int):
     """Exact MIPS top-k: ``q (M, d)``, ``x (N, d)`` -> (vals, ids) (M, k)."""
-    scores = q.astype(jnp.float32) @ x.astype(jnp.float32).T
+    scores = jnp.dot(q.astype(jnp.float32), x.astype(jnp.float32).T,
+                     precision=jax.lax.Precision.HIGHEST)
     vals, ids = jax.lax.top_k(scores, k)
     return vals, ids.astype(jnp.int32)

@@ -14,5 +14,6 @@ def sq_dot_ref(q: jax.Array, codes: jax.Array, lo: jax.Array,
     """
     qf = q.astype(jnp.float32)
     q_scaled = qf * delta[None, :]
-    return q_scaled @ codes.astype(jnp.float32).T \
-        + (qf @ lo)[:, None]
+    hi = jax.lax.Precision.HIGHEST
+    return jnp.dot(q_scaled, codes.astype(jnp.float32).T, precision=hi) \
+        + jnp.dot(qf, lo, precision=hi)[:, None]

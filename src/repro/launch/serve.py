@@ -85,7 +85,6 @@ from repro.serve import faults, frontend, lifecycle
 from repro.serve.engine import ServingEngine
 from repro.train import checkpoint
 from repro.utils import runtime
-from repro.utils.jax_compat import make_mesh
 
 
 def build_index(args, X, scorer, model):
@@ -638,40 +637,27 @@ def fit_model(args, ds: vectors.VectorDataset):
     return gv.fit(jax.random.PRNGKey(0), Q, X, c=args.clusters, d=args.d)
 
 
-def shard_mesh(n_shards: int):
-    """A 1-D ``("shard",)`` mesh over the first ``n_shards`` devices when
-    that many exist, else None (the shards then run one after another on
-    one device)."""
-    if len(jax.devices()) < n_shards:
-        return None
-    return make_mesh((n_shards,), ("shard",),
-                     devices=jax.devices()[:n_shards])
-
-
 def build_state(args, X, model) -> msearch.ServingState:
-    """Artifacts + traversal for ``args`` over the database ``X`` (a host
-    array: the sharded build places its row shards device by device)."""
+    """Artifacts + traversal for ``args`` over the database ``X``."""
+    X = jnp.asarray(X)
     if args.shards:
         # the stacked per-shard scorer IS the serving scorer -- don't also
         # encode the whole database into a global one just to discard it
-        mesh = shard_mesh(args.shards)
         index, stacked = distributed.build_sharded_index(
             args.index, args.mode, X, model, n_shards=args.shards,
-            mesh=mesh, key=jax.random.PRNGKey(1), n_lists=args.lists,
+            key=jax.random.PRNGKey(1), n_lists=args.lists,
             nprobe=args.nprobe, reduced_probe=args.reduced_probe,
             aligned=args.aligned, beam=args.beam, max_hops=args.max_hops,
             expand=args.expand, fused_graph=args.fused_graph,
             graph_kwargs={"r": args.graph_degree, "n_iters": 4, "seed": 0,
                           "method": args.graph_build})
-        artifacts = msearch.SearchArtifacts(
-            scorer=stacked, x_full=distributed.place_rows(X, index),
-            model=model)
+        artifacts = msearch.SearchArtifacts(scorer=stacked, x_full=X,
+                                            model=model)
         if args.host_rerank:
             # spill-to-host: per-shard rerank tiers demote to host buffers
             artifacts = msearch.demote_rerank_tier(artifacts,
                                                    shards=args.shards)
     else:
-        X = jnp.asarray(X)
         artifacts = msearch.build_artifacts(args.mode, X, model)
         index = build_index(args, X, artifacts.scorer, model)
         if args.host_rerank:

@@ -1,19 +1,12 @@
-"""Process-wide JAX settings of the program's entry points
-(``python -m repro.launch.serve``, ``chip_smoke.py``, ``benchmarks/run.py``).
+"""Where the program's entry points (``python -m repro.launch.serve``,
+``chip_smoke.py``, ``benchmarks/run.py``) keep JAX's persistent
+compilation cache.
 
-* f32 matmuls. The TPU's default f32 matmul rounds its inputs to bf16 in
-  one pass. The GleanVec fit (sphering by K_Q^{-1/2}, whose small-eigenvalue
-  directions carry large weights) and the int8 scorers' query offsets
-  <A_c q, lo_c> (which cancel against the code products) both lose the
-  near-tie order that recall depends on: flat recall@10 fell to 0.37 at
-  7M rows of T2I shape on a v5e. Emulating the rounding on the CPU at 1M
-  rows gives 0.90 for the fit alone and 0.82 for the query preparation
-  alone (1.0 in f32). XLA:CPU computes f32 matmuls in full either way.
-* The persistent compilation cache. A run on a fresh machine compiles
-  every program from cold; with the cache on, a later process in the same
-  checkout (or on a machine that mounts the same cache directory) reads
-  them back instead. The cache directory is part of each entry's key, so
-  it must not move between runs: no temp, pid or time-derived paths.
+A run on a fresh machine compiles every program from cold; with the cache
+on, a later process in the same checkout (or on a machine that mounts the
+same cache directory) reads them back instead. The cache directory is part
+of each entry's key, so it must not move between runs: no temp, pid or
+time-derived paths.
 """
 from __future__ import annotations
 
@@ -22,23 +15,18 @@ from pathlib import Path
 
 import jax
 
-__all__ = ["CHECKOUT_CACHE", "F32_MATMULS", "configure"]
+__all__ = ["CHECKOUT_CACHE", "configure"]
 
 # <checkout>/.jax_cache (gitignored): this file is src/repro/utils/*.py
 CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
-# matmul precision of every f32 computation (see the module docstring);
-# the served step also traces under it whoever calls it (core/search.py)
-F32_MATMULS = "highest"
 
 
 def configure() -> str:
-    """Make f32 matmuls full-precision for the process, turn the
-    persistent compilation cache on, and return the cache's directory.
+    """Turn the persistent compilation cache on and return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it at
     start-up and no other directory is set here. Otherwise the cache goes
     to the fixed :data:`CHECKOUT_CACHE`."""
-    jax.config.update("jax_default_matmul_precision", F32_MATMULS)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
