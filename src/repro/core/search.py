@@ -140,7 +140,8 @@ def state_candidates(queries: jax.Array, state: ServingState,
     function serving engines compile when ``host_tier(artifacts)`` is
     set -- the host gather + :func:`rerank_candidates` run outside."""
     scorer = state.artifacts.scorer
-    qstate = state.index.prepare_queries(scorer, queries)
+    with jax.named_scope("search.prepare"):
+        qstate = state.index.prepare_queries(scorer, queries)
     _, candidates = state.index.candidates(qstate, scorer, kappa)
     return candidates
 
@@ -174,11 +175,12 @@ def _rerank_math(q_full: jax.Array, cand_vecs: jax.Array,
     stable tie-break keeps real ids ahead of equal-scoring padding, so a
     row with fewer than k live candidates pads its tail with -1 (never an
     arbitrary id)."""
-    scores = jnp.einsum("mkd,md->mk", cand_vecs, q_full,
-                        precision=linalg.F32)
-    scores = jnp.where(candidates >= 0, scores, NEG_INF)
-    top = jax.lax.top_k(scores, k)[1]                    # (m, k)
-    return jnp.take_along_axis(candidates, top, axis=1)
+    with jax.named_scope("search.rerank"):
+        scores = jnp.einsum("mkd,md->mk", cand_vecs, q_full,
+                            precision=linalg.F32)
+        scores = jnp.where(candidates >= 0, scores, NEG_INF)
+        top = jax.lax.top_k(scores, k)[1]                # (m, k)
+        return jnp.take_along_axis(candidates, top, axis=1)
 
 
 # The small second-stage program of the two-level pipeline: reranks the
@@ -216,10 +218,11 @@ def rerank(queries: jax.Array, artifacts: SearchArtifacts,
     """
     store = host_tier(artifacts)
     if store is None:
-        safe = jnp.where(candidates >= 0, candidates, 0)
-        cand_vecs = artifacts.x_full[safe]               # (m, kappa, D)
-        return _rerank_math(_rotate_queries(queries, artifacts), cand_vecs,
-                            candidates, k)
+        with jax.named_scope("search.rerank"):
+            safe = jnp.where(candidates >= 0, candidates, 0)
+            cand_vecs = artifacts.x_full[safe]           # (m, kappa, D)
+            q_full = _rotate_queries(queries, artifacts)
+        return _rerank_math(q_full, cand_vecs, candidates, k)
     if isinstance(candidates, jax.core.Tracer):
         raise TypeError(
             "rerank over a host-tier x_full cannot run inside jit: the "
@@ -249,9 +252,11 @@ def multi_step_search(queries: jax.Array, artifacts: SearchArtifacts,
     """
     scorer = artifacts.scorer
     if hasattr(index_search, "candidates"):     # Index protocol
-        qstate = index_search.prepare_queries(scorer, queries)
+        with jax.named_scope("search.prepare"):
+            qstate = index_search.prepare_queries(scorer, queries)
         _, candidates = index_search.candidates(qstate, scorer, kappa)
     else:                                       # legacy callable
-        q_low = scorer.prepare_queries(queries)
+        with jax.named_scope("search.prepare"):
+            q_low = scorer.prepare_queries(queries)
         candidates = index_search(q_low, artifacts, kappa)
     return rerank(queries, artifacts, candidates, k)

@@ -47,13 +47,15 @@ def scan_scorer(scorer, qstate, k: int, block: int = 4096):
     n = scorer.n_rows
     m = batch_of(qstate)
     block = getattr(scorer, "layout_block", block)
-    padded = scorer.pad_rows((-n) % block)
+    with jax.named_scope("search.scan"):
+        padded = scorer.pad_rows((-n) % block)
 
     def score_block(start):
         return padded.score_block(qstate, start, block)
 
     vals, ids = topk.blocked_topk(score_block, n, k, block, m)
-    return vals, scorer.translate_ids(ids)
+    with jax.named_scope("search.merge"):
+        return vals, scorer.translate_ids(ids)
 
 
 def search_scorer(queries: jax.Array, scorer, k: int, block: int = 4096):

@@ -31,6 +31,11 @@ clusters' slabs stream through the fused single-tag kernel with a running
 top-k in VMEM, no ``(m, nprobe*L)`` candidate-id or score matrix ever
 reaches HBM, and the posting lists themselves are never read (they are
 kept only so streaming ``insert_ids`` / ``remove_ids`` stay available).
+
+Both fine steps name their phases for the profiler (``jax.named_scope``):
+``search.probe`` (coarse scores and the ``nprobe`` top-k),
+``search.scan`` (the fine scoring) and ``search.merge`` (the gathered
+path's final top-k).
 """
 from __future__ import annotations
 
@@ -320,9 +325,11 @@ def _probe_and_scan(qstate: IVFQueryState, scorer, index: IVFIndex,
     slabs through the scorer's gather-free ``scan_lists``. ``index.lists``
     is never read (XLA drops the unused leaf), so the posting-list HBM
     footprint vanishes from the compiled sorted serving path."""
-    coarse = coarse_scores(index, qstate)                   # (m, C)
-    _, probe = jax.lax.top_k(coarse, index.nprobe)          # (m, nprobe)
-    return scorer.scan_lists(qstate.qstate, probe, k)
+    with jax.named_scope("search.probe"):
+        coarse = coarse_scores(index, qstate)               # (m, C)
+        _, probe = jax.lax.top_k(coarse, index.nprobe)      # (m, nprobe)
+    with jax.named_scope("search.scan"):
+        return scorer.scan_lists(qstate.qstate, probe, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -330,17 +337,21 @@ def _probe_and_score(qstate: IVFQueryState, scorer, index: IVFIndex,
                      k: int):
     """Probe ``index.nprobe`` lists per query, score via the scorer."""
     m = jax.tree_util.tree_leaves(qstate.qstate)[0].shape[0]
-    coarse = coarse_scores(index, qstate)                   # (m, C)
-    _, probe = jax.lax.top_k(coarse, index.nprobe)          # (m, nprobe)
-    cand = index.lists[probe].reshape(m, -1)                # (m, nprobe*L)
-    safe = jnp.where(cand >= 0, cand, 0)
-    scores = scorer.score_ids(qstate.qstate, safe)          # (m, nprobe*L)
-    scores = jnp.where(cand >= 0, scores, NEG_INF)
-    vals, sel = jax.lax.top_k(scores, k)
-    ids = jnp.take_along_axis(cand, sel, axis=1)
-    # -inf winners are padding slots or tombstoned (dead) rows a streaming
-    # store masked; strip their ids so the rerank never resurrects them.
-    return vals, jnp.where(vals > NEG_INF, ids, -1)
+    with jax.named_scope("search.probe"):
+        coarse = coarse_scores(index, qstate)               # (m, C)
+        _, probe = jax.lax.top_k(coarse, index.nprobe)      # (m, nprobe)
+    with jax.named_scope("search.scan"):
+        cand = index.lists[probe].reshape(m, -1)            # (m, nprobe*L)
+        safe = jnp.where(cand >= 0, cand, 0)
+        scores = scorer.score_ids(qstate.qstate, safe)      # (m, nprobe*L)
+        scores = jnp.where(cand >= 0, scores, NEG_INF)
+    with jax.named_scope("search.merge"):
+        vals, sel = jax.lax.top_k(scores, k)
+        ids = jnp.take_along_axis(cand, sel, axis=1)
+        # -inf winners are padding slots or tombstoned (dead) rows a
+        # streaming store masked; strip their ids so the rerank never
+        # resurrects them.
+        return vals, jnp.where(vals > NEG_INF, ids, -1)
 
 
 def search_scorer(queries: jax.Array, scorer, index: IVFIndex, k: int,

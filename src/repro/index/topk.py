@@ -35,20 +35,25 @@ def blocked_topk(score_block_fn: Callable, n: int, k: int, block: int,
     [start, start+block). Scores for ids >= n must already be -inf-masked by
     the caller (or n % block == 0).
     Returns (values, ids): (batch, k) each.
+
+    The loop, its id bookkeeping and the merges are the profiler scope
+    ``search.merge``; each block's scoring inside it is ``search.scan``.
     """
     n_blocks = -(-n // block)
 
     def body(carry, i):
         best_v, best_i = carry
         start = i * block
-        scores = score_block_fn(start)
+        with jax.named_scope("search.scan"):
+            scores = score_block_fn(start)
         ids = start + jax.lax.broadcasted_iota(jnp.int32, (batch, block), 1)
         valid = ids < n
         scores = jnp.where(valid, scores, NEG_INF)
         best_v, best_i = merge_topk(best_v, best_i, scores, ids, k)
         return (best_v, best_i), None
 
-    init = (jnp.full((batch, k), NEG_INF),
-            jnp.full((batch, k), -1, jnp.int32))
-    (vals, ids), _ = jax.lax.scan(body, init, jnp.arange(n_blocks))
+    with jax.named_scope("search.merge"):
+        init = (jnp.full((batch, k), NEG_INF),
+                jnp.full((batch, k), -1, jnp.int32))
+        (vals, ids), _ = jax.lax.scan(body, init, jnp.arange(n_blocks))
     return vals, ids

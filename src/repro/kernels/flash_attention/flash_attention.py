@@ -110,6 +110,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         functools.partial(_flash_kernel, bq=bq, bk=bk, causal=causal,
                           window=window, n_kv_blocks=n_kv_blocks,
                           scale=scale),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda bh, i, j: (bh, i, 0)),

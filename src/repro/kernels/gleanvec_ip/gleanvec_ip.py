@@ -69,6 +69,7 @@ def gleanvec_ip(q_views: jax.Array, tags: jax.Array, x_low: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_gleanvec_ip_kernel, c=c),
+        name="gleanvec_ip",
         grid=grid,
         in_specs=[
             pl.BlockSpec((tm, c, d), lambda i, j: (i, 0, 0)),

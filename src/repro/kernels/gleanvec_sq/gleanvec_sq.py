@@ -136,8 +136,9 @@ def _pad0(x, pad, fill=0):
     return jnp.pad(x, widths, constant_values=fill)
 
 
-def _call(kernel, q_scaled, q_lo, tags, codes, row_ids, *, layout_block: int,
-          tm: int, tn: int, out_specs, out_shape, interpret: bool):
+def _call(kernel, q_scaled, q_lo, tags, codes, row_ids, *, name: str,
+          layout_block: int, tm: int, tn: int, out_specs, out_shape,
+          interpret: bool):
     """Shared pallas_call plumbing of the dense and top-k variants: pads M
     (and N on the gathered layout), lays the operands out for Mosaic and
     picks the grid spec of the layout."""
@@ -186,7 +187,7 @@ def _call(kernel, q_scaled, q_lo, tags, codes, row_ids, *, layout_block: int,
         args = (q_scaled, q_lo, tag_rows, *row_ops, codes)
     return pl.pallas_call(
         functools.partial(kernel, c=c, bpt=bpt, sorted_layout=srt),
-        grid_spec=grid_spec, out_shape=out_shape(rows),
+        name=name, grid_spec=grid_spec, out_shape=out_shape(rows),
         interpret=interpret)(*args)
 
 
@@ -206,7 +207,7 @@ def gleanvec_sq(q_scaled: jax.Array, q_lo: jax.Array, tags: jax.Array,
     m, n = q_scaled.shape[0], codes.shape[0]
     tm_ = min(tm, max(1, m))
     out = _call(_dense_kernel, q_scaled, q_lo, tags, codes, None,
-                layout_block=layout_block, tm=tm, tn=tn,
+                name="gleanvec_sq", layout_block=layout_block, tm=tm, tn=tn,
                 out_specs=pl.BlockSpec((tm_, tn), lambda i, j, *_: (i, j)),
                 out_shape=lambda rows: jax.ShapeDtypeStruct(rows,
                                                             jnp.float32),
@@ -235,8 +236,8 @@ def gleanvec_sq_topk(q_scaled: jax.Array, q_lo: jax.Array, tags: jax.Array,
     spec = pl.BlockSpec((tm_, kp), lambda i, j, *_: (i, 0))
     vals, ids = _call(
         functools.partial(_topk_kernel, k=k), q_scaled, q_lo, tags, codes,
-        row_ids, layout_block=layout_block, tm=tm, tn=tn,
-        out_specs=[spec, spec],
+        row_ids, name="gleanvec_sq_topk", layout_block=layout_block, tm=tm,
+        tn=tn, out_specs=[spec, spec],
         out_shape=lambda rows: [
             jax.ShapeDtypeStruct((rows[0], kp), jnp.float32),
             jax.ShapeDtypeStruct((rows[0], kp), jnp.int32)],

@@ -179,6 +179,7 @@ def graph_scan_beam_step(q_scaled: jax.Array, q_lo: jax.Array,
         )
         return pl.pallas_call(
             functools.partial(_beam_step_kernel, tn=tn, bpt=bpt),
+            name="graph_scan_beam_step",
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((mc, 1, b), jnp.float32),

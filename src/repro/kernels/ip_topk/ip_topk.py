@@ -76,6 +76,7 @@ def ip_topk(q: jax.Array, x: jax.Array, k: int, tm: int = 128, tn: int = 512,
 
     vals, ids = pl.pallas_call(
         functools.partial(_ip_topk_kernel, k=k, tn=tn, n_total=n),
+        name="ip_topk",
         grid=grid,
         in_specs=[
             pl.BlockSpec((tm, d), lambda i, j: (i, 0)),

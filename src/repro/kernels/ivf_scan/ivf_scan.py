@@ -133,6 +133,7 @@ def ivf_scan_topk(q_scaled: jax.Array, q_lo: jax.Array, block_tags: jax.Array,
         )
         return pl.pallas_call(
             functools.partial(_range_scan_kernel, k=k, bpt=bpt),
+            name="ivf_scan_topk",
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((mc, 1, kp), jnp.float32),

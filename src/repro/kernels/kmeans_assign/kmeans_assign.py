@@ -42,6 +42,7 @@ def kmeans_assign(x: jax.Array, centers: jax.Array, tn: int = 1024,
 
     tags, sims = pl.pallas_call(
         _kmeans_assign_kernel,
+        name="kmeans_assign",
         grid=grid,
         in_specs=[
             pl.BlockSpec((tn, d), lambda i: (i, 0)),

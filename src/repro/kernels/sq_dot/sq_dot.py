@@ -49,6 +49,7 @@ def sq_dot(q: jax.Array, codes: jax.Array, lo: jax.Array, delta: jax.Array,
 
     out = pl.pallas_call(
         _sq_dot_kernel,
+        name="sq_dot",
         grid=grid,
         in_specs=[
             pl.BlockSpec((tm, d), lambda i, j: (i, 0)),

@@ -65,6 +65,7 @@ asserting the frontend keeps answering within SLO or sheds predictably
 from __future__ import annotations
 
 import argparse
+import contextlib
 import tempfile
 import threading
 import time
@@ -772,13 +773,24 @@ def parser() -> argparse.ArgumentParser:
     ap.add_argument("--seed", type=int, default=0,
                     help="seed of the generated collection (and of the fit "
                          "sample of a search run)")
+    ap.add_argument("--trace-dir", default=None,
+                    help="write a profiler trace of the run to this "
+                         "directory (off by default): the serve.* host "
+                         "spans and the search.* scopes of the step")
     return ap
 
 
 def main(argv=None):
     args = parser().parse_args(argv)
     runtime.configure()
+    with (jax.profiler.trace(args.trace_dir) if args.trace_dir
+          else contextlib.nullcontext()):
+        _run(args)
 
+
+def _run(args):
+    """The run ``args`` ask for: the frontend, the streaming lifecycle, or
+    one search run."""
     if args.inject_fault in faults.FRONTEND_FAULTS and not args.frontend:
         raise SystemExit(f"--inject-fault {args.inject_fault} is a "
                          "concurrency drill: it needs --frontend")

@@ -26,6 +26,7 @@ from typing import Deque, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import search as msearch
 
@@ -94,10 +95,16 @@ class _Step:
     donated is a copy of every leaf, the rerank store included, on every
     call (and twice the state's device memory)."""
 
-    def __init__(self, fn, donate: bool):
+    def __init__(self, fn, donate: bool, name: str):
         self.donate = donate
-        self.jitted = jax.jit(fn if donate else (lambda q, s: fn(q, s)[0]),
-                              donate_argnums=(1,) if donate else ())
+
+        def step(queries, state):
+            out = fn(queries, state)
+            return out if donate else out[0]
+
+        # the compiled module is ``jit_<name>``: a stable name for traces
+        step.__name__ = step.__qualname__ = name
+        self.jitted = jax.jit(step, donate_argnums=(1,) if donate else ())
 
     def __call__(self, queries, state):
         if self.donate:
@@ -253,13 +260,15 @@ class ServingEngine:
         if self._host is None:
             self._cand_fn = None
             self._fn = _Step(
-                functools.partial(_engine_step, k=k, kappa=kappa), donate)
+                functools.partial(_engine_step, k=k, kappa=kappa), donate,
+                "serve_step")
             # warmup/compile with a dummy batch
             ids, self.state = self._fn(dummy, self.state)
         else:
             self._fn = None
             self._cand_fn = _Step(
-                functools.partial(_candidates_step, kappa=kappa), donate)
+                functools.partial(_candidates_step, kappa=kappa), donate,
+                "serve_candidates")
             # warmup compiles BOTH stages for this shape family
             cand, new_state = self._cand_fn(dummy, self.state)
             self.state = self._reattach(new_state)
@@ -291,17 +300,27 @@ class ServingEngine:
         cache_size = getattr(fn, "_cache_size", None)
         return cache_size() if cache_size is not None else None
 
+    def lower(self, batch: int):
+        """The compiled serving step for ``batch`` queries, lowered against
+        the installed state: its ``compile().as_text()`` is the executable
+        a profiler trace's device operations name."""
+        fn = self._fn if self._fn is not None else self._cand_fn
+        return fn.lower(jnp.zeros((batch, self.dim), jnp.float32),
+                        self.state)
+
     def search_with(self, queries, state: msearch.ServingState):
         """One full search against an arbitrary (treedef-compatible) state
         WITHOUT installing it or touching engine stats -- the lifecycle
         layer's canary hook. Runs whichever pipeline shape the engine
         serves, so a canary over a host-tier state exercises the candidate
-        state's own host store."""
-        queries = jnp.asarray(queries, jnp.float32)
-        if self._host is None:
-            ids, _ = self._fn(queries, state)
-            return ids
-        cand, _ = self._cand_fn(queries, state)
+        state's own host store. The argument transfer and the compiled
+        call's dispatch are the profiler span ``serve.launch``."""
+        with TraceAnnotation("serve.launch"):
+            queries = jnp.asarray(queries, jnp.float32)
+            if self._host is None:
+                ids, _ = self._fn(queries, state)
+                return ids
+            cand, _ = self._cand_fn(queries, state)
         return msearch.rerank(queries, state.artifacts, np.asarray(cand),
                               self.k)
 
@@ -379,9 +398,12 @@ class ServingEngine:
             if pad:
                 chunk = np.pad(chunk, ((0, pad), (0, 0)))
             t0 = time.perf_counter()
-            ids, self.state = self._fn(jnp.asarray(chunk, jnp.float32),
-                                       self.state)
-            ids = jax.block_until_ready(ids)
+            with TraceAnnotation("serve.step", rows=self.batch_size,
+                                 live=self.batch_size - pad):
+                with TraceAnnotation("serve.launch"):
+                    ids, self.state = self._fn(
+                        jnp.asarray(chunk, jnp.float32), self.state)
+                ids = jax.block_until_ready(ids)
             dt = time.perf_counter() - t0
             self.stats.n_batches += 1
             self.stats.n_queries += min(self.batch_size, n - s)

@@ -60,6 +60,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import streaming
 from repro.serve.engine import ServingEngine, sanitize_queries
@@ -277,46 +278,62 @@ class ServingFrontend:
         into one padded bucket, run the compiled step, slice results back
         per request. Returns the number of requests retired (served +
         shed). Deterministic -- the threaded dispatcher is a loop over
-        this; tests call it directly."""
-        batch, shed = self._take(timeout)
+        this; tests call it directly.
+
+        The round and its phases are profiler spans: ``serve.round``
+        around ``serve.take``, ``serve.assemble``, ``serve.step`` (with
+        the bucket's ``rows`` and its ``live`` requests) and
+        ``serve.resolve``, so a trace shows where the host time between
+        device steps goes."""
+        with TraceAnnotation("serve.round"):
+            return self._round(timeout)
+
+    def _round(self, timeout: Optional[float]) -> int:
+        with TraceAnnotation("serve.take"):
+            batch, shed = self._take(timeout)
         for req in shed:
             self.stats.n_shed += 1
             req.future.set_exception(
                 Rejected("shed", "deadline expired while queued"))
         if not batch:
             return len(shed)
-        b = self._pick_bucket(len(batch))
-        chunk = np.zeros((b, self.engine.dim), np.float32)
-        for i, req in enumerate(batch):
-            chunk[i] = req.query[0]
+        with TraceAnnotation("serve.assemble"):
+            b = self._pick_bucket(len(batch))
+            chunk = np.zeros((b, self.engine.dim), np.float32)
+            for i, req in enumerate(batch):
+                chunk[i] = req.query[0]
         t0 = self._clock()
         try:
-            # one atomic reference read: a concurrent swap either lands
-            # before (batch sees the fresh state) or after (stale-but-
-            # valid) -- never a torn state, states being immutable pytrees
-            state = self.engine.state
-            ids = self.engine.search_with(chunk, state)
-            ids = np.asarray(jax.block_until_ready(ids))
+            with TraceAnnotation("serve.step", rows=b, live=len(batch)):
+                # one atomic reference read: a concurrent swap either
+                # lands before (batch sees the fresh state) or after
+                # (stale-but-valid) -- never a torn state, states being
+                # immutable pytrees
+                state = self.engine.state
+                ids = jax.block_until_ready(
+                    self.engine.search_with(chunk, state))
         except Exception as e:      # noqa: BLE001 -- fail THIS batch only
             for req in batch:
                 req.future.set_exception(e)
             return len(batch) + len(shed)
-        dt = self._clock() - t0
-        a = self._ewma_alpha
-        self._ewma_s = a * dt + (1 - a) * self._ewma_s
-        self.dispatched_shapes.add(b)
-        self.stats.n_batches += 1
-        self.stats.n_queries += len(batch)
-        self.stats.total_s += dt
-        self.stats.latencies_ms.append(dt * 1e3)
-        now = self._clock()
-        for i, req in enumerate(batch):
-            self.stats.request_ms.append((now - req.t_enqueue) * 1e3)
-            if now > req.deadline:
-                self.stats.n_deadline_miss += 1
-            out = np.full((self.engine.k,), -1, np.int32) if req.poisoned \
-                else ids[i].astype(np.int32, copy=True)
-            req.future.set_result(out)
+        with TraceAnnotation("serve.resolve"):
+            ids = np.asarray(ids)
+            dt = self._clock() - t0
+            a = self._ewma_alpha
+            self._ewma_s = a * dt + (1 - a) * self._ewma_s
+            self.dispatched_shapes.add(b)
+            self.stats.n_batches += 1
+            self.stats.n_queries += len(batch)
+            self.stats.total_s += dt
+            self.stats.latencies_ms.append(dt * 1e3)
+            now = self._clock()
+            for i, req in enumerate(batch):
+                self.stats.request_ms.append((now - req.t_enqueue) * 1e3)
+                if now > req.deadline:
+                    self.stats.n_deadline_miss += 1
+                out = np.full((self.engine.k,), -1, np.int32) \
+                    if req.poisoned else ids[i].astype(np.int32, copy=True)
+                req.future.set_result(out)
         return len(batch) + len(shed)
 
     def _dispatch_loop(self) -> None:

@@ -6,9 +6,14 @@ one of its devices at the served shapes' widths -- C=48 clusters, reduced
 dims d in {160, 192} (RQA-10M and T2I-10M), u8 codes. Every compiled
 program must call the kernel (``tpu_custom_call``) and must hold no gather
 (:class:`NoGatherOnFusedPath`, which only TPU-compiled HLO can check).
-Where libtpu cannot describe the topology the tests skip.
+The kernel's instruction is named after it (``pallas_call(name=...)``):
+the profiler names its events by that instruction, and the benchmark finds
+``ivf_scan_topk``'s events by it. Where libtpu cannot describe the
+topology the tests skip.
 """
 from __future__ import annotations
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,5 +103,10 @@ def test_kernel_compiles_for_v5e(v5e, kernel, codes, d):
                             label=f"{kernel}/{codes}/d{d}")
     assert program.backend == "tpu"
     assert "tpu_custom_call" in program.text
+    calls = [line.strip() for line in program.text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    name = kernel.split("-")[0]
+    assert calls and all(re.match(rf"%?{name}(\.\d+)? = ", c)
+                         for c in calls), calls[:1]
     result = NoGatherOnFusedPath().check(program)
     assert result.passed and not result.skipped, result.evidence
