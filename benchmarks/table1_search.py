@@ -55,18 +55,17 @@ def _fine_bytes_gathered(index, scorer, queries, kappa) -> float:
     return float(cost.get("bytes accessed", 0.0))
 
 
-def _fine_bytes_fused(index, scorer, m: int, kappa: int) -> float:
+def _fine_bytes_fused(index, scorer, queries, kappa: int) -> float:
     """HBM bytes of the FUSED range-scan fine step: the kernel's traffic
-    is fixed by its BlockSpecs (``fine_step_bytes``), with the expected
-    schedule occupancy = nprobe * (mean blocks per cluster) slabs/query."""
-    ranges = np.asarray(scorer.list_block_ranges)
-    blocks_per_cluster = (ranges >= 0).sum() / ranges.shape[0]
-    visited = m * index.nprobe * blocks_per_cluster
+    is fixed by its BlockSpecs (``fine_step_bytes``) and the batch's
+    probes (the union of the probed clusters' blocks)."""
+    qs = index.prepare_queries(scorer, queries)
+    probe = jax.lax.top_k(ivf.coarse_scores(index, qs), index.nprobe)[1]
     rows = getattr(scorer, "codes", None)
     if rows is None:
         rows = scorer.x_low
-    return fine_step_bytes(m, visited, scorer.layout_block, rows.shape[1],
-                           ranges.shape[0],
+    return fine_step_bytes(probe, scorer.block_tags, scorer.layout_block,
+                           rows.shape[1],
                            code_bytes=np.dtype(rows.dtype).itemsize,
                            k=kappa)
 
@@ -200,7 +199,7 @@ def run():
     # BlockSpec-determined HBM traffic; fine_bytes_gathered is the
     # compiled gathered fine step's (normalize_cost) for the same probe.
     iva = ivf.build_aligned(model, X, nprobe=8)
-    fb_fused = _fine_bytes_fused(iva, sgq, nq, kappa)
+    fb_fused = _fine_bytes_fused(iva, sgq, QT, kappa)
     fb_gather = _fine_bytes_gathered(replace(iva, aligned_layout=False),
                                      sgq, QT, kappa)
     bench(f"ivf-sorted-fused/gleanvec-d{d}-int8-sorted",

@@ -51,11 +51,9 @@ RECALL_FLOOR = {"flat": 0.95, "ivf": 0.95, "graph": 0.80}
 # fused vs gathered traversal: top-10 ids that must agree (the chip's f32
 # matmuls may split exact ties differently on the two paths)
 MIN_OVERLAP = 0.99
-# the fused ivf scan splits a batch into query chunks once two int32 probe
-# schedules per query (the probed lists' 512-row blocks) outgrow the SMEM
-# budget: at 7M rows in 48 clusters, 4 probed lists are ~2,300 blocks, so a
-# 64-query batch takes about three chunks
-IVF_CHUNKED_NPROBE = 4
+# fused vs gathered ivf traversal at this probe count: the gathered fine
+# step holds (m, nprobe * list_len, d) rows, so it stays small
+IVF_PARITY_NPROBE = 4
 T2I = dict(dim=200, d=192, clusters=48)
 # T2I-10M is cut to 7M rows on one chip. The TPU's default layout of an
 # (n, 200) f32 array is column-major (200 lanes would pad to 256), so the
@@ -115,24 +113,13 @@ def top10(index, queries, state):
     return _top10(queries, index, state.artifacts.scorer)
 
 
-def count_chunks(module) -> list:
-    """Make ``module.query_chunks`` record, each time a kernel call is
-    traced, how many query chunks it split the batch into."""
-    inner, counts = module.query_chunks, []
-
-    def counted(run, *args):
-        n = [0]
-
-        def run_one(*rows):
-            n[0] += 1
-            return run(*rows)
-
-        out = inner(run_one, *args)
-        counts.append(n[0])
-        return out
-
-    module.query_chunks = counted
-    return counts
+def kernel_calls(index, queries, scorer, name: str) -> int:
+    """Calls of the Pallas kernel ``name`` in the compiled traversal of
+    ``index`` (``_top10``) over ``queries``."""
+    text = _top10.lower(queries, index, scorer).compile().as_text()
+    return sum(1 for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and line.strip().lstrip("%").startswith(name))
 
 
 def tripwires() -> None:
@@ -185,10 +172,10 @@ def phase_kernels(seed: int) -> None:
     block_tags = jnp.asarray(np.sort(rng.integers(0, c, n // lb)), jnp.int32)
     perm = jnp.asarray(rng.permutation(n), jnp.int32)
     row_tags = jnp.asarray(rng.integers(0, c, n), jnp.int32)
-    # a probe schedule visits distinct layout blocks (-1 pads the tail)
-    sched = np.stack([rng.permutation(n // lb)[:12] for _ in range(m)])
-    sched = jnp.asarray(np.where(np.arange(12) < rng.integers(
-        8, 13, (m, 1)), sched, -1), jnp.int32)
+    # each query probes 8-12 distinct clusters (-1 pads the tail)
+    probe = np.stack([rng.permutation(c)[:12] for _ in range(m)])
+    probe = jnp.asarray(np.where(np.arange(12) < rng.integers(
+        8, 13, (m, 1)), probe, -1), jnp.int32)
     nbr = jnp.asarray(rng.integers(-1, n, (m, 144)), jnp.int32)
     beam_v = jnp.full((m, 96), -3.4e38, jnp.float32)
     beam_i = jnp.full((m, 96), -1, jnp.int32)
@@ -210,10 +197,10 @@ def phase_kernels(seed: int) -> None:
             lambda: gleanvec_sq_topk(qs, qlo, row_tags, codes, k),
             lambda: gleanvec_sq_topk_ref(qs, qlo, row_tags, codes, k)),
         "ivf_scan_topk": (
-            lambda: ivf_scan_topk(qs, qlo, block_tags, perm, codes, sched,
+            lambda: ivf_scan_topk(qs, qlo, block_tags, perm, codes, probe,
                                   100, layout_block=lb),
             lambda: ivf_scan_topk_ref(qs, qlo, block_tags, perm, codes,
-                                      sched, 100, layout_block=lb)),
+                                      probe, 100, layout_block=lb)),
         "graph_scan_beam_step": (
             lambda: graph_scan_beam_step(qs, qlo, block_tags, perm, codes,
                                          nbr, beam_v, beam_i,
@@ -290,7 +277,6 @@ def phase_frontend(run, n_clients: int = 4, per_client: int = 16) -> None:
 
 def one_chip(seed: int, n_big: int, n_graph: int) -> None:
     from repro.index.protocol import replace
-    from repro.kernels.ivf_scan import ivf_scan
     from repro.launch import serve
 
     phase_kernels(seed)
@@ -316,20 +302,19 @@ def one_chip(seed: int, n_big: int, n_graph: int) -> None:
                       "ivf", "--aligned", "--reduced-probe")
     ivf = serve.run_search(args, ds=ds, model=model)   # same fit
     state = ivf.engine.state
-    # fused vs gathered on one served batch, at a probe count whose probe
-    # schedules overflow one kernel call's SMEM, so the fused scan splits
-    # the batch into query chunks. The gathered fine step holds
+    # fused vs gathered on one served batch. The fused scan is one kernel
+    # call for the whole batch; the gathered fine step holds
     # (m, nprobe * list_len, d) rows: it runs one query at a time.
-    fused = replace(state.index, nprobe=IVF_CHUNKED_NPROBE)
+    fused = replace(state.index, nprobe=IVF_PARITY_NPROBE)
     gathered = replace(fused, aligned_layout=False)
     qt = jnp.asarray(ds.queries_test[:ivf.engine.batch_size])
-    chunks = count_chunks(ivf_scan)
+    calls = kernel_calls(fused, qt, state.artifacts.scorer, "ivf_scan_topk")
     got = np.asarray(top10(fused, qt, state))
     want = np.concatenate([top10(gathered, qt[i:i + 1], state)
                            for i in range(qt.shape[0])])
     log(f"[ivf] fused scan of {qt.shape[0]} queries at "
-        f"nprobe={fused.nprobe}: {chunks} query chunks")
-    check(chunks and max(chunks) > 1, "the fused ivf scan was not chunked")
+        f"nprobe={fused.nprobe}: {calls} ivf_scan_topk call(s)")
+    check(calls == 1, "the fused ivf scan is not one kernel call")
     phase_report("ivf", args, ivf, has_kernel(ivf.engine),
                  overlap(got, want), need_kernel=True)
     del ivf, state, ds

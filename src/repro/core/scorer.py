@@ -51,11 +51,10 @@ HBM-write reduction the Perf log quantifies. The price is a private row
 order: ``perm`` (sorted row -> original id, -1 on padding) and ``inv_perm``
 (original id -> sorted row) translate at the consumer boundary, so IVF
 posting lists, graph neighbors and rerank candidates keep speaking original
-ids. They additionally carry ``list_block_ranges`` ((C, max_blocks) block
-indices per cluster, -1-padded, derived from ``block_tags``) and expose
-``scan_lists(qstate, probe, k)`` -- the gather-free IVF fine step: an
-ALIGNED coarse quantizer's probed clusters stream slab-by-slab through the
-``kernels/ivf_scan`` range-scan kernel instead of a posting-list gather.
+ids. They additionally expose ``scan_lists(qstate, probe, k)`` -- the
+gather-free IVF fine step: the union of an ALIGNED coarse quantizer's
+probed clusters streams slab-by-slab through the ``kernels/ivf_scan``
+range-scan kernel, once per batch, instead of a posting-list gather.
 
 ``GleanVecQuantizedScorer`` is the composition the LeanVec line of work
 endorses (DR stacked with scalar quantization): the per-cluster reduced
@@ -109,28 +108,6 @@ def _translate_sorted(perm: jax.Array, ids: jax.Array):
     sort permutation; invalid slots and padding rows map to -1."""
     orig = perm[jnp.where(ids >= 0, ids, 0)]
     return jnp.where(ids >= 0, orig, -1)
-
-
-def _list_block_ranges(block_tags, c: int) -> jax.Array:
-    """(C, max_blocks) table of layout-block indices per cluster, -1-padded
-    (host-side, once at build; derivable from ``block_tags`` because
-    ``sort_by_tag`` keeps each cluster's blocks -- slack blocks included --
-    contiguous). ``ranges[probe]`` IS the probe schedule the gather-free
-    range-scan kernel consumes: one argsort/bincount pass, no per-cluster
-    sweep."""
-    import numpy as np
-    bt = np.asarray(block_tags)
-    blocks = np.nonzero(bt >= 0)[0]           # stacked shards pad with -1
-    t = bt[blocks]
-    counts = np.bincount(t, minlength=c) if t.size else np.zeros(c, int)
-    maxb = max(1, int(counts.max()) if t.size else 1)
-    starts = np.zeros(c, np.int64)
-    starts[1:] = np.cumsum(counts)[:-1]
-    order = np.argsort(t, kind="stable")
-    rank = np.arange(t.size) - starts[t[order]]
-    out = np.full((c, maxb), -1, np.int32)
-    out[t[order], rank] = blocks[order].astype(np.int32)
-    return jnp.asarray(out)
 
 
 def _center_views_scorer(centers: jax.Array, model) -> "GleanVecScorer":
@@ -691,9 +668,6 @@ class SortedGleanVecScorer(NamedTuple):
     perm: jax.Array                  # (ns,) sorted row -> original id (-1)
     inv_perm: jax.Array              # (n,)  original id -> sorted row
     a: Optional[jax.Array] = None    # (C, d, D) per-cluster query maps
-    # (C, max_blocks) layout-block indices per cluster, -1-padded (the
-    # range-scan probe schedule source; None on hand-rolled layouts)
-    list_block_ranges: Optional[jax.Array] = None
 
     @property
     def n_rows(self) -> int:
@@ -751,21 +725,18 @@ class SortedGleanVecScorer(NamedTuple):
 
     def scan_lists(self, qstate: jax.Array, probe: jax.Array, k: int):
         """Gather-free IVF fine step (``kernels/ivf_scan``): stream the
-        probed clusters' single-tag slabs through the range-scan kernel --
-        no posting-list gather, no (m, nprobe*L) candidate or score matrix.
-        ``probe (m, nprobe)`` holds cluster ids that must equal this
-        layout's tags (an ALIGNED coarse quantizer: ``ivf.build_aligned``).
-        Returns (vals, ids) (m, k) with ORIGINAL ids; padding slots and
-        removed rows (perm == -1) score -inf and strip to id -1."""
+        union of the batch's probed clusters' single-tag slabs once
+        through the range-scan kernel, scoring each query against its own
+        clusters' rows -- no posting-list gather, no (m, nprobe*L)
+        candidate or score matrix. ``probe (m, nprobe)`` holds cluster ids
+        that must equal this layout's tags (an ALIGNED coarse quantizer:
+        ``ivf.build_aligned``). Returns (vals, ids) (m, k) with ORIGINAL
+        ids, ties to the lower sorted row; unfilled slots and removed rows
+        (perm == -1) score -inf and strip to id -1."""
         from repro.kernels.ivf_scan import ivf_scan_topk
-        if self.list_block_ranges is None:
-            raise ValueError(
-                "scan_lists needs list_block_ranges; build the scorer "
-                "through its factory (sorted_gleanvec_scorer)")
-        sched = self.list_block_ranges[probe].reshape(probe.shape[0], -1)
         q_lo = jnp.zeros(qstate.shape[:2], jnp.float32)   # no affine term
         return ivf_scan_topk(qstate, q_lo, self.block_tags, self.perm,
-                             self.x_low, sched, k,
+                             self.x_low, probe, k,
                              layout_block=self.layout_block)
 
     def scan_neighbors(self, qstate: jax.Array, nbr_rows: jax.Array,
@@ -787,16 +758,11 @@ class SortedGleanVecScorer(NamedTuple):
         # Row-shard the sorted layout: the shard count must divide the
         # BLOCK count so no single-tag block straddles shards, and ``perm``
         # must hold GLOBAL original ids (build the layout before sharding).
-        # ``list_block_ranges`` indexes the GLOBAL block space, so it stays
-        # replicated (the row-sharded flat scan never consumes it).
         from jax.sharding import PartitionSpec as P
         return SortedGleanVecScorer(x_low=P(tuple(axes), None),
                                     block_tags=P(tuple(axes)),
                                     perm=P(tuple(axes)), inv_perm=P(),
-                                    a=None if self.a is None else P(),
-                                    list_block_ranges=None
-                                    if self.list_block_ranges is None
-                                    else P())
+                                    a=None if self.a is None else P())
 
     def translate_ids(self, ids: jax.Array) -> jax.Array:
         return _translate_sorted(self.perm, ids)
@@ -877,9 +843,6 @@ class SortedGleanVecQuantizedScorer(NamedTuple):
     lo: jax.Array                    # (C, d) per-cluster lower bounds
     delta: jax.Array                 # (C, d) per-cluster steps
     a: jax.Array                     # (C, d, D) per-cluster query maps
-    # (C, max_blocks) layout-block indices per cluster, -1-padded (the
-    # range-scan probe schedule source; None on hand-rolled layouts)
-    list_block_ranges: Optional[jax.Array] = None
 
     @property
     def n_rows(self) -> int:
@@ -939,13 +902,8 @@ class SortedGleanVecQuantizedScorer(NamedTuple):
         contract as :meth:`SortedGleanVecScorer.scan_lists`, with the
         per-cluster affine terms riding the folded qstate."""
         from repro.kernels.ivf_scan import ivf_scan_topk
-        if self.list_block_ranges is None:
-            raise ValueError(
-                "scan_lists needs list_block_ranges; build the scorer "
-                "through its factory (sorted_gleanvec_quantized_scorer)")
-        sched = self.list_block_ranges[probe].reshape(probe.shape[0], -1)
         return ivf_scan_topk(qstate.q_scaled, qstate.q_lo, self.block_tags,
-                             self.perm, self.codes, sched, k,
+                             self.perm, self.codes, probe, k,
                              layout_block=self.layout_block)
 
     def scan_neighbors(self, qstate: QuantQueryState, nbr_rows: jax.Array,
@@ -966,9 +924,7 @@ class SortedGleanVecQuantizedScorer(NamedTuple):
         from jax.sharding import PartitionSpec as P
         return SortedGleanVecQuantizedScorer(
             codes=P(tuple(axes), None), block_tags=P(tuple(axes)),
-            perm=P(tuple(axes)), inv_perm=P(), lo=P(), delta=P(), a=P(),
-            list_block_ranges=None if self.list_block_ranges is None
-            else P())
+            perm=P(tuple(axes)), inv_perm=P(), lo=P(), delta=P(), a=P())
 
     def translate_ids(self, ids: jax.Array) -> jax.Array:
         return _translate_sorted(self.perm, ids)
@@ -1129,9 +1085,7 @@ def sorted_gleanvec_scorer(model, database: jax.Array, block: int = 4096,
     inv = gv.inverse_permutation(perm, x_low.shape[0])
     return SortedGleanVecScorer(x_low=xs, block_tags=block_tags,
                                 perm=perm.astype(jnp.int32), inv_perm=inv,
-                                a=model.a,
-                                list_block_ranges=_list_block_ranges(
-                                    block_tags, model.n_clusters))
+                                a=model.a)
 
 
 def sorted_gleanvec_quantized_scorer(
@@ -1147,8 +1101,7 @@ def sorted_gleanvec_quantized_scorer(
     inv = gv.inverse_permutation(perm, tags.shape[0])
     return SortedGleanVecQuantizedScorer(
         codes=cs, block_tags=block_tags, perm=perm.astype(jnp.int32),
-        inv_perm=inv, lo=db.lo, delta=db.delta, a=model.a,
-        list_block_ranges=_list_block_ranges(block_tags, model.n_clusters))
+        inv_perm=inv, lo=db.lo, delta=db.delta, a=model.a)
 
 
 MODES = ("full", "sphering", "gleanvec", "sphering-int8", "gleanvec-int8",

@@ -102,8 +102,7 @@ class IVFIndex:
                              q_coarse=q_coarse)
 
     def candidates(self, qstate: IVFQueryState, scorer, k: int):
-        if self.aligned_layout and \
-                getattr(scorer, "list_block_ranges", None) is not None:
+        if self.aligned_layout and hasattr(scorer, "scan_lists"):
             return _probe_and_scan(qstate, scorer, self, k)
         return _probe_and_score(qstate, scorer, self, k)
 

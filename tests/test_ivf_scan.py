@@ -202,11 +202,9 @@ def test_fused_fine_step_moves_4x_fewer_bytes():
         .cost_analysis())
     gathered_bytes = float(gathered_cost["bytes accessed"])
 
-    ranges = np.asarray(s.list_block_ranges)
-    visited = m * iva.nprobe * (ranges >= 0).sum() / ranges.shape[0]
-    fused_bytes = fine_step_bytes(m, visited, s.layout_block,
-                                  s.codes.shape[1], gvm.n_clusters,
-                                  code_bytes=1, k=kappa)
+    probe = jax.lax.top_k(ivf.coarse_scores(iva, qs), iva.nprobe)[1]
+    fused_bytes = fine_step_bytes(probe, s.block_tags, s.layout_block,
+                                  s.codes.shape[1], code_bytes=1, k=kappa)
     assert fused_bytes * 4 <= gathered_bytes, (fused_bytes, gathered_bytes)
 
     # no (m, nprobe*L) candidate/score matrix in the fused program, in

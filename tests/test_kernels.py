@@ -144,9 +144,10 @@ def test_gleanvec_sq_topk_sorted_emits_external_ids():
     assert (np.asarray(i1) >= 0).all()              # padding never wins
 
 
-def _scan_inputs(m, nb, c, d, lb, s, n_pad=0, f32=False, seed=1):
-    """Random sorted-layout inputs + a -1-padded per-query block schedule
-    (possibly with unscheduled blocks -- the kernel must never read them)."""
+def _scan_inputs(m, nb, c, d, lb, p, n_pad=0, f32=False, seed=1):
+    """Random sorted-layout inputs + a -1-padded per-query probe of ``p``
+    cluster ids (repeats and unprobed clusters included -- the kernel must
+    score each probed cluster's rows once and never an unprobed one)."""
     rng = np.random.default_rng(seed)
     n = nb * lb
     q_scaled, q_lo, _, codes = _sq_inputs(m, n, c, d)
@@ -156,47 +157,127 @@ def _scan_inputs(m, nb, c, d, lb, s, n_pad=0, f32=False, seed=1):
     perm = np.arange(n, dtype=np.int32)
     if n_pad:
         perm[rng.permutation(n)[:n_pad]] = -1        # dead/padding rows
-    sched = rng.integers(-1, nb, (m, s)).astype(np.int32)
+    probe = rng.integers(-1, c, (m, p)).astype(np.int32)
     return (q_scaled, q_lo, block_tags, jnp.asarray(perm), codes,
-            jnp.asarray(sched))
+            jnp.asarray(probe))
+
+
+def _assert_scan_matches_ref(args, k, lb, tn, atol=1e-3):
+    v1, i1 = ivf_scan_topk(*args, k, layout_block=lb, tn=tn, interpret=True)
+    v2, i2 = ivf_scan_topk_ref(*args, k, layout_block=lb)
+    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-4,
+                               atol=atol)
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    return np.asarray(v1), np.asarray(i1)
 
 
 @pytest.mark.tier1
-@pytest.mark.parametrize("m,nb,c,d,lb,s,tn", [
+@pytest.mark.parametrize("m,nb,c,d,lb,p,tn", [
     (4, 8, 6, 32, 128, 3, 64),      # layout_block % tn == 0
     (3, 5, 8, 48, 64, 5, 256),      # tn > layout_block -> tile shrink
     (1, 6, 4, 16, 96, 2, 64),       # tn does not divide -> tile shrink
 ])
-def test_ivf_scan_topk_matches_ref(m, nb, c, d, lb, s, tn):
-    """Scalar-prefetch range-scan kernel == gather oracle: schedule-driven
-    slab streaming, -1 schedule pads and -1 row_ids never win."""
-    qs, ql, bt, rid, codes, sched = _scan_inputs(m, nb, c, d, lb, s,
-                                                 n_pad=40)
-    v1, i1 = ivf_scan_topk(qs, ql, bt, rid, codes, sched, 7,
-                           layout_block=lb, tn=tn, interpret=True)
-    v2, i2 = ivf_scan_topk_ref(qs, ql, bt, rid, codes, sched, 7,
-                               layout_block=lb)
-    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-4,
-                               atol=1e-3)
-    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+def test_ivf_scan_topk_matches_ref(m, nb, c, d, lb, p, tn):
+    """Block-major range-scan kernel == set-of-probed-blocks oracle: the
+    union of the batch's probed blocks streams once, each query scores
+    only its own clusters' rows, -1 probe pads and -1 row_ids never win."""
+    args = _scan_inputs(m, nb, c, d, lb, p, n_pad=40)
+    _assert_scan_matches_ref(args, 7, lb, tn)
 
 
 @pytest.mark.tier1
 def test_ivf_scan_topk_f32_rows_and_empty_schedule():
     """The unquantized sorted scorer's f32 rows ride the same kernel, and
-    an all-padding schedule row returns (-inf, -1) everywhere."""
-    qs, ql, bt, rid, codes, sched = _scan_inputs(2, 6, 4, 24, 64, 4,
+    an all -1 probe row returns (-inf, -1) everywhere."""
+    qs, ql, bt, rid, codes, probe = _scan_inputs(2, 6, 4, 24, 64, 4,
                                                  f32=True)
-    sched = sched.at[1].set(-1)                      # query 1: no blocks
-    v1, i1 = ivf_scan_topk(qs, ql, bt, rid, codes, sched, 5,
-                           layout_block=64, tn=64, interpret=True)
-    v2, i2 = ivf_scan_topk_ref(qs, ql, bt, rid, codes, sched, 5,
-                               layout_block=64)
-    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-4,
-                               atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
-    assert (np.asarray(i1)[1] == -1).all()
-    assert (np.asarray(v1)[1] < -1e37).all()
+    probe = probe.at[1].set(-1)                      # query 1: no clusters
+    v1, i1 = _assert_scan_matches_ref((qs, ql, bt, rid, codes, probe), 5,
+                                      64, 64, atol=1e-4)
+    assert (i1[1] == -1).all()
+    assert (v1[1] < -1e37).all()
+
+
+def _clustered_layout(per, lb, slack=0, n_dead=0, seed=5):
+    """A sorted layout as ``sort_by_tag`` makes it: cluster ``t``'s
+    ``per[t]`` blocks contiguous, ascending by tag, then ``slack`` blocks of
+    dead rows (a streaming store's free room); ``n_dead`` more rows die at
+    random (removed rows)."""
+    rng = np.random.default_rng(seed)
+    live_block = np.concatenate([np.arange(p + slack) < p for p in per])
+    tags = np.repeat(np.arange(len(per)), np.asarray(per) + slack)
+    n = tags.size * lb
+    perm = rng.permutation(n).astype(np.int32)
+    perm[~np.repeat(live_block, lb)] = -1
+    perm[rng.permutation(n)[:n_dead]] = -1
+    return jnp.asarray(tags.astype(np.int32)), jnp.asarray(perm), n
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("case", ["shared64", "dead_and_slack"])
+def test_ivf_scan_topk_batch_cases(case):
+    """(a) a 64-row batch whose queries share clusters, each probing its
+    own clusters in its own order, with repeats and pads; (b) dead rows
+    (``row_ids == -1``) and slack blocks of dead rows in every cluster.
+    Each query's answer is the oracle's over its probed clusters alone,
+    whatever the other queries of the batch probe."""
+    rng = np.random.default_rng(11)
+    c, d, lb = 6, 32, 128
+    if case == "shared64":
+        m, slack, n_dead = 64, 0, 0
+        per = rng.integers(1, 4, c)
+    else:
+        m, slack, n_dead = 8, 2, 300
+        per = rng.integers(1, 3, c)
+    bt, rid, n = _clustered_layout(per, lb, slack=slack, n_dead=n_dead)
+    q_scaled, q_lo, _, codes = _sq_inputs(m, n, c, d)
+    probe = np.stack([rng.permutation(c)[:3] for _ in range(m)])
+    probe[::5, 2] = probe[::5, 0]                    # a repeated cluster
+    probe[::7, 1:] = -1                              # pads
+    probe = jnp.asarray(probe.astype(np.int32))
+    args = (q_scaled, q_lo, bt, rid, codes, probe)
+    _, ids = _assert_scan_matches_ref(args, 10, lb, 64)
+    # each row alone gives the same answer as inside the batch
+    for i in (0, 5, 7, m - 1):
+        one = (q_scaled[i:i + 1], q_lo[i:i + 1], bt, rid, codes,
+               probe[i:i + 1])
+        np.testing.assert_array_equal(
+            _assert_scan_matches_ref(one, 10, lb, 64)[1][0], ids[i])
+    live = np.asarray(rid)
+    assert np.isin(ids[ids >= 0], live[live >= 0]).all()
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("mode", ["gleanvec-sorted", "gleanvec-int8-sorted"])
+def test_ivf_scan_topk_single_query_matches_gathered(mode):
+    """(c) M=1: the kernel over one query's probe returns the gathered IVF
+    path's (value, id) set for both sorted scorer families."""
+    from helpers import assert_same_topk
+    from repro.core import gleanvec as gv, scorer as sc
+    from repro.data import vectors
+    from repro.index import ivf
+    from repro.index.protocol import replace
+
+    ds = vectors.make_dataset("ivfscan-m1", n=1024, d=32, n_queries=4,
+                              ood=True, seed=9)
+    X = jnp.asarray(ds.database)
+    model = gv.fit(jax.random.PRNGKey(0), jnp.asarray(ds.queries_learn), X,
+                   c=6, d=16)
+    build = (sc.sorted_gleanvec_scorer if mode == "gleanvec-sorted"
+             else sc.sorted_gleanvec_quantized_scorer)
+    s = build(model, X, block=64)
+    iva = ivf.build_aligned(model, X, nprobe=2)
+    q1 = jnp.asarray(ds.queries_test[:1])
+    qs = iva.prepare_queries(s, q1)
+    probe = jax.lax.top_k(ivf.coarse_scores(iva, qs), iva.nprobe)[1]
+    if mode == "gleanvec-sorted":
+        views, lo, rows = qs.qstate, jnp.zeros(qs.qstate.shape[:2]), s.x_low
+    else:
+        views, lo, rows = qs.qstate.q_scaled, qs.qstate.q_lo, s.codes
+    got = ivf_scan_topk(views, lo, s.block_tags, s.perm, rows, probe, 10,
+                        layout_block=s.layout_block, tn=64, interpret=True)
+    want = replace(iva, aligned_layout=False).search(q1, s, 10)
+    assert_same_topk(got, want, mode, rtol=1e-4, atol=1e-3)
 
 
 def _graph_scan_inputs(m, nb, c, d, lb, s, b, n_pad=0, f32=False, seed=3):

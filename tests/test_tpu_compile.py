@@ -3,7 +3,9 @@
 No chip is needed: libtpu describes a ``v5e:2x2`` slice, and each kernel
 is lowered through Mosaic (``use_pallas=True``, not interpret mode) for
 one of its devices at the served shapes' widths -- C=48 clusters, reduced
-dims d in {160, 192} (RQA-10M and T2I-10M), u8 codes. Every compiled
+dims d in {160, 192} (RQA-10M and T2I-10M), u8 codes; ``ivf_scan_topk``
+at the served T2I batch and layout (64 queries over 8,101,888 rows in
+4096-row blocks, k=100: one call for the whole batch). Every compiled
 program must call the kernel (``tpu_custom_call``) and must hold no gather
 (:class:`NoGatherOnFusedPath`, which only TPU-compiled HLO can check).
 The kernel's instruction is named after it (``pallas_call(name=...)``):
@@ -27,7 +29,9 @@ from repro.kernels.ivf_scan.ops import ivf_scan_topk
 
 C, N, M, K = 48, 1 << 16, 16, 10
 LAYOUT_BLOCK = 512
-SCHEDULE = 12                  # probed layout blocks per query
+# the ivf scan at the served T2I shape: a full 64-row batch, 12 of 48
+# clusters probed per query, over the 8M-row layout in 4096-row blocks
+IVF_M, IVF_N, IVF_BLOCK, NPROBE = 64, 8_101_888, 4096, 12
 NEIGHBORS, BEAM = 4 * 36, 96   # graph hop: expand * R neighbors, beam
 
 pytestmark = pytest.mark.tier1
@@ -50,11 +54,11 @@ def v5e():
 
 
 def _ivf(s, d, codes):
-    return (lambda qs, ql, bt, rid, x, sch: ivf_scan_topk(
-                qs, ql, bt, rid, x, sch, K, LAYOUT_BLOCK, use_pallas=True),
-            s((M, C, d), jnp.float32), s((M, C), jnp.float32),
-            s((N // LAYOUT_BLOCK,), jnp.int32), s((N,), jnp.int32),
-            s((N, d), codes), s((M, SCHEDULE), jnp.int32))
+    return (lambda qs, ql, bt, rid, x, probe: ivf_scan_topk(
+                qs, ql, bt, rid, x, probe, 100, IVF_BLOCK, use_pallas=True),
+            s((IVF_M, C, d), jnp.float32), s((IVF_M, C), jnp.float32),
+            s((IVF_N // IVF_BLOCK,), jnp.int32), s((IVF_N,), jnp.int32),
+            s((IVF_N, d), codes), s((IVF_M, NPROBE), jnp.int32))
 
 
 def _graph(s, d, codes):
