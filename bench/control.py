@@ -47,7 +47,7 @@ def control(spec, cell_name: str, seeds, seconds: float):
     window in turn."""
     import jax
     import numpy as np
-    from bench import datagen, load, reference, run
+    from bench import load, run
     from repro.core import linalg
     from repro.serve.engine import ServingEngine
     from repro.serve.frontend import ServingFrontend
@@ -55,8 +55,9 @@ def control(spec, cell_name: str, seeds, seconds: float):
     cell = spec.cell(cell_name)
     cfg = spec.config(cell["config"])
     traffic = spec.traffic(cell["traffic"])
-    base = datagen.make(cfg["collection_seed"], 0, cfg["n"], cfg["dim"],
-                        run.LEARN_QUERIES, 0)
+    place = run.placement(cfg)
+    base = place.make(cfg["collection_seed"], 0, cfg["n"], cfg["dim"],
+                      run.LEARN_QUERIES, 0)
     buckets = (cfg["max_batch"],)
     served, _ = run.build(cfg, base.x, base.learn, int(traffic["queue"]),
                           buckets=buckets)
@@ -65,7 +66,7 @@ def control(spec, cell_name: str, seeds, seconds: float):
                            run.full_batch_s(served, base.learn,
                                             cfg["max_batch"]),
                            cfg["max_batch"])
-    pools = {s: datagen.with_pool(base, s, n_pool).pool for s in seeds}
+    pools = {s: place.with_pool(base, s, n_pool).pool for s in seeds}
     windows = {}
     for s in seeds:
         windows["program@highest", s] = run.measure(
@@ -105,7 +106,7 @@ def control(spec, cell_name: str, seeds, seconds: float):
             # one bf16 pass XLA converts the whole store ahead of the
             # scan, which does not fit beside a 12 GB store
             try:
-                _, ids = reference.exact_topk(
+                _, ids = place.exact_topk(
                     pools[s][rows], base.x, cfg["k"],
                     precision=jax.lax.Precision[name.upper()])
             except jax.errors.JaxRuntimeError as e:

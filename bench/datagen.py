@@ -13,7 +13,9 @@ cannot change the yardstick:
   semantically linked).
 
 Everything is drawn with ``jax.random`` on the device: the rows in chunks
-inside one jitted call, so set-up never holds the collection on the host.
+inside one jitted call, so set-up never holds the collection on the host;
+or, for a store kept in host memory (``make_host``), the same chunks one
+call at a time, each copied into one host array.
 """
 from __future__ import annotations
 
@@ -114,7 +116,8 @@ ood_draws = jax.jit(_ood_draws, static_argnames=("count",))
 
 
 class Collection(NamedTuple):
-    x: jax.Array             # (n, D) float32 on the device
+    x: jax.Array             # (n, D) float32 on the device (on the host
+                             # from make_host)
     learn: jax.Array         # (n_learn, D) float32 on the device
     pool: np.ndarray         # (n_pool, D) float32 on the host
     law: QueryLaw
@@ -145,6 +148,84 @@ def with_pool(coll: Collection, seed: int, n_pool: int,
     k_pool = jax.random.fold_in(seed_key(seed), 2)
     parts = [np.asarray(ood_draws(jax.random.fold_in(k_pool, i), coll.law,
                                   coll.x, pool_chunk))
+             for i in range(-(-n_pool // pool_chunk))]
+    if not parts:
+        return coll
+    return coll._replace(pool=np.concatenate(parts)[:n_pool])
+
+
+# -- a store kept in host memory ------------------------------------------
+#
+# The same draws, for a configuration whose f32 rerank store lives in host
+# memory (``"store": "host"``) and may not fit on the device: the device
+# makes one chunk of rows at a time, with ``database``'s keys, and each
+# chunk is copied into one host array; the anchors of the query draws are
+# gathered from those host rows. Beside the device path, not through it.
+
+mixture = jax.jit(_mixture, static_argnames=("dim",))
+chunk_rows = jax.jit(_rows, static_argnames=("rows",))
+
+
+@functools.partial(jax.jit, static_argnames=("count", "n"))
+def anchor_ids(key, count: int, n: int) -> jax.Array:
+    """The rows ``_ood_draws`` takes its anchors from, from its key."""
+    return jax.random.randint(jax.random.split(key)[1], (count,), 0, n)
+
+
+@jax.jit
+def ood_mix(key, law: QueryLaw, anchor: jax.Array) -> jax.Array:
+    """``_ood_draws`` with its anchor rows given."""
+    k_z, _ = jax.random.split(key)
+    z = jax.random.normal(k_z, (anchor.shape[0], law.basis.shape[1]))
+    q = jnp.matmul(jnp.matmul(z, law.basis.T, precision=HIGHEST), law.rot,
+                   precision=HIGHEST) + law.shift
+    return 0.6 * q + 0.4 * anchor
+
+
+def host_database(key, n: int, dim: int, chunk: int) -> np.ndarray:
+    """``database(key, n, dim, chunk)`` in one read-only host array, made
+    one chunk at a time: the device never holds more than one chunk."""
+    if n % chunk:
+        raise ValueError(f"chunk {chunk} does not divide n={n}")
+    mix = mixture(jax.random.fold_in(key, 0), dim)
+    k_rows = jax.random.fold_in(key, 1)
+    x = np.empty((n, dim), np.float32)
+    for i in range(n // chunk):
+        x[i * chunk:(i + 1) * chunk] = np.asarray(
+            chunk_rows(jax.random.fold_in(k_rows, i), mix, chunk))
+    x.flags.writeable = False
+    return x
+
+
+def host_ood_draws(key, law: QueryLaw, x: np.ndarray,
+                   count: int) -> jax.Array:
+    """``ood_draws`` over host rows: the anchors are gathered on the
+    host."""
+    return ood_mix(key, law, x[np.asarray(anchor_ids(key, count,
+                                                     x.shape[0]))])
+
+
+def make_host(collection_seed: int, seed: int, n: int, dim: int,
+              n_learn: int, n_pool: int, chunk: int = 100_000
+              ) -> Collection:
+    """:func:`make` with the rows in host memory (``x`` a read-only numpy
+    array): the same rows, learning queries and pool."""
+    key = seed_key(collection_seed)
+    x = host_database(jax.random.fold_in(key, 11), n, dim, min(chunk, n))
+    k_q = jax.random.fold_in(key, 12)
+    law = query_law(jax.random.fold_in(k_q, 0), dim)
+    learn = host_ood_draws(jax.random.fold_in(k_q, 1), law, x, n_learn)
+    coll = Collection(x=x, learn=learn,
+                      pool=np.zeros((0, dim), np.float32), law=law)
+    return with_host_pool(coll, seed, n_pool)
+
+
+def with_host_pool(coll: Collection, seed: int, n_pool: int,
+                   pool_chunk: int = 16_384) -> Collection:
+    """:func:`with_pool` over host rows."""
+    k_pool = jax.random.fold_in(seed_key(seed), 2)
+    parts = [np.asarray(host_ood_draws(jax.random.fold_in(k_pool, i),
+                                       coll.law, coll.x, pool_chunk))
              for i in range(-(-n_pool // pool_chunk))]
     if not parts:
         return coll

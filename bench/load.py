@@ -31,6 +31,11 @@ WAIT_AFTER_CLOSE_S = 60.0
 # a closed loop's pool over the most that full steps at the set-up's
 # measured speed can answer
 POOL_MARGIN = 2.0
+# the least pool of a run: a set-up step slowed by other work on the host
+# must not leave a short window without queries (the full-size cells'
+# pools are larger; the pool's draws keep their order, so a floor adds
+# queries at its end and changes none that is served)
+POOL_FLOOR = 8192
 
 
 class Batch(NamedTuple):
@@ -190,11 +195,11 @@ def capacity(traffic: dict, seconds: float, full_batch_s: float,
     the run's query pool. A closed loop is answered at most ``max_batch``
     requests a step, so its pool holds POOL_MARGIN times what steps of
     ``full_batch_s`` (the warmed step's time, measured in set-up) answer
-    in the window; a run that would need more queries fails rather than
-    repeat one."""
+    in the window, and at least POOL_FLOOR; a run that would need more
+    queries fails rather than repeat one."""
     rate = max_batch / max(full_batch_s, 1e-4)
-    return int(math.ceil(POOL_MARGIN * rate * seconds)) \
-        + int(traffic["clients"])
+    return max(POOL_FLOOR, int(math.ceil(POOL_MARGIN * rate * seconds))
+               + int(traffic["clients"]))
 
 
 def drive(fe, pool: np.ndarray, traffic: dict, seconds: float, k: int,
@@ -216,6 +221,10 @@ def drive(fe, pool: np.ndarray, traffic: dict, seconds: float, k: int,
             except TimeoutError:
                 break
     win.trim()
+    # each future's callback holds the frontend (``_send``'s ``on_done``):
+    # let go of them, so that the frontend, its engine and the program's
+    # state are freed once the caller lets go of its own references
+    win.futures = []
     return win
 
 

@@ -2,7 +2,9 @@
 
 The reference is exact top-k by inner product over the full-precision
 rows, in plain ``jnp`` at ``precision="highest"``, computed on the device
-in blocks of queries and rows. It imports nothing of the program.
+in blocks of queries and rows. It imports nothing of the program. Over
+rows kept in host memory (``exact_topk_host``, ``scores_of_host``) each
+block of rows crosses to the device once, scored against every query.
 
 The comparison reads, for every checked request, the reference's top-10
 and the exact scores of the ten ids the served path returned:
@@ -93,6 +95,66 @@ def scores_of(queries: np.ndarray, x: jax.Array, ids: np.ndarray,
         i = np.pad(np.asarray(i, np.int32), ((0, pad), (0, 0)))
         out.append(np.asarray(_scores_of(jnp.asarray(q), x,
                                          jnp.asarray(i)))[:len(q) - pad])
+    return np.concatenate(out)
+
+
+# -- a store kept in host memory ------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("k", "precision"))
+def fold_rows(best, q, rows, start, n, k: int, precision):
+    """``best`` (values, ids) merged, as ``_topk_rows`` merges its blocks,
+    with the top-``k`` of ``q @ rows.T``: the block of host rows that
+    starts at row ``start``; its rows from ``n`` on are padding."""
+    s = jnp.matmul(q, rows.T, precision=precision)
+    ids = start + jnp.arange(rows.shape[0], dtype=jnp.int32)
+    v, i = jax.lax.top_k(jnp.where(ids < n, s, -jnp.inf), k)
+    v = jnp.concatenate([best[0], v], axis=1)
+    i = jnp.concatenate([best[1], i + start], axis=1)
+    v, sel = jax.lax.top_k(v, k)
+    return v, jnp.take_along_axis(i, sel, axis=1)
+
+
+def exact_topk_host(queries: np.ndarray, x: np.ndarray, k: int = 10,
+                    query_block: int = 1024, row_block: int = 65536,
+                    precision=HIGHEST):
+    """:func:`exact_topk` over rows in host memory. Every query is scored
+    at once (padded to whole ``query_block``s), and each block of
+    ``row_block`` rows goes to the device once: the store crosses once."""
+    n = x.shape[0]
+    row_block = min(row_block, n)
+    m = len(queries)
+    q = np.asarray(queries, np.float32)
+    q = jnp.asarray(np.pad(q, ((0, -m % query_block), (0, 0))))
+    best = (jnp.full((q.shape[0], k), -jnp.inf, jnp.float32),
+            jnp.full((q.shape[0], k), -1, jnp.int32))
+    for start in range(0, n, row_block):
+        rows = x[start:start + row_block]
+        if len(rows) < row_block:           # one compiled shape per run
+            rows = np.pad(rows, ((0, row_block - len(rows)), (0, 0)))
+        best = fold_rows(best, q, rows, np.int32(start), np.int32(n), k,
+                         precision)
+    return np.asarray(best[0])[:m], np.asarray(best[1])[:m]
+
+
+@jax.jit
+def scores_of_rows(q, rows):
+    """Exact scores of each query's given rows, ``(m, k, D)``."""
+    return jnp.einsum("mkd,md->mk", rows, q, precision=HIGHEST)
+
+
+def scores_of_host(queries: np.ndarray, x: np.ndarray, ids: np.ndarray,
+                   block: int = 1024) -> np.ndarray:
+    """:func:`scores_of` over rows in host memory: the answered rows are
+    gathered on the host."""
+    out = []
+    for s in range(0, len(queries), block):
+        q, i = queries[s:s + block], ids[s:s + block]
+        pad = block - len(q)
+        q = np.pad(np.asarray(q, np.float32), ((0, pad), (0, 0)))
+        i = np.pad(np.asarray(i, np.int64), ((0, pad), (0, 0)))
+        rows = x[np.clip(i, 0, x.shape[0] - 1)]
+        out.append(np.asarray(scores_of_rows(jnp.asarray(q),
+                                             rows))[:len(q) - pad])
     return np.concatenate(out)
 
 

@@ -11,11 +11,13 @@ process the run
 1. exits nonzero, before any set-up, unless JAX sees a TPU with as many
    chips as the cell asks for;
 2. makes the configuration's collection (from its ``collection_seed``)
-   on the device; fits, encodes, sorts and indexes it through the
-   program's own build calls; builds the ``ServingEngine`` and
-   ``ServingFrontend`` (which compiles every bucket shape); and draws the
-   run's queries (from ``--seed``) on the device: all of that is
-   ``setup_s``;
+   on the device, or, for a configuration whose ``store`` is ``host``,
+   chunk by chunk into host memory; fits, encodes, sorts and indexes it
+   through the program's own build calls (demoting the program's f32
+   rerank store to host memory for a host store); builds the
+   ``ServingEngine`` and ``ServingFrontend`` (which compiles every bucket
+   shape); and draws the run's queries (from ``--seed``) on the device:
+   all of that is ``setup_s``;
 3. offers the traffic through ``ServingFrontend.enqueue`` for
    ``--seconds`` and fails if anything compiles inside the window;
 4. reads the device's peak memory, frees the program's state, and checks
@@ -40,7 +42,7 @@ import sys  # noqa: E402
 import tempfile  # noqa: E402
 import threading  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import NamedTuple, Optional  # noqa: E402
+from typing import Callable, NamedTuple, Optional  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -107,6 +109,36 @@ class Spec:
 
 # -- set-up ----------------------------------------------------------------
 
+class Placement(NamedTuple):
+    """The harness's functions for where a configuration keeps its f32
+    rerank store."""
+    make: Callable          # the collection (``datagen.make``'s arguments)
+    with_pool: Callable     # its served pool
+    exact_topk: Callable    # the reference's top-k
+    scores_of: Callable     # the reference's scores of served ids
+
+
+def store(cfg: dict) -> str:
+    """Where the configuration keeps its f32 rerank store: ``device`` (the
+    default) or ``host``, a deployment whose store lives in host memory."""
+    where = cfg.get("store", "device")
+    if where not in ("device", "host"):
+        raise ValueError(f"unknown store {where!r}: device or host")
+    return where
+
+
+def placement(cfg: dict) -> Placement:
+    """The functions for the configuration's :func:`store`. The host ones
+    never hand the device more than one chunk of rows or one block of the
+    reference's rows."""
+    from bench import datagen, reference
+    if store(cfg) == "host":
+        return Placement(datagen.make_host, datagen.with_host_pool,
+                         reference.exact_topk_host, reference.scores_of_host)
+    return Placement(datagen.make, datagen.with_pool,
+                     reference.exact_topk, reference.scores_of)
+
+
 class Served(NamedTuple):
     engine: object
     frontend: object
@@ -117,11 +149,16 @@ def build(cfg: dict, x, learn, capacity: int, buckets=None):
     """Fit (on a sample drawn from the configuration's ``collection_seed``),
     encode, sort and index ``x`` the way ``repro.launch.serve``'s
     ``run_search`` does, and build the engine and the frontend (whose
-    construction compiles and warms every bucket shape). Returns the
-    :class:`Served` and the clock readings after the fit, the build and
-    the engine (whose construction compiles its own batch shape)."""
+    construction compiles and warms every bucket shape). A host store
+    (``x`` a host array) goes to the program's build calls as it is, and
+    the program's store is demoted to host memory after the index is
+    built, in the order of ``repro.launch.serve.build_state``'s
+    ``--host-rerank``. Returns the :class:`Served` and the clock readings
+    after the fit, the build and the engine (whose construction compiles
+    its own batch shape)."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
     from repro.core import gleanvec as gv
     from repro.core import search as msearch
     from repro.index import ivf
@@ -131,12 +168,16 @@ def build(cfg: dict, x, learn, capacity: int, buckets=None):
     from bench import datagen
 
     seed = cfg["collection_seed"]
+    host = store(cfg) == "host"
     n = x.shape[0]
     rows = x
     if n > FIT_ROWS:
         pick = jax.random.choice(datagen.seed_key(seed), n, (FIT_ROWS,),
                                  replace=False)
-        rows = x[jnp.sort(pick)]
+        if host:
+            rows = jnp.asarray(x[np.asarray(jnp.sort(pick))])
+        else:
+            rows = x[jnp.sort(pick)]
     model = gv.fit(jax.random.PRNGKey(0), learn, rows, c=cfg["clusters"],
                    d=cfg["d"])
     jax.block_until_ready(model)
@@ -145,11 +186,16 @@ def build(cfg: dict, x, learn, capacity: int, buckets=None):
     artifacts = msearch.build_artifacts(cfg["mode"], x, model)
     index = None
     if cfg["index"] == "ivf-aligned":
-        index = ivf.build_aligned(model, x, nprobe=cfg["nprobe"])
+        # over the store as build_artifacts placed it (``x`` itself for a
+        # device store): a host ``x`` would cross to the device again
+        index = ivf.build_aligned(model, artifacts.x_full,
+                                  nprobe=cfg["nprobe"])
         if cfg["reduced_probe"]:
             index = ivf.with_reduced_centers(index, artifacts.scorer, model)
     elif cfg["index"] != "flat":
         raise ValueError(f"unknown index {cfg['index']!r}")
+    if host:
+        artifacts = msearch.demote_rerank_tier(artifacts)
     state = msearch.make_state(artifacts, index=index,
                                block=cfg["layout_block"])
     jax.block_until_ready(state)
@@ -279,21 +325,23 @@ def measure(frontend, pool, traffic: dict, seconds: float, k: int,
 
 def check_answers(cfg: dict, x, pool, win, seed: int):
     """Compare a sample (drawn from the seed) of the window's answers with
-    the exact reference. Returns ``(correct, checks, comparison)``:
+    the exact reference (over ``x`` where the configuration keeps it).
+    Returns ``(correct, checks, comparison)``:
     ``checks`` holds each number compared beside its limit: the requests
     left without an answer, and each number the configuration's
     ``check_limits`` names."""
     import numpy as np
     from bench import load, reference
 
+    place = placement(cfg)
     rows = load.answered(win)
     if len(rows) > CHECK_MAX:
         rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 3])
         rows = np.sort(rng.choice(rows, CHECK_MAX, replace=False))
     queries = pool[rows]
-    ref_scores, ref_ids = reference.exact_topk(queries, x, cfg["k"])
+    ref_scores, ref_ids = place.exact_topk(queries, x, cfg["k"])
     got = win.ids[rows]
-    cmp = reference.compare(got, reference.scores_of(queries, x, got),
+    cmp = reference.compare(got, place.scores_of(queries, x, got),
                             ref_ids, ref_scores, cfg["n"])
     failed = int(np.sum(win.in_window & ~win.ok))
     checks = {"failed": {"value": failed, "limit": 0}}
@@ -359,16 +407,17 @@ def run_cell(spec: Spec, cell_name: str, seed: int, seconds: float,
     chip (``main`` checks for one)."""
     import jax
     import numpy as np
-    from bench import datagen, load, work
+    from bench import load, work
 
     t0 = T_PROCESS if t_process is None else t_process
     cell = spec.cell(cell_name)
     cfg = spec.config(cell["config"])
     traffic = spec.traffic(cell["traffic"])
+    place = placement(cfg)
 
     t = time.perf_counter()
-    coll = datagen.make(cfg["collection_seed"], seed, cfg["n"], cfg["dim"],
-                        LEARN_QUERIES, 0)
+    coll = place.make(cfg["collection_seed"], seed, cfg["n"], cfg["dim"],
+                      LEARN_QUERIES, 0)
     jax.block_until_ready(coll.x)
     t_data = time.perf_counter()
     compiles = CompileCounter()
@@ -378,7 +427,7 @@ def run_cell(spec: Spec, cell_name: str, seed: int, seconds: float,
     finally:
         compiles.close()
     t_buckets = time.perf_counter()
-    coll = datagen.with_pool(coll, seed, load.capacity(
+    coll = place.with_pool(coll, seed, load.capacity(
         traffic, seconds, full_batch_s(served, coll.learn, cfg["max_batch"]),
         cfg["max_batch"]))
     inst = load.Instrumented(served.frontend, keep_queries=trace)
@@ -426,6 +475,10 @@ def run_cell(spec: Spec, cell_name: str, seed: int, seconds: float,
               window=win, batches=load.batches_in(inst, win), setup=split,
               trace=reduced, work=per_batch, peaks=peaks)
     del served, inst
+    if store(cfg) == "host":
+        # JAX's tracing caches keep the tree structure of the arguments
+        # they traced, and a host store rides the state's as static data
+        jax.clear_caches()
     gc.collect()
 
     t = time.perf_counter()

@@ -55,17 +55,18 @@ def split_cell(spec, cell_name: str, seed: int, seconds: float,
     """One traced window of ``cell_name``, split; needs no chip.
     ``where`` finds the device operations (:class:`bench.scopes.Split`)."""
     import jax
-    from bench import datagen, load, run, scopes, trace_reduce as tr
+    from bench import load, run, scopes, trace_reduce as tr
 
     cell = spec.cell(cell_name)
     cfg = spec.config(cell["config"])
     traffic = spec.traffic(cell["traffic"])
-    coll = datagen.make(cfg["collection_seed"], seed, cfg["n"], cfg["dim"],
-                        run.LEARN_QUERIES, 0)
+    place = run.placement(cfg)
+    coll = place.make(cfg["collection_seed"], seed, cfg["n"], cfg["dim"],
+                      run.LEARN_QUERIES, 0)
     served, _ = run.build(cfg, coll.x, coll.learn, int(traffic["queue"]))
     rows = cfg["max_batch"]
     hlo = served.engine.lower(rows).compile().as_text()
-    coll = datagen.with_pool(coll, seed, load.capacity(
+    coll = place.with_pool(coll, seed, load.capacity(
         traffic, seconds, run.full_batch_s(served, coll.learn, rows), rows))
     inst = load.Instrumented(served.frontend)
     gc.collect()

@@ -17,18 +17,26 @@ TINY = {"n": 8192, "dim": 32, "metric": "inner_product", "queries": "ood",
         "collection_seed": 7, "source": "https://arxiv.org/abs/2410.22347"}
 TINY_IVF = dict(TINY, index="ivf-aligned", nprobe=4, reduced_probe=True)
 TINY_FLAT = dict(TINY, index="flat", nprobe=0, reduced_probe=False)
+# twins whose f32 rerank store lives in host memory
+TINY_HOST_IVF = dict(TINY_IVF, store="host")
+TINY_HOST_FLAT = dict(TINY_FLAT, store="host")
 TRAFFIC = {"loop": "closed", "clients": 8, "queue": 16}
 
 
 def make_root(tmp: Path, limits: dict) -> Path:
     """A copy of the benchmark under ``tmp`` with the tiny cells
-    ``tiny-ivf`` and ``tiny-flat`` (closed loop of 8 clients) added, each
-    reporting the metrics of the full-size cell of its index kind."""
+    ``tiny-ivf`` and ``tiny-flat`` and their host-store twins
+    ``tiny-host-ivf`` and ``tiny-host-flat`` (closed loop of 8 clients)
+    added, each reporting the metrics of the full-size cell of its index
+    kind."""
     shutil.copytree(REPO / "bench", tmp / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     spec = json.loads((REPO / "BENCHMARK.json").read_text())
-    for name, cfg, like in (("tiny-ivf", TINY_IVF, "t2i-8m-ivf-closed"),
-                            ("tiny-flat", TINY_FLAT, "rqa-4m-flat-closed")):
+    for name, cfg, like in (
+            ("tiny-ivf", TINY_IVF, "t2i-8m-ivf-closed"),
+            ("tiny-flat", TINY_FLAT, "rqa-4m-flat-closed"),
+            ("tiny-host-ivf", TINY_HOST_IVF, "t2i-8m-ivf-closed"),
+            ("tiny-host-flat", TINY_HOST_FLAT, "rqa-4m-flat-closed")):
         (tmp / "bench" / "configs" / f"{name}.json").write_text(
             json.dumps(dict(cfg, check_limits=limits)))
         spec["configs"].append({"name": name, "source": cfg["source"],

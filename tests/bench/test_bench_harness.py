@@ -3,6 +3,7 @@ metric by name: adding a cell takes new files only."""
 import filecmp
 import json
 import time
+import weakref
 
 import numpy as np
 import pytest
@@ -142,22 +143,31 @@ def _bf16_reference(orig, n):
     from bench import reference
 
     def step(self, queries, state):
-        x = state.artifacts.x_full.astype(jnp.bfloat16).astype(jnp.float32)
+        # the store where the program keeps it: a device array or, for a
+        # host store, a host array (``np.asarray`` reads both)
+        x = jnp.asarray(np.asarray(state.artifacts.x_full), jnp.bfloat16
+                        ).astype(jnp.float32)
         q = np.asarray(jnp.asarray(queries, jnp.bfloat16)
                        .astype(jnp.float32))
         return reference.exact_topk(q, x, self.k, query_block=len(q))[1]
     return step
 
 
-@pytest.mark.parametrize("fault", [_altered, _half_batch, _stale,
-                                   _bf16_reference])
-def test_broken_timed_path_is_not_correct(tmp_path, monkeypatch, fault):
+FAULTS = [_altered, _half_batch, _stale, _bf16_reference]
+
+
+@pytest.mark.parametrize("fault,cell", [
+    *(pytest.param(f, "tiny-ivf", id=f.__name__) for f in FAULTS),
+    *(pytest.param(f, "tiny-host-ivf", id=f"host{f.__name__}")
+      for f in FAULTS)])
+def test_broken_timed_path_is_not_correct(tmp_path, monkeypatch, fault,
+                                          cell):
     from repro.serve.engine import ServingEngine
     root = benchkit.make_root(tmp_path, _limits())
     monkeypatch.setattr(ServingEngine, "search_with",
                         fault(ServingEngine.search_with,
                               benchkit.TINY["n"]))
-    out = run.run_cell(run.Spec(root), "tiny-ivf", 2 ** 33 + 77, 0.3, False,
+    out = run.run_cell(run.Spec(root), cell, 2 ** 33 + 77, 0.3, False,
                        t_process=time.perf_counter())
     print(out["checks"])
     assert not out["correct"], out["checks"]
@@ -186,3 +196,90 @@ def test_a_compile_inside_the_window_fails_the_run(tmp_path, monkeypatch):
     with pytest.raises(RuntimeError, match="inside the measured window"):
         run.run_cell(run.Spec(root), "tiny-ivf", 2 ** 33 + 78, 0.3, False,
                      t_process=time.perf_counter())
+
+
+# -- a store kept in host memory -------------------------------------------
+
+class _Served:
+    """Records, for each engine built, a weak reference to it and to its
+    host store, and counts the host store's gathers of candidate rows."""
+
+    def __init__(self, monkeypatch):
+        from repro.core import rerank_tier
+        from repro.core import search as msearch
+        from repro.serve.engine import ServingEngine
+        self.engines, self.stores, self.takes = [], [], [0]
+        init, take = ServingEngine.__init__, rerank_tier.HostStore.take
+
+        def record(engine, state, *args, **kwargs):
+            init(engine, state, *args, **kwargs)
+            host = msearch.host_tier(state.artifacts)
+            self.engines.append(weakref.ref(engine))
+            self.stores.append(None if host is None else weakref.ref(host))
+
+        def counted(store, ids):
+            self.takes[0] += 1
+            return take(store, ids)
+
+        monkeypatch.setattr(ServingEngine, "__init__", record)
+        monkeypatch.setattr(rerank_tier.HostStore, "take", counted)
+
+
+@pytest.mark.parametrize("cell", ["tiny-host-ivf", "tiny-host-flat"])
+def test_host_store_cell_is_correct_through_the_host_branch(
+        root, monkeypatch, cell):
+    served = _Served(monkeypatch)
+    out = run.run_cell(run.Spec(root), cell, 2 ** 33 + 5, 0.5, False,
+                       t_process=time.perf_counter())
+    assert out["correct"], out["checks"]
+    assert out["metrics"]["recall10"]["value"] > 99.0
+    assert out["attempted"] > 0 and out["failed"] == 0
+    # the engine served a host store: every step gathered its candidate
+    # rows from host memory
+    assert len(served.stores) == 1 and served.stores[0] is not None
+    assert served.takes[0] >= out["attempted"] / benchkit.TINY["max_batch"]
+
+
+@pytest.mark.parametrize("cell,trace", [("tiny-ivf", False),
+                                        ("tiny-host-ivf", True)])
+def test_program_state_is_freed_before_the_reference(root, monkeypatch,
+                                                     cell, trace):
+    served = _Served(monkeypatch)
+    check = run.check_answers
+    gone = []
+
+    def checked(*args, **kwargs):
+        gone.append([r() is None for r in served.engines]
+                    + [r() is None for r in served.stores if r is not None])
+        return check(*args, **kwargs)
+
+    monkeypatch.setattr(run, "check_answers", checked)
+    monkeypatch.setattr(work, "peaks",
+                        lambda kind: work.Peaks(819e9, 393e12))
+    out = run.run_cell(run.Spec(root), cell, 2 ** 33 + 6, 0.5, trace,
+                       t_process=time.perf_counter())
+    assert out["correct"], out["checks"]
+    assert len(served.engines) == 1 and gone == [[True] * len(gone[0])]
+    assert len(gone[0]) == (2 if cell.startswith("tiny-host") else 1)
+    if trace:           # the readers of the work counts read a host store
+        assert {"step_ms", "ivf_scan_reuse"} <= set(out["metrics"])
+
+
+@pytest.mark.parametrize("cfg", [benchkit.TINY_IVF, benchkit.TINY_FLAT])
+def test_work_counts_read_the_same_for_a_host_store(cfg):
+    """``batch_work`` reads the probe sets and the scorer's ``perm``, not
+    the store: a host store's state gives the same counts."""
+    from bench import datagen, load
+    coll = datagen.make(cfg["collection_seed"], 11, cfg["n"], cfg["dim"],
+                        run.LEARN_QUERIES, 64)
+    batches = [load.Batch(i, 0.0, 0.0, 3, coll.pool[4 * i:4 * i + 4])
+               for i in range(4)]
+    counts = {}
+    for where in ("device", "host"):
+        c = dict(cfg, store=where)
+        x = coll.x if where == "device" else np.asarray(coll.x)
+        served, _ = run.build(c, x, coll.learn, 16)
+        served.frontend.close()
+        counts[where] = run.batch_work(c, served.state, batches)
+    assert counts["host"] == counts["device"]
+    assert all(w["step"].ops > 0 for w in counts["host"].values())

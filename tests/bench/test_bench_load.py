@@ -80,7 +80,9 @@ def test_closed_loop_stops_at_the_end_of_its_pool():
 def test_closed_pool_covers_twice_what_full_steps_answer():
     traffic = dict(CLOSED, clients=256, queue=512)
     # steps of 0.5 s answer at most 64 / 0.5 = 128 requests a second
-    assert load.capacity(traffic, 10.0, 0.5, 64) == 2 * 128 * 10 + 256
+    assert load.capacity(traffic, 51.0, 0.5, 64) == 2 * 128 * 51 + 256
+    # a short window, or a slow set-up step, still gets the floor
+    assert load.capacity(traffic, 10.0, 0.5, 64) == load.POOL_FLOOR
     t = time.perf_counter()
     assert load.capacity(traffic, 51.0, 0.3, 64) > 51 * 64 / 0.3
     assert time.perf_counter() - t < 1.0
