@@ -20,7 +20,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import linalg, spherical_kmeans
+from repro.core import linalg, rerank_tier, spherical_kmeans
 from repro.core.leanvec_sphering import SpheringModel
 
 __all__ = ["GleanVecModel", "fit", "fit_from_moments", "assign_tags",
@@ -108,7 +108,13 @@ def fit(key: jax.Array, queries: jax.Array, database: jax.Array, c: int,
 def assign_tags(model: GleanVecModel, database: jax.Array) -> jax.Array:
     """Eq. (19) cluster assignment under the model's fixed landmarks (the
     tag half of :func:`encode_database`; streaming inserts use it alone to
-    route rank-1 moment updates). Row-sliced: no (n, D) temporary."""
+    route rank-1 moment updates). Row-sliced: no (n, D) temporary. A host
+    store (``rerank_tier.is_host``) is streamed to the device one chunk at
+    a time."""
+    if rerank_tier.is_host(database):
+        return jnp.concatenate(rerank_tier.map_host_chunks(
+            lambda i, rows: _assign_tags_jit(model, rows), database))
+
     def tag(x):
         return spherical_kmeans.assign(
             spherical_kmeans.normalize_rows(x.astype(jnp.float32)),
@@ -117,6 +123,10 @@ def assign_tags(model: GleanVecModel, database: jax.Array) -> jax.Array:
     database = jnp.asarray(database)
     return linalg.map_row_blocks(tag, [database],
                                  linalg.block_rows(database.shape[1] * 8))
+
+
+# one compiled program for every chunk of a host store
+_assign_tags_jit = jax.jit(assign_tags)
 
 
 def encode_rows(model: GleanVecModel, tags: jax.Array,
@@ -133,8 +143,13 @@ def encode_database(model: GleanVecModel, database: jax.Array):
 
     Returns ``(tags: (n,) int32, x_low: (n, d))``. The pair is what a
     deployment stores contiguously per vector. Row-sliced, so the gathered
-    (rows, d, D) projections never exceed one slice.
+    (rows, d, D) projections never exceed one slice. A host store is
+    streamed to the device one chunk at a time.
     """
+    if rerank_tier.is_host(database):
+        parts = rerank_tier.map_host_chunks(
+            lambda i, rows: _encode_database_jit(model, rows), database)
+        return tuple(jnp.concatenate(p) for p in zip(*parts))
     database = jnp.asarray(database, jnp.float32)
     tags = assign_tags(model, database)
     b = model.b
@@ -142,6 +157,9 @@ def encode_database(model: GleanVecModel, database: jax.Array):
         lambda t, x: encode_rows(model, t, x), [tags, database],
         linalg.block_rows(b.shape[1] * b.shape[2] * 4))
     return tags, x_low
+
+
+_encode_database_jit = jax.jit(encode_database)
 
 
 def project_queries_eager(model: GleanVecModel, queries: jax.Array):
@@ -165,8 +183,46 @@ def inner_products_eager(q_views: jax.Array, tags: jax.Array,
     return jnp.sum(q_views[tags] * x_low, axis=-1)
 
 
+def _tag_layout(tags, block: int = 4096, slack_blocks: int = 0):
+    """The cluster-contiguous layout of rows with these ``tags`` (see
+    :func:`sort_by_tag`), from the tags alone: ``perm`` (numpy int64,
+    ``perm[i_sorted] = original id``, -1 for padding) and ``block_tags``
+    (numpy int32, the one tag of each ``block`` of sorted rows)."""
+    import numpy as np
+    tags_np = np.asarray(tags)
+    n = tags_np.shape[0]
+    c = int(tags_np.max()) + 1 if n else 1
+    counts = np.bincount(tags_np, minlength=c)
+    padded = counts + (-counts) % block + slack_blocks * block
+    order = np.argsort(tags_np, kind="stable")
+    sorted_tags = tags_np[order]
+    # cluster t's j-th row (in id order) lands on row j of its range
+    rank = np.arange(n) - (np.cumsum(counts) - counts)[sorted_tags]
+    perm = np.full(int(padded.sum()), -1, np.int64)
+    perm[(np.cumsum(padded) - padded)[sorted_tags] + rank] = order
+    block_tags = np.repeat(np.arange(c, dtype=np.int32), padded // block)
+    return perm, block_tags
+
+
+def _place_rows_host(rows, perm):
+    import numpy as np
+    rows = np.asarray(rows)
+    if not rows.shape[0]:
+        return jnp.zeros((len(perm),) + rows.shape[1:], rows.dtype)
+    out = rows[np.maximum(perm, 0)]
+    out[perm < 0] = 0
+    return jnp.asarray(out)
+
+
+@jax.jit
+def _place_rows_device(rows, perm):
+    picked = jnp.take(rows, jnp.maximum(perm, 0), axis=0)
+    return jnp.where((perm >= 0)[:, None], picked,
+                     jnp.zeros((), rows.dtype))
+
+
 def sort_by_tag(tags, x_low, x_full=None, block: int = 4096,
-                slack_blocks: int = 0):
+                slack_blocks: int = 0, on_device: bool = False):
     """Cluster-contiguous layout for the sorted scorers / scans (see
     core.scorer.SortedGleanVecScorer): sorts rows by tag and pads each
     cluster to a ``block`` multiple, so every block of the sorted database
@@ -181,37 +237,21 @@ def sort_by_tag(tags, x_low, x_full=None, block: int = 4096,
     Returns (x_low_sorted, block_tags, perm, x_full_sorted) where
     ``perm[i_sorted] = original id`` (padding rows map to id -1 and are
     filled with zeros; sorted scorers additionally mask them to -inf).
+    The layout comes from the tags alone (:func:`_tag_layout`); the rows
+    are reordered on the host, or with ``on_device`` in one gather on the
+    device (rows that never cross to the host, as a host store's codes:
+    the gather's temporaries take about three times the rows' bytes).
     """
-    import numpy as np
-    tags_np = np.asarray(tags)
-    x_low_np = np.asarray(x_low)
-    n, d = x_low_np.shape
-    order = np.argsort(tags_np, kind="stable")
-    sorted_tags = tags_np[order]
-    c = int(tags_np.max()) + 1 if n else 1
-    rows, perm, blk_tags = [], [], []
-    full_rows = None if x_full is None else []
-    x_full_np = None if x_full is None else np.asarray(x_full)
-    for ci in range(c):
-        sel = order[sorted_tags == ci]
-        pad = (-len(sel)) % block + slack_blocks * block
-        rows.append(x_low_np[sel])
-        perm.append(sel.astype(np.int64))
-        if full_rows is not None:
-            full_rows.append(x_full_np[sel])
-        if pad:
-            rows.append(np.zeros((pad, d), x_low_np.dtype))
-            perm.append(np.full(pad, -1, np.int64))
-            if full_rows is not None:
-                full_rows.append(
-                    np.zeros((pad, x_full_np.shape[1]), x_full_np.dtype))
-        blk_tags.extend([ci] * ((len(sel) + pad) // block))
-    x_low_sorted = jnp.asarray(np.concatenate(rows, axis=0))
-    perm = jnp.asarray(np.concatenate(perm))
-    block_tags = jnp.asarray(np.asarray(blk_tags, np.int32))
-    x_full_sorted = (None if full_rows is None
-                     else jnp.asarray(np.concatenate(full_rows, axis=0)))
-    return x_low_sorted, block_tags, perm, x_full_sorted
+    perm, block_tags = _tag_layout(tags, block, slack_blocks)
+    if on_device:
+        perm = jnp.asarray(perm)
+        place = _place_rows_device
+    else:
+        place = _place_rows_host
+    x_low_sorted = place(x_low, perm)
+    x_full_sorted = None if x_full is None else place(x_full, perm)
+    return x_low_sorted, jnp.asarray(block_tags), jnp.asarray(perm), \
+        x_full_sorted
 
 
 def inverse_permutation(perm, n: int):

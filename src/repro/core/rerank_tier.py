@@ -45,7 +45,11 @@ import numpy as np
 
 __all__ = ["HostStore", "ShardedHostStore", "demote", "promote",
            "host_store", "host_arrays", "from_host_arrays",
-           "supports_pinned_host"]
+           "supports_pinned_host", "is_host", "read_only",
+           "map_host_chunks", "HOST_CHUNK"]
+
+# rows of a host store that one pass over it sends to the device at once
+HOST_CHUNK = 100_000
 
 
 class _At:
@@ -115,7 +119,8 @@ class _HostTier:
 
 
 class HostStore(_HostTier):
-    """Single host buffer holding the (n, D) full-precision rerank tier."""
+    """Single host buffer holding the (n, D) full-precision rerank tier. A
+    C-contiguous array is held as it is: the store shares its memory."""
 
     def __init__(self, x: np.ndarray):
         self.x = np.ascontiguousarray(np.asarray(x))
@@ -236,11 +241,44 @@ def host_store(x) -> Optional[_HostTier]:
     return x if isinstance(x, _HostTier) else None
 
 
+def is_host(x) -> bool:
+    """Whether ``x`` is a store to be served from host memory where it
+    lies: a :class:`HostStore`, or a read-only numpy array (its owner
+    promises the rows do not change under the engine, so the build reads
+    it in place and never copies it; a writable array goes to the device
+    as before)."""
+    return isinstance(x, HostStore) or (isinstance(x, np.ndarray)
+                                        and not x.flags.writeable)
+
+
+def read_only(x) -> np.ndarray:
+    """A read-only view of host rows (no copy): what :func:`is_host`
+    takes for a host store."""
+    view = np.asarray(x).view()
+    view.flags.writeable = False
+    return view
+
+
+def map_host_chunks(fn, x) -> list:
+    """``[fn(i, rows_i)]`` over consecutive :data:`HOST_CHUNK`-row slices
+    of the host store ``x``: slice ``i`` is sent to the device alone, and
+    its result is ready and its rows dropped before the next is sent, so
+    the device never holds more than one chunk of the store."""
+    a = x.x if isinstance(x, HostStore) else x
+    out = []
+    for i, start in enumerate(range(0, a.shape[0], HOST_CHUNK)):
+        rows = jax.device_put(a[start:start + HOST_CHUNK])
+        out.append(jax.block_until_ready(fn(i, rows)))
+        del rows
+    return out
+
+
 def demote(x_full, shards: int = 0) -> Union[HostStore, ShardedHostStore]:
     """Move a full-precision store to the host tier. ``shards > 0`` splits
     it into that many equal contiguous row shards (spill-to-host for
     sharded placements); rows must divide evenly, matching
-    ``build_sharded_index``'s partition."""
+    ``build_sharded_index``'s partition. A host store (:func:`is_host`)
+    stays the caller's array: wrapped, never copied."""
     if isinstance(x_full, _HostTier):
         return x_full
     x = np.asarray(x_full)

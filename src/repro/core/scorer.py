@@ -79,7 +79,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import gleanvec as gv
-from repro.core import linalg
+from repro.core import linalg, rerank_tier
 from repro.core import quantization as quant
 from repro.core.quantization import ClusteredSQDatabase
 
@@ -1041,29 +1041,76 @@ def quantized_scorer(model, database: jax.Array,
                            a=model.a)
 
 
-def _encode_quantized(model, database: jax.Array, bits: int):
-    """Tags + per-cluster SQ codes of ``database`` in two passes over row
-    slices -- ranges first, then codes -- so the f32 reduced vectors never
-    exist for all rows at once (at 10M rows they would take as much device
-    memory as the full-precision store). Same codes and scales as
-    ``quantize_per_cluster(encode_database(...))``."""
-    database = jnp.asarray(database, jnp.float32)
-    tags = gv.assign_tags(model, database)
+def _code_block(model) -> int:
     c, d, d_full = model.b.shape
-    block = linalg.block_rows(d * d_full * 4)
+    return linalg.block_rows(d * d_full * 4)
+
+
+def _cluster_ranges(model, tags: jax.Array, rows: jax.Array):
+    """Per-cluster (min, max) of ``B_t x`` over ``rows``, a row slice at
+    a time."""
+    c, d, _ = model.b.shape
 
     def ranges(acc, t, x, fresh):
         lo, hi = quant.cluster_ranges(gv.encode_rows(model, t, x), t, c)
         return jnp.minimum(acc[0], lo), jnp.maximum(acc[1], hi)
 
     init = (jnp.full((c, d), jnp.inf), jnp.full((c, d), -jnp.inf))
-    lo, delta = quant.cluster_steps(
-        *linalg.fold_row_blocks(ranges, init, [tags, database], block), bits)
-    codes = linalg.map_row_blocks(
+    return linalg.fold_row_blocks(ranges, init, [tags, rows],
+                                  _code_block(model))
+
+
+def _cluster_codes(model, tags: jax.Array, rows: jax.Array, lo, delta,
+                   bits: int):
+    """u8 codes of ``rows`` under their clusters' (lo, delta), a row slice
+    at a time."""
+    return linalg.map_row_blocks(
         lambda t, x: quant.quantize_rows(gv.encode_rows(model, t, x), t, lo,
                                          delta, bits),
-        [tags, database], block)
+        [tags, rows], _code_block(model))
+
+
+_cluster_ranges_jit = jax.jit(_cluster_ranges)
+_cluster_codes_jit = jax.jit(_cluster_codes, static_argnames=("bits",))
+
+
+def _encode_quantized(model, database: jax.Array, bits: int):
+    """Tags + per-cluster SQ codes of ``database`` in two passes over row
+    slices -- ranges first, then codes -- so the f32 reduced vectors never
+    exist for all rows at once (at 10M rows they would take as much device
+    memory as the full-precision store). Same codes and scales as
+    ``quantize_per_cluster(encode_database(...))``.
+
+    A host store (``rerank_tier.is_host``) never goes to the device whole:
+    it is streamed one chunk at a time, twice -- tags and ranges, then
+    codes from the kept tags -- and gives the same tags and codes."""
+    if rerank_tier.is_host(database):
+        return _encode_quantized_host(model, database, bits)
+    database = jnp.asarray(database, jnp.float32)
+    tags = gv.assign_tags(model, database)
+    lo, delta = quant.cluster_steps(
+        *_cluster_ranges(model, tags, database), bits)
+    codes = _cluster_codes(model, tags, database, lo, delta, bits)
     return tags, ClusteredSQDatabase(codes=codes, lo=lo, delta=delta)
+
+
+def _encode_quantized_host(model, database, bits: int):
+    def tags_and_ranges(i, rows):
+        t = gv._assign_tags_jit(model, rows)
+        return t, _cluster_ranges_jit(model, t, rows)
+
+    first = rerank_tier.map_host_chunks(tags_and_ranges, database)
+    tags = [t for t, _ in first]
+    lo, delta = quant.cluster_steps(
+        jnp.min(jnp.stack([lo for _, (lo, _) in first]), axis=0),
+        jnp.max(jnp.stack([hi for _, (_, hi) in first]), axis=0), bits)
+    del first
+    codes = rerank_tier.map_host_chunks(
+        lambda i, rows: _cluster_codes_jit(model, tags[i], rows, lo, delta,
+                                           bits), database)
+    codes = jnp.concatenate(codes)
+    return (jnp.concatenate(tags),
+            ClusteredSQDatabase(codes=codes, lo=lo, delta=delta))
 
 
 def gleanvec_quantized_scorer(model, database: jax.Array,
@@ -1080,8 +1127,9 @@ def sorted_gleanvec_scorer(model, database: jax.Array, block: int = 4096,
     padded to a ``block`` multiple, one tag per block. ``slack_blocks``
     reserves extra free blocks per cluster for streaming inserts."""
     tags, x_low = gv.encode_database(model, database)
-    xs, block_tags, perm, _ = gv.sort_by_tag(tags, x_low, block=block,
-                                             slack_blocks=slack_blocks)
+    xs, block_tags, perm, _ = gv.sort_by_tag(
+        tags, x_low, block=block, slack_blocks=slack_blocks,
+        on_device=rerank_tier.is_host(database))
     inv = gv.inverse_permutation(perm, x_low.shape[0])
     return SortedGleanVecScorer(x_low=xs, block_tags=block_tags,
                                 perm=perm.astype(jnp.int32), inv_perm=inv,
@@ -1096,8 +1144,10 @@ def sorted_gleanvec_quantized_scorer(
     codes/scales as :func:`gleanvec_quantized_scorer` (quantize first, then
     sort), so scores match the unsorted scorer exactly."""
     tags, db = _encode_quantized(model, database, bits)
-    cs, block_tags, perm, _ = gv.sort_by_tag(tags, db.codes, block=block,
-                                             slack_blocks=slack_blocks)
+    # a host store's codes stay on the device: they are reordered there
+    cs, block_tags, perm, _ = gv.sort_by_tag(
+        tags, db.codes, block=block, slack_blocks=slack_blocks,
+        on_device=rerank_tier.is_host(database))
     inv = gv.inverse_permutation(perm, tags.shape[0])
     return SortedGleanVecQuantizedScorer(
         codes=cs, block_tags=block_tags, perm=perm.astype(jnp.int32),

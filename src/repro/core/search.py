@@ -81,10 +81,22 @@ def build_artifacts(mode: str, database: jax.Array,
                     model=None) -> SearchArtifacts:
     """Mode-string construction covering every scorer (see ``scorer.MODES``):
     full / sphering / gleanvec / sphering-int8 / gleanvec-int8 /
-    gleanvec-sorted / gleanvec-int8-sorted."""
+    gleanvec-sorted / gleanvec-int8-sorted.
+
+    A host store (``rerank_tier.is_host``: a read-only numpy array or a
+    ``HostStore``) stays where it is: the gleanvec scorers stream it to
+    the device a chunk at a time, and ``x_full`` is a ``HostStore`` over
+    the caller's array (float32 rows are not copied), so the artifacts
+    are already demoted (:func:`demote_rerank_tier` returns them as they
+    are)."""
+    if isinstance(database, rerank_tier.HostStore):
+        x_full = database
+    elif rerank_tier.is_host(database):
+        x_full = rerank_tier.HostStore(np.asarray(database, np.float32))
+    else:
+        x_full = jnp.asarray(database, jnp.float32)
     return SearchArtifacts(scorer=sc.build_scorer(mode, database, model),
-                           x_full=jnp.asarray(database, jnp.float32),
-                           model=model)
+                           x_full=x_full, model=model)
 
 
 class ServingState(NamedTuple):

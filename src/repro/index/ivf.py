@@ -208,7 +208,8 @@ def build_aligned(model, database, nprobe: int = 8) -> IVFIndex:
     range-scan fine step (``scorer.scan_lists``, dispatched automatically
     by ``candidates``). The packed lists are kept ONLY for streaming
     ``insert_ids`` / ``remove_ids`` and for non-sorted scorers; the fused
-    serving path never reads them."""
+    serving path never reads them. A host store (``rerank_tier.is_host``)
+    is tagged a chunk at a time (``gv.assign_tags``), never sent whole."""
     tags = np.asarray(gv.assign_tags(model, database))
     return IVFIndex(centers=jnp.asarray(model.centers, jnp.float32),
                     lists=jnp.asarray(_pack_lists(tags, model.n_clusters)),

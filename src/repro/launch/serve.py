@@ -76,8 +76,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import gleanvec as gv, leanvec_sphering as lvs, metrics
+from repro.core import rerank_tier, streaming
 from repro.core import search as msearch
-from repro.core import streaming
 from repro.core.scorer import MODES
 from repro.data import vectors
 from repro.index import distributed, graph, ivf
@@ -639,8 +639,14 @@ def fit_model(args, ds: vectors.VectorDataset):
 
 
 def build_state(args, X, model) -> msearch.ServingState:
-    """Artifacts + traversal for ``args`` over the database ``X``."""
-    X = jnp.asarray(X)
+    """Artifacts + traversal for ``args`` over the database ``X``. With
+    ``--host-rerank`` (unsharded) the store stays in host memory: the
+    build streams it to the device a chunk at a time and serves it where
+    it lies."""
+    if args.host_rerank and not args.shards:
+        X = rerank_tier.read_only(np.asarray(X, np.float32))
+    else:
+        X = jnp.asarray(X)
     if args.shards:
         # the stacked per-shard scorer IS the serving scorer -- don't also
         # encode the whole database into a global one just to discard it
@@ -661,8 +667,6 @@ def build_state(args, X, model) -> msearch.ServingState:
     else:
         artifacts = msearch.build_artifacts(args.mode, X, model)
         index = build_index(args, X, artifacts.scorer, model)
-        if args.host_rerank:
-            artifacts = msearch.demote_rerank_tier(artifacts)
     return msearch.make_state(artifacts, index=index)
 
 

@@ -166,13 +166,16 @@ class ServeStats:
     # ``host_bytes`` is the measured host->device rerank-row traffic,
     # ``host_bytes_lb`` the m*kappa*D*4 lower bound per batch -- the bench
     # layer smoke-enforces measured <= 2x bound, pinning the tier's whole
-    # point (per-query traffic scales with kappa, not n).
+    # point (per-query traffic scales with kappa, not n). Counted for every
+    # batch that crosses: ``submit``'s and ``search_with``'s (the
+    # frontend's, its warmup's and canaries').
     host_bytes: int = 0
     host_bytes_lb: int = 0
     window: int = 8192
     latencies_ms: Optional[Deque[float]] = None
     swap_ms: Optional[Deque[float]] = None
     prefetch_ms: Optional[Deque[float]] = None    # host gather + H2D + rerank
+    host_take_ms: Optional[Deque[float]] = None   # host gather + H2D
     request_ms: Optional[Deque[float]] = None     # frontend enqueue->resolve
 
     def __post_init__(self):
@@ -182,6 +185,8 @@ class ServeStats:
             self.swap_ms = collections.deque(maxlen=self.window)
         if self.prefetch_ms is None:
             self.prefetch_ms = collections.deque(maxlen=self.window)
+        if self.host_take_ms is None:
+            self.host_take_ms = collections.deque(maxlen=self.window)
         if self.request_ms is None:
             self.request_ms = collections.deque(maxlen=self.window)
 
@@ -310,19 +315,42 @@ class ServingEngine:
 
     def search_with(self, queries, state: msearch.ServingState):
         """One full search against an arbitrary (treedef-compatible) state
-        WITHOUT installing it or touching engine stats -- the lifecycle
-        layer's canary hook. Runs whichever pipeline shape the engine
-        serves, so a canary over a host-tier state exercises the candidate
-        state's own host store. The argument transfer and the compiled
-        call's dispatch are the profiler span ``serve.launch``."""
+        WITHOUT installing it -- the frontend's step and the lifecycle
+        layer's canary hook; of the engine's stats it touches only the
+        host tier's traffic counters (:meth:`_fetch_rows`). Runs whichever
+        pipeline shape the engine serves, so a canary over a host-tier
+        state exercises the candidate state's own host store. The
+        argument transfer and the compiled call's dispatch are the
+        profiler span ``serve.launch``."""
         with TraceAnnotation("serve.launch"):
             queries = jnp.asarray(queries, jnp.float32)
             if self._host is None:
                 ids, _ = self._fn(queries, state)
                 return ids
             cand, _ = self._cand_fn(queries, state)
-        return msearch.rerank(queries, state.artifacts, np.asarray(cand),
-                              self.k)
+        cand = np.asarray(cand)                 # blocks on the fine scan
+        rows = self._fetch_rows(msearch.host_tier(state.artifacts), cand)
+        return msearch.rerank_candidates(
+            msearch._rotate_queries(queries, state.artifacts), rows,
+            jnp.asarray(cand), self.k)
+
+    def _fetch_rows(self, store, cand: np.ndarray) -> jax.Array:
+        """The host tier's share of one batch: gather the batch's kappa
+        candidate rows from the host ``store`` (span ``serve.host_take``,
+        with the ``rows`` and ``bytes`` gathered) and copy them to the
+        device (span ``serve.host_copy``, until they are there). Counted in
+        ``stats.host_bytes`` / ``host_bytes_lb`` / ``host_take_ms``."""
+        t0 = time.perf_counter()
+        row_bytes = store.shape[1] * np.dtype(store.dtype).itemsize
+        with TraceAnnotation("serve.host_take", rows=cand.size,
+                             bytes=cand.size * row_bytes):
+            rows = store.take(cand)             # (batch, kappa, D) gather
+        with TraceAnnotation("serve.host_copy"):
+            rows_dev = jax.block_until_ready(jax.device_put(rows))
+        self.stats.host_take_ms.append((time.perf_counter() - t0) * 1e3)
+        self.stats.host_bytes += rows.nbytes
+        self.stats.host_bytes_lb += cand.shape[0] * self.kappa * row_bytes
+        return rows_dev
 
     def _check_swap_compatible(self, state: msearch.ServingState) -> None:
         """Raise ``ValueError`` unless ``state`` would reuse the compiled
@@ -422,8 +450,8 @@ class ServingEngine:
         For each batch the compiled candidates step is DISPATCHED (jax's
         async dispatch returns immediately); while the device runs batch
         i+1's fine scan, the host drains batch i: block on its candidate
-        ids, gather the kappa full-D rows from the host store, push them
-        with a non-blocking ``device_put`` and fold the shared compiled
+        ids, gather the kappa full-D rows from the host store, copy them
+        to the device (:meth:`_fetch_rows`) and fold the shared compiled
         ``rerank_candidates`` program over them. The host->device traffic
         is exactly the candidate rows -- batch*kappa*D*4 bytes, counted in
         ``stats.host_bytes`` against the matching lower bound -- never the
@@ -462,16 +490,12 @@ class ServingEngine:
         cand_dev, q_full, keep, n_live, t0 = pending
         cand = np.asarray(cand_dev)            # blocks on the fine scan
         tp = time.perf_counter()
-        rows = self._host.take(cand)           # (batch, kappa, D) host gather
-        rows_dev = jax.device_put(rows)        # non-blocking H2D prefetch
+        rows_dev = self._fetch_rows(self._host, cand)
         ids = msearch.rerank_candidates(q_full, rows_dev,
                                         jnp.asarray(cand), self.k)
         ids = jax.block_until_ready(ids)
         now = time.perf_counter()
         self.stats.prefetch_ms.append((now - tp) * 1e3)
-        self.stats.host_bytes += rows.nbytes
-        self.stats.host_bytes_lb += (cand.shape[0] * self.kappa
-                                     * rows.shape[-1] * rows.itemsize)
         self.stats.n_batches += 1
         self.stats.n_queries += n_live
         self.stats.latencies_ms.append((now - t0) * 1e3)

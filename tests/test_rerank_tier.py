@@ -20,7 +20,13 @@ Four guarantee layers:
   and graph traversals).
 * TRACE SAFETY -- ``rerank`` over a host store refuses to run inside jit
   (the gather is host-driven) with an actionable error.
+* HOST BUILD -- a read-only host array is built from where it lies: the
+  codes, tags, layout, IVF lists and served ids equal the device build's,
+  ``x_full`` shares the caller's memory through demotion, and no transfer
+  to the device is larger than one chunk of rows.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -205,6 +211,133 @@ def test_rerank_refuses_host_gather_inside_jit(setup):
 
     with pytest.raises(TypeError, match="state_candidates"):
         jax.jit(traced)(jnp.zeros((4, KAPPA), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# HOST BUILD: a read-only host array stays where it is.
+# ---------------------------------------------------------------------------
+
+HOST_CHUNK = 600          # 2048 rows: three whole chunks and a short one
+SCORER_FIELDS = ("codes", "x_low", "tags", "block_tags", "perm", "inv_perm",
+                 "lo", "delta")
+
+
+def _host_and_device_states(X, gvm, mode, index_kind):
+    """The same rows built on the device and from a read-only host array
+    (``index_kind``: flat or aligned IVF over every list)."""
+    states = {}
+    for where in ("device", "host"):
+        x = X if where == "device" else rerank_tier.read_only(np.asarray(X))
+        arts = msearch.build_artifacts(mode, x, gvm)
+        index = None
+        if index_kind == "ivf-aligned":
+            index = ivf.with_reduced_centers(
+                ivf.build_aligned(gvm, arts.x_full, nprobe=gvm.n_clusters),
+                arts.scorer, gvm)
+        states[where] = msearch.make_state(arts, index=index, block=256)
+    return states, x
+
+
+@pytest.mark.parametrize("mode,index_kind", [
+    ("gleanvec-int8-sorted", "flat"), ("gleanvec-int8-sorted", "ivf-aligned"),
+    ("gleanvec-sorted", "flat"), ("gleanvec-sorted", "ivf-aligned"),
+    ("gleanvec-int8", "flat")])
+def test_host_array_build_equals_device_build(setup, monkeypatch, mode,
+                                              index_kind):
+    ds, X, _, gvm = setup
+    monkeypatch.setattr(rerank_tier, "HOST_CHUNK", HOST_CHUNK)
+    states, x_host = _host_and_device_states(X, gvm, mode, index_kind)
+    dev, host = (states[w].artifacts for w in ("device", "host"))
+    assert msearch.host_tier(dev) is None
+    assert isinstance(msearch.host_tier(host), rerank_tier.HostStore)
+    fields = [f for f in SCORER_FIELDS if hasattr(dev.scorer, f)]
+    assert len(fields) >= 4
+    for f in fields:
+        np.testing.assert_array_equal(np.asarray(getattr(host.scorer, f)),
+                                      np.asarray(getattr(dev.scorer, f)),
+                                      err_msg=f)
+    if index_kind == "ivf-aligned":
+        np.testing.assert_array_equal(
+            np.asarray(states["host"].index.lists),
+            np.asarray(states["device"].index.lists))
+    # served ids through the engines (one step on the device, two stages
+    # with the host gather) are the same
+    q = np.asarray(ds.queries_test)
+    served = {w: ServingEngine(st, k=K, kappa=KAPPA, batch_size=32,
+                               dim=X.shape[1]).submit(q)
+              for w, st in states.items()}
+    np.testing.assert_array_equal(served["host"], served["device"])
+    # reranking every row (kappa = n) answers the exact top-10
+    n = X.shape[0]
+    exact = np.argsort(-(q.astype(np.float64) @ x_host.T.astype(np.float64)),
+                       axis=1)[:, :K]
+    got = np.asarray(_host_search(host, jnp.asarray(q), K, n,
+                                  index=states["host"].index))
+    assert [set(r) for r in got] == [set(r) for r in exact]
+
+
+def test_host_build_serves_the_callers_array(setup):
+    """``x_full`` is a HostStore over the caller's read-only array: built
+    from the array or from a HostStore over it, and demoted, it shares the
+    caller's memory (no copy of the store anywhere)."""
+    _, X, _, gvm = setup
+    x = rerank_tier.read_only(np.asarray(X))
+    assert rerank_tier.is_host(x)
+    assert not rerank_tier.is_host(np.array(x))      # writable: device path
+    for given in (x, rerank_tier.HostStore(x)):
+        arts = msearch.build_artifacts("gleanvec-int8-sorted", given, gvm)
+        store = msearch.host_tier(arts)
+        assert isinstance(store, rerank_tier.HostStore)
+        assert np.shares_memory(store.x, x)
+        demoted = msearch.demote_rerank_tier(arts)
+        assert demoted.x_full is store
+        state = msearch.make_state(
+            demoted, index=ivf.build_aligned(gvm, store, nprobe=4))
+        assert np.shares_memory(msearch.host_tier(state.artifacts).x, x)
+
+
+def test_serve_cli_host_rerank_keeps_the_store_on_the_host(setup):
+    """``repro.launch.serve --host-rerank`` builds through the same calls:
+    the served store is a view of the dataset's own rows."""
+    from repro.launch import serve
+    ds, _, _, gvm = setup
+    args = serve.parser().parse_args(
+        ["--mode", "gleanvec-int8-sorted", "--index", "ivf", "--aligned",
+         "--reduced-probe", "--n", "2048", "--dim", "64", "--d", "24",
+         "--clusters", "8", "--nprobe", "4", "--host-rerank"])
+    state = serve.build_state(args, ds.database, gvm)
+    store = msearch.host_tier(state.artifacts)
+    assert isinstance(store, rerank_tier.HostStore)
+    assert np.shares_memory(store.x, ds.database)
+    assert ds.database.flags.writeable          # the caller's flag is kept
+
+
+AVAL = re.compile(r"host-to-device transfer: aval=ShapedArray\((\w+)\[([\d,]*)\]")
+
+
+def test_host_build_sends_the_device_one_chunk_at_a_time(setup, monkeypatch,
+                                                         capfd):
+    """Every host->device transfer of the host build, as the transfer
+    guard logs it, is at most one chunk of rows; the scorer streams the
+    store twice (tags with ranges, then codes) and the aligned IVF once."""
+    _, X, _, gvm = setup
+    monkeypatch.setattr(rerank_tier, "HOST_CHUNK", HOST_CHUNK)
+    x = rerank_tier.read_only(np.asarray(X))
+    capfd.readouterr()
+    with jax.transfer_guard_host_to_device("log_explicit"):
+        arts = msearch.build_artifacts("gleanvec-int8-sorted", x, gvm)
+        index = ivf.build_aligned(gvm, arts.x_full, nprobe=4)
+        jax.block_until_ready((arts.scorer, index))
+    sizes = []
+    for dtype, shape in AVAL.findall(capfd.readouterr().err):
+        dims = [int(d) for d in shape.split(",") if d]
+        sizes.append((int(np.prod(dims)) * np.dtype(dtype).itemsize, dims))
+    n, dim = x.shape
+    chunk_bytes = HOST_CHUNK * dim * 4
+    assert sizes and max(b for b, _ in sizes) <= chunk_bytes
+    store_chunks = [d for _, d in sizes if len(d) == 2 and d[1] == dim]
+    assert len(store_chunks) == 3 * -(-n // HOST_CHUNK)
+    assert sum(d[0] for d in store_chunks) == 3 * n
 
 
 # ---------------------------------------------------------------------------

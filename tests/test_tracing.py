@@ -5,7 +5,10 @@ The names are a contract: the benchmark's per-layer metrics read them.
 * Host spans (``jax.profiler.TraceAnnotation``): each dispatcher round is
   ``serve.round`` around ``serve.take``, ``serve.assemble``,
   ``serve.step`` (with ``rows`` and ``live``) and ``serve.resolve``; the
-  engine's ``serve.launch`` sits inside ``serve.step``.
+  engine's ``serve.launch`` sits inside ``serve.step``. With the rerank
+  store in host memory, ``serve.host_take`` (``rows``, ``bytes``) and
+  ``serve.host_copy`` sit there too, and ``ServeStats`` counts the rows'
+  bytes and each batch's ``host_take_ms``.
 * Named scopes (``jax.named_scope``) of the compiled ``serve_step``: every
   compute instruction that comes from a traced operation carries one of
   ``search.prepare`` / ``probe`` / ``scan`` / ``merge`` / ``rerank``.
@@ -121,6 +124,36 @@ def test_submit_step_spans(data, engine, tmp_path):
         == [(BATCH, 3), (BATCH, BATCH)]
     launches = named(spans, "serve.launch")
     assert len(launches) == 2 and all(inside(s, steps) for s in launches)
+
+
+def test_host_tier_spans_and_counters_on_the_frontend_path(data, tmp_path):
+    kappa = 20
+    engine = ServingEngine(
+        served_state(data, [*SERVED["ivf-aligned"][0], "--host-rerank"]),
+        k=10, kappa=kappa, batch_size=BATCH, dim=D)
+    fe = ServingFrontend(engine, capacity=64, start=False)
+    s = engine.stats
+    before = (s.host_bytes, s.host_bytes_lb, len(s.host_take_ms))
+    queries = np.asarray(data.queries_test)
+    with jax.profiler.trace(str(tmp_path)):
+        for n in (3, BATCH):
+            futures = [fe.enqueue(q) for q in queries[:n]]
+            while fe.queue_depth:
+                fe.drain_once()
+            assert all(f.result().shape == (10,) for f in futures)
+    spans = host_spans(tmp_path)
+    steps = named(spans, "serve.step")
+    takes = named(spans, "serve.host_take")
+    copies = named(spans, "serve.host_copy")
+    assert len(steps) == len(takes) == len(copies) == 2
+    assert all(inside(t, steps) for t in takes + copies)
+    rows = sorted(st[4]["rows"] * kappa for st in steps)       # 4 and 8
+    assert sorted(t[4]["rows"] for t in takes) == rows
+    assert sorted(t[4]["bytes"] for t in takes) == [r * D * 4 for r in rows]
+    moved = sum(rows) * D * 4
+    assert (s.host_bytes, s.host_bytes_lb, len(s.host_take_ms)) \
+        == (before[0] + moved, before[1] + moved, before[2] + 2)
+    assert all(ms > 0 for ms in list(s.host_take_ms)[-2:])
 
 
 # -- the compiled step's scopes ---------------------------------------------
